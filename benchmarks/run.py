@@ -23,6 +23,7 @@ import time
 import traceback
 from typing import Dict, Optional
 
+from repro.compile_cache import use_compile_cache
 from benchmarks import (
     availability,
     correlation,
@@ -119,6 +120,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trajectory", type=str, default=TRAJECTORY_PATH,
                     help="trajectory JSONL path")
     args = ap.parse_args(argv)
+    use_compile_cache()
     names = (
         [n.strip() for n in args.only.split(",") if n.strip()]
         if args.only

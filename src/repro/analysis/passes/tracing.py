@@ -225,9 +225,8 @@ def _body_findings(
                     message=f"{name} inside traced function {fn.name!r}: "
                             "without enable_x64 JAX silently down-casts "
                             "to 32-bit",
-                    hint="use 32-bit dtypes, or manage "
-                         "jax.experimental.enable_x64 explicitly at "
-                         "module level",
+                    hint="use 32-bit dtypes, or scope 64-bit mode "
+                         "explicitly with jax.enable_x64(True)",
                 ))
     return out
 

@@ -4,13 +4,14 @@ Each kernel ships three artifacts:
 
 * ``<name>.py``  — the ``pl.pallas_call`` + explicit BlockSpec VMEM tiling,
 * ``ops.py``     — jit'd wrappers with model-layout transforms and the
-                   ``interpret`` switch (True on CPU: the kernel body runs
-                   in Python for correctness validation),
+                   ``interpret`` switch (compiled by default; True runs
+                   the kernel body in Python, which is how the CPU tests
+                   validate it),
 * ``ref.py``     — pure-jnp oracles the tests ``assert_allclose`` against.
 
-``compat.py`` absorbs Pallas TPU API drift across JAX versions
-(``TPUCompilerParams`` vs ``CompilerParams``, the VMEM handle); kernels
-never touch ``jax.experimental.pallas.tpu`` symbols directly.
+``compat.py`` holds the Pallas TPU names of the pinned JAX
+(``CompilerParams``, the VMEM handle); kernels never touch
+``jax.experimental.pallas.tpu`` symbols directly.
 
 Kernels:
 
@@ -22,8 +23,10 @@ Kernels:
 * ``moe_gmm``          — grouped (per-expert) matmul for MoE FFNs.
 
 TPU tiling notes: MXU wants the two minor dims in multiples of (8, 128)
-for fp32 / (16, 128) for bf16; all BlockSpecs here keep the last dim a
-multiple of 128 and the second-minor a multiple of the sublane count.
+for fp32 / (16, 128) for bf16; every BlockSpec here keeps each of its
+two minor dims a multiple of that tile or equal to the whole array dim,
+the two shapes the TPU compiler accepts (``tests/test_tpu_compile.py``
+compiles each kernel for a v5e at served-model widths).
 """
 
 from repro.kernels import compat, ops, ref

@@ -1,18 +1,11 @@
-"""Pallas TPU API compatibility shim.
+"""Pallas TPU names, resolved in one place.
 
-The Pallas TPU surface has drifted across JAX releases: the compiler-
-options dataclass was published as ``TPUCompilerParams`` (jax <= 0.4.x /
-0.5.x) and renamed to ``CompilerParams`` later; the VMEM scratch-space
-handle has likewise moved between spellings.  The seed kernels were
-written against the newer spelling, which left the whole data plane dead
-under older-but-supported JAX versions (``AttributeError: module
-'jax.experimental.pallas.tpu' has no attribute 'CompilerParams'``).
-
-All four kernels resolve the drifted symbols through this module, so a
-JAX upgrade (or downgrade within the tested range in ``pyproject.toml``)
-is a one-file fix.  Resolution happens at import time; the ``resolve_*``
-helpers take the module as an argument so tests can exercise both API
-spellings without touching the installed JAX.
+All four kernels take the compiler-options class and the VMEM scratch
+handle from this module, so a JAX upgrade that moves either is a one-file
+fix.  Only the spellings of the JAX pinned in ``pyproject.toml`` are
+accepted; an install without them fails at import with an error naming
+the pin.  The ``resolve_*`` helpers take the module as an argument so
+tests can check the failure without touching the installed JAX.
 """
 
 from __future__ import annotations
@@ -29,37 +22,27 @@ __all__ = [
     "resolve_vmem",
 ]
 
-# Preferred spelling first: the current JAX name wins when both exist.
-_COMPILER_PARAMS_NAMES = ("CompilerParams", "TPUCompilerParams")
-_VMEM_NAMES = ("VMEM",)
-
 
 def resolve_compiler_params_cls(module: Any = _pltpu) -> Any:
-    """The TPU compiler-options class under whichever name ``module`` has."""
-    for name in _COMPILER_PARAMS_NAMES:
-        cls = getattr(module, name, None)
-        if cls is not None:
-            return cls
-    raise ImportError(
-        f"jax.experimental.pallas.tpu exposes none of "
-        f"{_COMPILER_PARAMS_NAMES}; this JAX version is outside the "
-        "range supported by repro.kernels (see pyproject.toml)"
-    )
+    """The TPU compiler-options class, ``CompilerParams``."""
+    cls = getattr(module, "CompilerParams", None)
+    if cls is None:
+        raise ImportError(
+            "jax.experimental.pallas.tpu has no CompilerParams; this JAX "
+            "version is outside the range pinned in pyproject.toml"
+        )
+    return cls
 
 
 def resolve_vmem(module: Any = _pltpu) -> Any:
     """The VMEM memory-space handle used for scratch shapes."""
-    for name in _VMEM_NAMES:
-        obj = getattr(module, name, None)
-        if obj is not None:
-            return obj
-    ms = getattr(module, "MemorySpace", None)
-    if ms is not None and hasattr(ms, "VMEM"):
-        return ms.VMEM
-    raise ImportError(
-        "jax.experimental.pallas.tpu has no VMEM handle; this JAX version "
-        "is outside the range supported by repro.kernels"
-    )
+    vmem = getattr(module, "VMEM", None)
+    if vmem is None:
+        raise ImportError(
+            "jax.experimental.pallas.tpu has no VMEM handle; this JAX "
+            "version is outside the range pinned in pyproject.toml"
+        )
+    return vmem
 
 
 CompilerParams = resolve_compiler_params_cls()
@@ -69,12 +52,7 @@ VMEM = resolve_vmem()
 def compiler_params(
     *, dimension_semantics: Sequence[str], **kwargs: Any
 ) -> Any:
-    """Build TPU compiler params portably.
-
-    ``dimension_semantics`` is accepted by every known spelling of the
-    class; further keywords pass through verbatim for callers that need
-    version-specific knobs.
-    """
+    """TPU compiler params; further keywords pass through verbatim."""
     return CompilerParams(
         dimension_semantics=tuple(dimension_semantics), **kwargs
     )

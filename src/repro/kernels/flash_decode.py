@@ -1,15 +1,19 @@
 """Flash decode Pallas TPU kernel: one query token vs. a long KV cache.
 
-Layout: q (B, H, D), k/v (B, Kv, S, D), valid (B, S) int8, out (B, H, D).
+Layout: q (B, Kv, G, D) — the G = H / Kv query heads that share one KV
+head —, k/v (B, Kv, S, D), valid (B, 1, S) int32, out (B, Kv, G, D).
 
-Grid: (B, H, nKV) — the KV axis is the sequential reduction with running
-max / denominator in VMEM scratch (split-K style flash decoding).  The
-validity mask (cache occupancy, ring-buffer slots) rides along as a blocked
-int8 input, so arbitrary cache lengths need no recompile.
+Grid: (B, Kv, nKV) — the KV axis is the sequential reduction with running
+max / denominator in VMEM scratch (split-K style flash decoding).  One
+kernel instance serves a whole GQA group, so each KV block is read once
+per group rather than once per query head, and the q/out blocks are the
+full (G, D) plane — aligned to the TPU tiling for every G.  The validity
+mask (cache occupancy, ring-buffer slots) rides along as a blocked input,
+so arbitrary cache lengths need no recompile.
 
 Decode attention is HBM-bandwidth-bound (read the whole KV cache once per
-token); the kernel's job is to keep the reads streaming at full ``(8,128)``
-tile efficiency with zero intermediate HBM traffic.
+token); the kernel's job is to keep the reads streaming with zero
+intermediate HBM traffic.
 """
 
 from __future__ import annotations
@@ -42,33 +46,32 @@ def _kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                # (1, D) row block
+    q = q_ref[0, 0].astype(jnp.float32)             # (G, D)
     k = k_ref[0, 0].astype(jnp.float32)             # (bkv, D)
     v = v_ref[0, 0].astype(jnp.float32)
-    valid = valid_ref[0] != 0                        # (bkv,)
+    valid = valid_ref[0] != 0                        # (1, bkv)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )[0] * scale                                     # (bkv,)
+    ) * scale                                        # (G, bkv)
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_scr[0]
-    l_prev = l_scr[0]
-    m_new = jnp.maximum(m_prev, s.max())
+    m_prev = m_scr[...]                              # (G, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[0] = l_prev * corr + p.sum()
-    m_scr[0] = m_new
+    l_scr[...] = l_scr[...] * corr + p.sum(axis=-1, keepdims=True)
+    m_scr[...] = m_new
     acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-        p[None, :], v, (((1,), (0,)), ((), ())),
+        p, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
     @pl.when(kj == n_kv - 1)
     def _finish():
-        l = jnp.maximum(l_scr[0], 1e-20)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)   # (1, D)
+        l = jnp.maximum(l_scr[...], 1e-20)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_decode_bhd(
@@ -92,34 +95,28 @@ def flash_decode_bhd(
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
         valid = jnp.pad(valid, ((0, 0), (0, pad)))
     nkv = (S + pad) // block_kv
-    valid = valid.astype(jnp.int8)
+    valid = valid.astype(jnp.int32)[:, None, :]
 
     kernel = functools.partial(_kernel, scale=scale, n_kv=nkv)
     out = pl.pallas_call(
         kernel,
-        grid=(B, H, nkv),
+        grid=(B, Kv, nkv),
         in_specs=[
-            pl.BlockSpec((1, 1, D), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec(
-                (1, 1, block_kv, D),
-                lambda b, h, j, G=G: (b, h // G, j, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_kv, D),
-                lambda b, h, j, G=G: (b, h // G, j, 0),
-            ),
-            pl.BlockSpec((1, block_kv), lambda b, h, j: (b, j)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_kv, D), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_kv), lambda b, h, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda b, h, j: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Kv, G, D), q.dtype),
         scratch_shapes=[
-            compat.VMEM((1,), jnp.float32),
-            compat.VMEM((1,), jnp.float32),
-            compat.VMEM((1, D), jnp.float32),
+            compat.VMEM((G, 1), jnp.float32),
+            compat.VMEM((G, 1), jnp.float32),
+            compat.VMEM((G, D), jnp.float32),
         ],
         compiler_params=compat.compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v, valid)
-    return out
+    )(q.reshape(B, Kv, G, D), k, v, valid)
+    return out.reshape(B, H, D)

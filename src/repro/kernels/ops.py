@@ -1,17 +1,11 @@
 """jit'd wrappers over the Pallas kernels, in model layouts.
 
-``interpret`` defaults to True off-TPU (the kernel body executes in Python
-on CPU for correctness); on TPU backends the compiled kernels run.  Model
-code calls these through ``impl="pallas"``.
-
-Backend detection happens HERE, in the plain-Python wrappers, before the
-jitted inner functions are entered.  ``interpret`` is a static argument,
-so resolving it inside the traced body would bake ``jax.default_backend()``
-at first-trace time into the cache entry for ``interpret=None`` — a later
-call under a different backend (e.g. a CPU fallback after TPU init, or a
-``jax.default_device`` context) would silently reuse the stale choice.
-Resolved pre-jit, every distinct backend decision gets its own cache
-entry keyed on the concrete boolean.
+Every wrapper runs the compiled kernel unless the caller passes
+``interpret=True`` (the kernel body then executes in Python, which is how
+the CPU tests validate the kernels).  Nothing here looks at the backend:
+a compiled kernel off-TPU fails loudly instead of silently running the
+interpreter, and the choice never depends on what a trace happened to
+see.  Model code calls these through ``impl="pallas"``.
 """
 
 from __future__ import annotations
@@ -20,17 +14,11 @@ import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.flash_decode import flash_decode_bhd
 from repro.kernels.moe_gmm import moe_gmm_ecf
-from repro.kernels.selective_scan import selective_scan_bqcn
-
-
-def _default_interpret() -> bool:
-    """Interpret off-TPU.  Must only be called from un-jitted code."""
-    return jax.default_backend() != "tpu"
+from repro.kernels.selective_scan import selective_scan_bqnc
 
 
 @functools.partial(
@@ -38,17 +26,17 @@ def _default_interpret() -> bool:
     static_argnames=("causal", "window", "prefix_len", "block_q",
                      "block_kv", "interpret"),
 )
-def _flash_attention_jit(
-    q: jax.Array,
-    k: jax.Array,
+def flash_attention(
+    q: jax.Array,                 # model layout (B, S, H, D)
+    k: jax.Array,                 # (B, S, Kv, D)
     v: jax.Array,
     *,
-    causal: bool,
-    window: Optional[int],
-    prefix_len: int,
-    block_q: int,
-    block_kv: int,
-    interpret: bool,
+    causal: bool = True,
+    window: Optional[int] = None,
+    prefix_len: int = 0,
+    block_q: int = 128,
+    block_kv: int = 128,
+    interpret: bool = False,
 ) -> jax.Array:
     out = flash_attention_bhsd(
         q.transpose(0, 2, 1, 3),
@@ -64,42 +52,17 @@ def _flash_attention_jit(
     return out.transpose(0, 2, 1, 3)
 
 
-def flash_attention(
-    q: jax.Array,                 # model layout (B, S, H, D)
-    k: jax.Array,                 # (B, S, Kv, D)
-    v: jax.Array,
-    *,
-    causal: bool = True,
-    window: Optional[int] = None,
-    prefix_len: int = 0,
-    block_q: int = 128,
-    block_kv: int = 128,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    if interpret is None:
-        interpret = _default_interpret()
-    return _flash_attention_jit(
-        q, k, v,
-        causal=causal,
-        window=window,
-        prefix_len=prefix_len,
-        block_q=block_q,
-        block_kv=block_kv,
-        interpret=interpret,
-    )
-
-
 @functools.partial(
     jax.jit, static_argnames=("block_kv", "interpret")
 )
-def _flash_decode_jit(
-    q: jax.Array,
-    k_cache: jax.Array,
+def flash_decode(
+    q: jax.Array,                 # (B, 1, H, D) model layout
+    k_cache: jax.Array,           # (B, S, Kv, D)
     v_cache: jax.Array,
-    kv_valid: jax.Array,
     *,
-    block_kv: int,
-    interpret: bool,
+    kv_valid: jax.Array,          # (B, S)
+    block_kv: int = 512,
+    interpret: bool = False,
 ) -> jax.Array:
     out = flash_decode_bhd(
         q[:, 0],
@@ -112,77 +75,53 @@ def _flash_decode_jit(
     return out[:, None]
 
 
-def flash_decode(
-    q: jax.Array,                 # (B, 1, H, D) model layout
-    k_cache: jax.Array,           # (B, S, Kv, D)
-    v_cache: jax.Array,
-    *,
-    kv_valid: jax.Array,          # (B, S)
-    block_kv: int = 512,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    if interpret is None:
-        interpret = _default_interpret()
-    return _flash_decode_jit(
-        q, k_cache, v_cache, kv_valid,
-        block_kv=block_kv, interpret=interpret,
+#: bytes of one selective-scan VMEM block: three blocked arrays, double
+#: buffered, stay far inside the 16 MiB of scoped VMEM a v5e kernel gets
+_SCAN_BLOCK_BYTES = 1 << 20
+
+
+def _scan_blocks(Q: int, N: int, C: int) -> tuple:
+    """(block_q, block_c) for the selective scan: the channel block is the
+    largest multiple of 128 up to 512 dividing C (else all of C, which the
+    tiling also accepts); the time block is the longest divisor of Q whose
+    f32 block fits ``_SCAN_BLOCK_BYTES``."""
+    bc = next((c for c in (512, 384, 256, 128) if C % c == 0), C)
+    bq = next(
+        q for q in range(Q, 0, -1)
+        if Q % q == 0 and (q * N * bc * 4 <= _SCAN_BLOCK_BYTES or q == 1)
     )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_c", "interpret")
-)
-def _selective_scan_jit(
-    a: jax.Array,
-    b: jax.Array,
-    h0: jax.Array,
-    *,
-    block_c: int,
-    interpret: bool,
-) -> jax.Array:
-    return selective_scan_bqcn(
-        a, b, h0, block_c=block_c, interpret=interpret
-    )
-
-
-def selective_scan(
-    a: jax.Array,                 # (B, Q, C, N)
-    b: jax.Array,
-    h0: jax.Array,                # (B, C, N)
-    *,
-    block_c: int = 512,
-    interpret: Optional[bool] = None,
-) -> jax.Array:
-    if interpret is None:
-        interpret = _default_interpret()
-    C = a.shape[2]
-    bc = block_c
-    while C % bc:
-        bc //= 2
-    return _selective_scan_jit(
-        a, b, h0, block_c=max(bc, 1), interpret=interpret
-    )
+    return bq, bc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _moe_gmm_jit(
-    x: jax.Array,
-    w: jax.Array,
+def selective_scan(
+    a: jax.Array,                 # (B, Q, C, N) model layout
+    b: jax.Array,
+    h0: jax.Array,                # (B, C, N)
     *,
-    interpret: bool,
+    interpret: bool = False,
 ) -> jax.Array:
-    return moe_gmm_ecf(x, w, interpret=interpret)
+    B, Q, C, N = a.shape
+    block_q, block_c = _scan_blocks(Q, N, C)
+    out = selective_scan_bqnc(
+        a.transpose(0, 1, 3, 2),
+        b.transpose(0, 1, 3, 2),
+        h0.transpose(0, 2, 1),
+        block_c=block_c,
+        block_q=block_q,
+        interpret=interpret,
+    )
+    return out.transpose(0, 1, 3, 2)
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def moe_gmm(
     x: jax.Array,                 # (E, C, D)
     w: jax.Array,                 # (E, D, F)
     *,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _default_interpret()
-    return _moe_gmm_jit(x, w, interpret=interpret)
+    return moe_gmm_ecf(x, w, interpret=interpret)
 
 
 def moe_ffn(
@@ -192,11 +131,9 @@ def moe_ffn(
     wo: jax.Array,                # (E, F, D)
     *,
     act: str = "silu",
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Full expert FFN via the grouped-matmul kernel."""
-    if interpret is None:
-        interpret = _default_interpret()
     h = moe_gmm(xe, wi, interpret=interpret)
     a = jax.nn.silu if act == "silu" else jax.nn.gelu
     if wg is not None:

@@ -15,14 +15,16 @@ Runs the full control plane (SpotHedge placement + dynamic fallback +
 autoscaler + least-loaded LB) against a recorded spot trace with the
 roofline-derived data-plane latency model — the §5.1 methodology.  Every
 run is a :class:`repro.service.ServiceSpec`; the CLI flags are just a spec
-built for you.  Swap ``--live`` (reduced arch) to serve real tokens from
-in-process JAX engines (see examples/serve_llm.py for the live path).
+built for you.  ``--engine jax`` with ``--sweep`` plays the whole matrix
+as one vmapped program on the accelerator.  examples/serve_llm.py serves
+real tokens from in-process JAX engines.
 """
 
 import argparse
 import json
 import sys
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCH_IDS
 from repro.core.policy import registered_policies
 from repro.service import Service, load_spec
@@ -100,13 +102,14 @@ def main(argv=None) -> int:
                     help="run sweep cells in N worker processes "
                     "('auto' = one per CPU); default serial")
     ap.add_argument("--engine", default=None,
-                    choices=["vector", "legacy"],
+                    choices=["vector", "legacy", "jax"],
                     help="override sim.engine for this run")
     ap.add_argument("--replica-model", default=None,
                     choices=["request", "token"],
                     help="override sim.replica_model for this run "
                     "(token = continuous batching + TTFT/TPOT/goodput)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     from repro.service import SpecError
 

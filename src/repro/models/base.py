@@ -88,11 +88,19 @@ def _init_leaf(spec: ParamSpec, key: jax.Array) -> jax.Array:
     raise ValueError(f"unknown init {spec.init!r}")
 
 
-def init_params(bp: Blueprint, key: jax.Array) -> Any:
-    """Materialize parameters (smoke tests / examples / checkpoints)."""
+# one program per leaf: the f32 draw fuses into the cast, so a bf16 leaf
+# never holds an f32 copy of itself on the device
+_init_leaf_jit = jax.jit(_init_leaf, static_argnums=0)
+
+
+def init_params(bp: Blueprint, key: jax.Array, dtype: Any = None) -> Any:
+    """Materialize parameters (smoke tests / examples / checkpoints).
+
+    ``dtype`` overrides every leaf's dtype (serving weights in bf16)."""
     leaves, treedef = jax.tree_util.tree_flatten(bp, is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
-    out = [_init_leaf(spec, k) for spec, k in zip(leaves, keys)]
+    specs = [dataclasses.replace(s, dtype=dtype or s.dtype) for s in leaves]
+    out = [_init_leaf_jit(s, k) for s, k in zip(specs, keys)]
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

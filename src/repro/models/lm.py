@@ -129,8 +129,8 @@ class TransformerLM:
             )
         return bp
 
-    def init(self, key: jax.Array) -> Any:
-        return init_params(self.blueprint(), key)
+    def init(self, key: jax.Array, dtype=None) -> Any:
+        return init_params(self.blueprint(), key, dtype)
 
     def abstract(self, dtype=jnp.bfloat16) -> Any:
         return abstract_params(self.blueprint(), dtype)

@@ -165,8 +165,8 @@ def profile_model(
     """Measure one (model × instance-accelerator) step-time row."""
     cfg = get_config(model_id)
     if interpret is None:
-        # same rule the kernels apply when models call them (ops.py)
-        interpret = ops._default_interpret()
+        # compiled kernels only where they can run
+        interpret = jax.default_backend() != "tpu"
 
     # attention kernels measure the full requested prompt; scan kernels
     # always measure one chunk (the unit the model repeats across a

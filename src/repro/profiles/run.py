@@ -19,6 +19,7 @@ import sys
 import jax
 
 from repro.cluster.catalog import default_catalog
+from repro.compile_cache import use_compile_cache
 from repro.configs import ARCH_IDS
 from repro.profiles.profiler import profile_models
 from repro.profiles.schema import (
@@ -54,6 +55,7 @@ def main(argv=None) -> int:
         "interpret off-TPU",
     )
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     models = list(args.models)
     if models == ["all"]:

@@ -20,8 +20,12 @@ Scope and guarantees:
   shapes are data-dependent, so it stays on the NumPy path (documented
   limitation; the jax path still accepts such specs);
 * a cell whose per-replica queue would exceed ``queue_capacity`` is
-  transparently re-run on the oracle (the kernel flags overflow instead
-  of dropping work), so capacity tuning can never change results.
+  re-run on the oracle (the kernel flags overflow instead of dropping
+  work), so capacity tuning can never change results.
+
+Each cell sent to NumPy counts once on its run's registry, as
+``jax_numpy_fallback{reason=token|overflow}``: a "jax" result that did
+not run on the device says so.
 """
 
 from __future__ import annotations
@@ -50,6 +54,9 @@ __all__ = [
 
 #: per-replica queue pool size (static shape); overflow → oracle rerun
 DEFAULT_QUEUE_CAPACITY = 256
+
+#: registry counter of cells that ran on the NumPy oracle instead
+FALLBACK_COUNTER = "jax_numpy_fallback"
 
 
 class JaxServingEngine(VectorizedServingEngine):
@@ -161,6 +168,7 @@ class JaxServingEngine(VectorizedServingEngine):
         # fresh recorder: the rerun replays the whole control plane, and
         # sharing this engine's recorder would double-record phase A
         kw["obs"] = self.obs.fresh()
+        kw["obs"].registry.inc(FALLBACK_COUNTER, reason="overflow")
         eng = VectorizedServingEngine(
             p["trace"],
             copy.deepcopy(p["policy"]),
@@ -172,9 +180,6 @@ class JaxServingEngine(VectorizedServingEngine):
 
     # -- public API ---------------------------------------------------
     def run(self, duration_s: Optional[float] = None) -> ServingResult:
-        if self._token_cfg is not None:
-            # token cells: continuous batching stays on the NumPy path
-            return super().run(duration_s)
         return run_cells([self], [duration_s])[0]
 
 
@@ -414,6 +419,8 @@ def run_cells(
     scheds: List[CellSchedule] = []
     for i, (eng, dur) in enumerate(zip(engines, durations)):
         if eng._token_cfg is not None:
+            # continuous batching stays on the NumPy path
+            eng.obs.registry.inc(FALLBACK_COUNTER, reason="token")
             results[i] = VectorizedServingEngine.run(eng, dur)
         else:
             scheds.append(eng.record_schedule(dur))

@@ -63,12 +63,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
 
 # float64 parity with the NumPy engines is scoped to run_group's
-# enable_x64() context — the Pallas model kernels elsewhere in this
-# repo assume the default-f32 world, so the flag must never be flipped
-# process-globally here
+# jax.enable_x64(True) context — the Pallas model kernels elsewhere in
+# this repo assume the default-f32 world, so the flag must never be
+# flipped process-globally here
 
 _BIG_I = np.iinfo(np.int64).max
 
@@ -530,7 +529,7 @@ def run_group(
     kern = get_kernel(key)
     # trace, compile and execute under x64 (the jit cache keys on the
     # flag, so every call sees one consistent dtype world)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = kern(
             jnp.asarray(lanes["arr"]),
             jnp.asarray(lanes["svc"]),

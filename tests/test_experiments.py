@@ -192,6 +192,46 @@ def test_suite_parallel_equals_serial():
         assert da == db
 
 
+def test_jax_matrix_never_reaches_worker_processes(monkeypatch):
+    """engine="jax" runs in the calling process whatever ``workers``
+    says: a chip belongs to one process, so a pool worker that needed
+    it would fail or hang while the parent holds it."""
+    def no_pool(*args, **kwargs):
+        raise AssertionError("device cells reached the process pool")
+
+    monkeypatch.setattr(ScenarioSuite, "_run_parallel", no_pool)
+    report = _small_suite().run(engine="jax", workers=2)
+    assert report.workers == 1 and len(report.cells) == 2
+
+
+def test_worker_cells_start_no_jax_backend():
+    """What a pool worker runs (request and token cells on the host
+    engines) never initialises a JAX backend, so workers forked beside a
+    process that holds the chip never contend for it."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "from jax._src import xla_bridge\n"
+        "from repro.experiments.suite import _run_scenario_worker\n"
+        "from tests.test_experiments import _small_suite, _spec\n"
+        "from repro.experiments import ScenarioSuite\n"
+        "token = ScenarioSuite.from_spec(_spec(sweep={'policies': "
+        "['spothedge'], 'replica_models': ['token']}))\n"
+        "for sc in _small_suite().scenarios + token.scenarios:\n"
+        "    _run_scenario_worker((sc, None))\n"
+        "print(xla_bridge.backends_are_initialized())\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([os.path.join(root, "src"), root]))
+    out = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "False"
+
+
 def test_report_select_and_json_artifact(tmp_path):
     report = _small_suite().run(save_to=str(tmp_path))
     assert len(report.select(policy="spothedge")) == 1

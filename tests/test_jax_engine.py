@@ -31,7 +31,8 @@ from repro.experiments.suite import (
     _workload_tape_key,
 )
 from repro.serving.engine import VectorizedServingEngine
-from repro.serving.jaxengine import JaxServingEngine
+from repro.serving.jaxengine import JaxServingEngine, run_cells
+from repro.serving.jaxengine.engine import FALLBACK_COUNTER
 from repro.serving.load_balancer import RoundRobinBalancer
 from repro.service import Service, SpecError, spec_from_dict
 from repro.workloads import make_workload
@@ -181,6 +182,38 @@ def test_queue_overflow_falls_back_to_oracle():
     )
     jx.queue_capacity = 2           # force overflow under saturation
     _assert_equivalent(vec.run(4200.0), jx.run(4200.0))
+
+
+def _fallbacks(res, reason):
+    counters = (res.metrics or {}).get("counters", {})
+    return counters.get(f"{FALLBACK_COUNTER}{{reason={reason}}}", 0)
+
+
+@pytest.mark.parametrize("reason", ["token", "overflow"])
+def test_numpy_fallbacks_are_counted(reason):
+    """Every cell the jax engine hands to NumPy counts once, by reason,
+    on that cell's registry; a cell that ran on the device counts none."""
+    trace = _mini_trace(steps=120, seed=3)
+    reqs = make_workload("poisson", rate_per_s=6.0, seed=3).generate(3600.0)
+
+    def engine(replica_model="request"):
+        return JaxServingEngine(
+            trace, make_policy("spothedge"), reqs, CFG,
+            itype="g5.48xlarge", autoscaler=ConstantTarget(3),
+            timeout_s=30.0, concurrency=1, replica_model=replica_model,
+        )
+
+    if reason == "token":
+        fell_back = engine(replica_model="token")
+    else:
+        fell_back = engine()
+        fell_back.queue_capacity = 2
+    # one batch sizes its queue pools for its largest capacity, so the
+    # undersized cell runs in a batch of its own
+    (fell,) = run_cells([fell_back], [4200.0])
+    kept = engine().run(4200.0)
+    assert _fallbacks(fell, reason) == 1
+    assert _fallbacks(kept, "token") == _fallbacks(kept, "overflow") == 0
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.flash_decode import flash_decode_bhd
 from repro.kernels.moe_gmm import moe_gmm_ecf
-from repro.kernels.selective_scan import selective_scan_bqcn
+from repro.kernels.selective_scan import selective_scan_bqnc
 
 
 def rnd(key, shape, dtype):
@@ -104,20 +104,24 @@ def test_flash_decode_matches_ref(case, dtype):
 # ---------------------------------------------------------------------------
 
 SCAN_CASES = [
-    (1, 32, 64, 16),
-    (2, 64, 128, 16),
-    (2, 17, 256, 8),      # odd chunk length
+    # (B, Q, C, N, block_q)
+    (1, 32, 64, 16, 8),   # state carried across time blocks
+    (2, 64, 128, 16, 16),
+    (2, 17, 256, 8, 17),  # odd chunk length
 ]
 
 
 @pytest.mark.parametrize("case", SCAN_CASES)
 def test_selective_scan_matches_ref(case):
-    B, Q, C, N = case
+    B, Q, C, N, block_q = case
     # a in (0,1) like exp(delta·A); b small
     a = jax.nn.sigmoid(rnd(10, (B, Q, C, N), jnp.float32))
     b = rnd(11, (B, Q, C, N), jnp.float32) * 0.1
     h0 = rnd(12, (B, C, N), jnp.float32)
-    got = selective_scan_bqcn(a, b, h0, block_c=64, interpret=True)
+    got = selective_scan_bqnc(
+        a.transpose(0, 1, 3, 2), b.transpose(0, 1, 3, 2),
+        h0.transpose(0, 2, 1), block_c=64, block_q=block_q, interpret=True,
+    ).transpose(0, 1, 3, 2)
     want = ref.selective_scan_ref(a, b, h0)
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
@@ -135,7 +139,7 @@ def test_selective_scan_equals_mamba_chunked_path():
 
     a_s, b_s = jax.lax.associative_scan(combine, (a, b), axis=1)
     want = b_s + a_s * h0[:, None]
-    got = selective_scan_bqcn(a, b, h0, block_c=32, interpret=True)
+    got = ops.selective_scan(a, b, h0, interpret=True)
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 

@@ -110,3 +110,28 @@ def test_roofline_terms():
     assert r.useful_flops_fraction == pytest.approx(1.0)
     assert r.mfu == pytest.approx(1.0)
     assert r.bottleneck in ("compute", "memory", "collective")
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_dir):
+    """Unset: the cache is <repo>/.jax_cache.  Set: JAX reads the variable
+    itself and the helper sets no directory of its own."""
+    import os
+
+    from repro.compile_cache import REPO_CACHE_DIR, use_compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert str(REPO_CACHE_DIR) == os.path.join(repo, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            assert use_compile_cache() == str(REPO_CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        else:
+            want = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+            assert use_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -6,6 +6,8 @@ a prefill→decode consistency check (the cache path must reproduce the
 full-sequence forward exactly).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -109,6 +111,34 @@ def test_smoke_decode_matches_prefill(arch):
         scale = float(jnp.abs(ref32).max())
         tol = (0.1 if cfg.is_moe else 0.02) + 0.004 * scale
         assert float(err) <= tol, f"{arch} decode mismatch at t={t}: {err}"
+
+
+@pytest.mark.parametrize("arch", ["command-r-35b", "falcon-mamba-7b"])
+def test_pallas_serving_step_matches_blockwise(arch, monkeypatch):
+    """impl="pallas" (kernels interpreted on CPU) serves the same logits
+    as the jnp path: prefill, then a decode step on each one's cache."""
+    from repro.kernels import ops
+
+    # the models look the kernels up at trace time
+    for name in ("flash_attention", "flash_decode", "selective_scan"):
+        monkeypatch.setattr(ops, name, functools.partial(
+            getattr(ops, name), interpret=True))
+    cfg = get_smoke_config(arch)
+    params = build_model(cfg).init(KEY, jnp.bfloat16)
+    B, S = 2, 24
+    toks, _, _ = _inputs(cfg, B=B, S=S)
+    out = {}
+    for impl in ("blockwise", "pallas"):     # both decode blockwise's token
+        model = build_model(cfg, impl=impl, ssm_chunk=8)
+        lg, cache = model.prefill(params, toks, model.init_cache(B, S + 1))
+        if impl == "blockwise":
+            nxt = jnp.argmax(lg, -1).astype(jnp.int32)
+        dec, _ = model.decode_step(params, nxt, cache)
+        out[impl] = (lg, dec)
+    for want, got in zip(out["blockwise"], out["pallas"]):
+        want32, got32 = want.astype(jnp.float32), got.astype(jnp.float32)
+        err = float(jnp.abs(want32 - got32).max())
+        assert err <= 0.02 + 0.004 * float(jnp.abs(want32).max()), err
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

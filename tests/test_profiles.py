@@ -2,9 +2,8 @@
 
 Covers the three layers the profile data plane spans:
 
-* ``kernels/compat.py`` resolves the Pallas TPU API under both historical
-  spellings (``CompilerParams`` vs ``TPUCompilerParams``) — exercised via
-  stand-in modules, independent of the installed JAX;
+* ``kernels/compat.py`` resolves the pinned JAX's Pallas TPU names and
+  fails loudly without them — exercised via stand-in modules;
 * ``profiles/`` schema round-trips, version gating, directory merging,
   and a real (tiny) profiler run through the interpret-mode kernels;
 * ``ProfiledLatencyModel`` reproduces the measured step times from a
@@ -59,20 +58,6 @@ def test_compat_resolves_new_spelling():
     assert compat.resolve_compiler_params_cls(mod) is _Params
 
 
-def test_compat_resolves_old_spelling():
-    mod = types.SimpleNamespace(TPUCompilerParams=_Params)
-    assert compat.resolve_compiler_params_cls(mod) is _Params
-
-
-def test_compat_prefers_current_spelling_when_both_exist():
-    class Old(_Params):
-        pass
-
-    mod = types.SimpleNamespace(CompilerParams=_Params,
-                                TPUCompilerParams=Old)
-    assert compat.resolve_compiler_params_cls(mod) is _Params
-
-
 def test_compat_raises_outside_supported_range():
     with pytest.raises(ImportError, match="pyproject"):
         compat.resolve_compiler_params_cls(types.SimpleNamespace())
@@ -80,27 +65,16 @@ def test_compat_raises_outside_supported_range():
         compat.resolve_vmem(types.SimpleNamespace())
 
 
-def test_compat_vmem_falls_back_to_memoryspace_enum():
-    sentinel = object()
-    mod = types.SimpleNamespace(
-        MemorySpace=types.SimpleNamespace(VMEM=sentinel)
-    )
-    assert compat.resolve_vmem(mod) is sentinel
-
-
-def test_compat_installed_jax_resolves(monkeypatch):
-    """Whatever JAX is installed, the shim found a working class."""
+def test_compat_installed_jax_resolves():
+    """The installed JAX has the pinned names, and the shim uses them."""
     p = compat.compiler_params(
         dimension_semantics=("parallel", "arbitrary")
     )
     assert tuple(p.dimension_semantics) == ("parallel", "arbitrary")
-    # both spellings route through the same resolver under monkeypatching
     import jax.experimental.pallas.tpu as pltpu
 
-    cls = compat.resolve_compiler_params_cls(pltpu)
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        shadow = types.SimpleNamespace(**{name: cls})
-        assert compat.resolve_compiler_params_cls(shadow) is cls
+    assert compat.CompilerParams is pltpu.CompilerParams
+    assert compat.VMEM is pltpu.VMEM
 
 
 # ---------------------------------------------------------------------------

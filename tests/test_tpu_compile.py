@@ -1,0 +1,144 @@
+"""The chip path compiles for a TPU v5e, with no chip attached.
+
+Each case lowers and compiles one program of the main path for one device
+of a described ``v5e:2x2`` topology: the four Pallas kernels at the widths
+of the served models, and the scenario engine's phase-B kernel under x64.
+A compile that passes is not a chip run, but it catches what interpret
+mode cannot: blocks not aligned to the tiling, kernels that overflow
+scoped VMEM, programs the TPU compiler refuses.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this install
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (S, window, prefix_len) at command-r-35b widths: H64 Kv8 D128
+ATTN_CASES = [
+    (512, None, 0),
+    (300, None, 0),        # S not a multiple of the 128 block
+    (512, None, 64),       # prefix-LM zone
+    (512, 128, 0),         # sliding window
+]
+
+
+@pytest.mark.parametrize("case", ATTN_CASES)
+def test_flash_attention_compiles(one_chip, case):
+    S, window, prefix = case
+    q = _sds(one_chip, (1, S, 64, 128))
+    kv = _sds(one_chip, (1, S, 8, 128))
+    txt = _compile_text(
+        lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=True, window=window, prefix_len=prefix,
+            interpret=False,
+        ),
+        q, kv, kv,
+    )
+    assert "tpu_custom_call" in txt
+
+
+def test_flash_decode_compiles(one_chip):
+    """B4 over a 4096-token cache, command-r-35b heads (G = 8)."""
+    B, S = 4, 4096
+    txt = _compile_text(
+        lambda q, k, v, m: ops.flash_decode(
+            q, k, v, kv_valid=m, interpret=False
+        ),
+        _sds(one_chip, (B, 1, 64, 128)),
+        _sds(one_chip, (B, S, 8, 128)),
+        _sds(one_chip, (B, S, 8, 128)),
+        _sds(one_chip, (B, S), jnp.bool_),
+    )
+    assert "tpu_custom_call" in txt
+
+
+# (Q, C, N): falcon-mamba-7b (d_inner 8192, state 16) at the scan chunk
+# of the kernel table and of the model, zamba2-7b (d_inner 7168, state 64)
+SCAN_CASES = [(64, 8192, 16), (256, 8192, 16), (64, 7168, 64)]
+
+
+@pytest.mark.parametrize("case", SCAN_CASES)
+def test_selective_scan_compiles(one_chip, case):
+    Q, C, N = case
+    f32 = jnp.float32
+    txt = _compile_text(
+        lambda a, b, h: ops.selective_scan(a, b, h, interpret=False),
+        _sds(one_chip, (1, Q, C, N), f32),
+        _sds(one_chip, (1, Q, C, N), f32),
+        _sds(one_chip, (1, C, N), f32),
+    )
+    assert "tpu_custom_call" in txt
+
+
+def test_moe_gmm_compiles(one_chip):
+    """qwen3-moe-30b expert widths: E128 C128 D2048 F768."""
+    txt = _compile_text(
+        lambda x, w: ops.moe_gmm(x, w, interpret=False),
+        _sds(one_chip, (128, 128, 2048)),
+        _sds(one_chip, (128, 2048, 768)),
+    )
+    assert "tpu_custom_call" in txt
+
+
+def test_phase_b_kernel_compiles_under_x64(one_chip):
+    """The scenario engine's vmapped scan at a small shape signature."""
+    from repro.serving.jaxengine import kernel as K
+
+    key = K.KernelKey(G=240, W=24, N=360, R=4, Q=32, C=4, NREG=2, E=2,
+                      AMAX=3, ATYP=2, lb_rr=False, expire_on=True)
+    L = 2
+    f64, i64 = jnp.float64, jnp.int64
+    with jax.enable_x64(True):
+        shapes = [
+            _sds(one_chip, (L, key.N), f64),               # arr
+            _sds(one_chip, (L, key.N), f64),               # svc
+            _sds(one_chip, (L, key.N), i64),               # rcode
+            _sds(one_chip, (L, key.R, key.NREG), f64),     # rtt
+            _sds(one_chip, (L, key.W, key.R), jnp.bool_),  # ready
+            _sds(one_chip, (L, key.E), i64),               # kill_slot
+            _sds(one_chip, (L, key.E), i64),               # kill_g
+            _sds(one_chip, (L,), f64),                     # timeout
+            _sds(one_chip, (key.G,), f64),                 # ts
+            _sds(one_chip, (key.G,), i64),                 # gs
+            _sds(one_chip, (key.G,), i64),                 # wins
+        ]
+        compiled = K._build_kernel(key).lower(*shapes).compile()
+    assert compiled.memory_analysis() is not None
