@@ -40,6 +40,7 @@ from typing import (
 from repro.cluster.traces import SpotTrace
 from repro.core.policy import policy_class
 from repro.experiments.report import CellResult, ScenarioReport
+from repro.obs import hostspan
 from repro.service.builder import build_requests, build_service
 from repro.service.loader import load_spec
 from repro.service.spec import (
@@ -468,23 +469,24 @@ class ScenarioSuite:
 
         builds = []
         for sc in self.scenarios:
-            spec = sc.spec
-            if spec.sim.engine != "jax":
-                spec = dataclasses.replace(
-                    spec, sim=dataclasses.replace(spec.sim, engine="jax")
+            with hostspan.host_span(hostspan.BUILD):
+                spec = sc.spec
+                if spec.sim.engine != "jax":
+                    spec = dataclasses.replace(
+                        spec, sim=dataclasses.replace(spec.sim, engine="jax")
+                    )
+                requests: Optional[List[Request]] = None
+                key = _effective_tape_key(sc)
+                if key is not None:
+                    requests = _worker_tapes.get(key)
+                    if requests is None:
+                        requests = _worker_tapes[key] = build_requests(spec)
+                t0 = time.perf_counter()
+                resolved = build_service(
+                    spec, trace=sc.trace, requests=requests
                 )
-            requests: Optional[List[Request]] = None
-            key = _effective_tape_key(sc)
-            if key is not None:
-                requests = _worker_tapes.get(key)
-                if requests is None:
-                    requests = _worker_tapes[key] = build_requests(spec)
-            t0 = time.perf_counter()
-            resolved = build_service(
-                spec, trace=sc.trace, requests=requests
-            )
-            builds.append((sc, spec, resolved,
-                           time.perf_counter() - t0))
+                builds.append((sc, spec, resolved,
+                               time.perf_counter() - t0))
         t0 = time.perf_counter()
         results = run_cells(
             [b[2].simulator for b in builds],
@@ -493,14 +495,15 @@ class ScenarioSuite:
         # the batch is one program: attribute its wall clock evenly
         share = (time.perf_counter() - t0) / max(len(builds), 1)
         cells: List[CellResult] = []
-        for (sc, _spec, _res, build_s), result in zip(builds, results):
-            cells.append(
-                CellResult.from_result(sc.labels, result,
-                                       build_s + share)
-            )
-            if progress:
-                print(f"[suite {self.name}] {cells[-1].cell_id} done "
-                      f"({len(cells)}/{len(builds)})", flush=True)
+        with hostspan.host_span(hostspan.ASSEMBLE):
+            for (sc, _spec, _res, build_s), result in zip(builds, results):
+                cells.append(
+                    CellResult.from_result(sc.labels, result,
+                                           build_s + share)
+                )
+                if progress:
+                    print(f"[suite {self.name}] {cells[-1].cell_id} done "
+                          f"({len(cells)}/{len(builds)})", flush=True)
         return cells
 
     def _engine_label(self) -> str:

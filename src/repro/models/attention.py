@@ -450,45 +450,47 @@ def attention_apply(
         if layer_cache is not None:
             # prefill: write K/V (post-RoPE) into the cache
             slots = layer_cache["k"].shape[1]
-            if cfg.sliding_window is not None and S > slots:
-                # keep the last `slots` positions, ring-aligned
-                k_tail, v_tail = k[:, -slots:], v[:, -slots:]
-                pos_tail = positions[-slots:]
-                idx = pos_tail % slots
-                ck = layer_cache["k"].at[:, idx].set(
-                    k_tail.astype(layer_cache["k"].dtype)
-                )
-                cv = layer_cache["v"].at[:, idx].set(
-                    v_tail.astype(layer_cache["v"].dtype)
-                )
-            else:
-                start = positions[0]
-                if cfg.sliding_window is not None:
-                    start = start % slots
-                ck = jax.lax.dynamic_update_slice(
-                    layer_cache["k"],
-                    k.astype(layer_cache["k"].dtype),
-                    (0, start, 0, 0),
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    layer_cache["v"],
-                    v.astype(layer_cache["v"].dtype),
-                    (0, start, 0, 0),
-                )
+            with jax.named_scope("kv_write"):
+                if cfg.sliding_window is not None and S > slots:
+                    # keep the last `slots` positions, ring-aligned
+                    k_tail, v_tail = k[:, -slots:], v[:, -slots:]
+                    pos_tail = positions[-slots:]
+                    idx = pos_tail % slots
+                    ck = layer_cache["k"].at[:, idx].set(
+                        k_tail.astype(layer_cache["k"].dtype)
+                    )
+                    cv = layer_cache["v"].at[:, idx].set(
+                        v_tail.astype(layer_cache["v"].dtype)
+                    )
+                else:
+                    start = positions[0]
+                    if cfg.sliding_window is not None:
+                        start = start % slots
+                    ck = jax.lax.dynamic_update_slice(
+                        layer_cache["k"],
+                        k.astype(layer_cache["k"].dtype),
+                        (0, start, 0, 0),
+                    )
+                    cv = jax.lax.dynamic_update_slice(
+                        layer_cache["v"],
+                        v.astype(layer_cache["v"].dtype),
+                        (0, start, 0, 0),
+                    )
             new_cache = {"k": ck, "v": cv}
     elif mode == "decode":
         assert layer_cache is not None and cache_len is not None
         slots = layer_cache["k"].shape[1]
         pos = positions[0]  # scalar: absolute position of the new token
         slot = pos % slots if cfg.sliding_window is not None else pos
-        ck = jax.lax.dynamic_update_slice(
-            layer_cache["k"], k.astype(layer_cache["k"].dtype),
-            (0, slot, 0, 0),
-        )
-        cv = jax.lax.dynamic_update_slice(
-            layer_cache["v"], v.astype(layer_cache["v"].dtype),
-            (0, slot, 0, 0),
-        )
+        with jax.named_scope("kv_write"):
+            ck = jax.lax.dynamic_update_slice(
+                layer_cache["k"], k.astype(layer_cache["k"].dtype),
+                (0, slot, 0, 0),
+            )
+            cv = jax.lax.dynamic_update_slice(
+                layer_cache["v"], v.astype(layer_cache["v"].dtype),
+                (0, slot, 0, 0),
+            )
         n_filled = jnp.minimum(cache_len + 1, slots)
         slot_ids = jnp.arange(slots)
         if cfg.sliding_window is not None:

@@ -14,7 +14,12 @@ assignment (everything except Whisper's encoder-decoder, see ``whisper.py``):
   for the full sequence (vocab 257k × seq 4k would be hundreds of GB),
 * PaliGemma's vision frontend is a stub per the assignment:
   ``prefix_embed`` (precomputed patch embeddings) is concatenated in front
-  of the token embeddings with a bidirectional prefix-LM mask.
+  of the token embeddings with a bidirectional prefix-LM mask,
+* the serving step names its parts with ``jax.named_scope`` (``embed``,
+  ``layers``, ``logits``; in an attention block ``norm``, ``attention``
+  with its ``kv_write``, ``ffn``), which a profile reads from each
+  operation's metadata; what lies under ``layers`` in no block scope is
+  the scan's own slicing and stacking of per-layer weights and cache.
 
 Modes
 -----
@@ -203,34 +208,39 @@ class TransformerLM:
     ):
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, new_kv = attn.attention_apply(
-            lp["attn"], cfg, h,
-            positions=positions, mode=mode, layer_cache=layer_kv,
-            cache_len=cache_len, prefix_len=prefix_len, impl=self.impl,
-            q_block=self.q_block, kv_block=self.kv_block,
-        )
+        with jax.named_scope("norm"):
+            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+        with jax.named_scope("attention"):
+            a, new_kv = attn.attention_apply(
+                lp["attn"], cfg, h,
+                positions=positions, mode=mode, layer_cache=layer_kv,
+                cache_len=cache_len, prefix_len=prefix_len, impl=self.impl,
+                q_block=self.q_block, kv_block=self.kv_block,
+            )
         if cfg.parallel_block:
             # command-r: attn and FFN read the SAME normed input, summed
-            if cfg.is_moe:
-                f, aux_l = moe_mod.moe_apply(
-                    lp["moe"], cfg, h, return_aux=True
-                )
-                aux = aux + aux_l
-            else:
-                f = mlp_apply(lp["mlp"], cfg, h)
+            with jax.named_scope("ffn"):
+                if cfg.is_moe:
+                    f, aux_l = moe_mod.moe_apply(
+                        lp["moe"], cfg, h, return_aux=True
+                    )
+                    aux = aux + aux_l
+                else:
+                    f = mlp_apply(lp["mlp"], cfg, h)
             x = x + a + f
         else:
             x = x + a
-            h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            if cfg.is_moe:
-                f, aux_l = moe_mod.moe_apply(
-                    lp["moe"], cfg, h2, return_aux=True
-                )
-                aux = aux + (aux_l if aux_l is not None else 0.0)
-                x = x + f
-            else:
-                x = x + mlp_apply(lp["mlp"], cfg, h2)
+            with jax.named_scope("norm"):
+                h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
+            with jax.named_scope("ffn"):
+                if cfg.is_moe:
+                    f, aux_l = moe_mod.moe_apply(
+                        lp["moe"], cfg, h2, return_aux=True
+                    )
+                    aux = aux + (aux_l if aux_l is not None else 0.0)
+                    x = x + f
+                else:
+                    x = x + mlp_apply(lp["mlp"], cfg, h2)
         return x, new_kv, aux
 
     def _mamba_block(self, lp, x, *, mode, state, version):
@@ -408,15 +418,13 @@ class TransformerLM:
         return x, None, jnp.zeros((), jnp.float32)
 
     def _run_stack(self, params, x, *, positions, mode, cache, prefix_len):
-        if self.cfg.family == "hybrid":
-            return self._run_hybrid_stack(
+        run = (self._run_hybrid_stack if self.cfg.family == "hybrid"
+               else self._run_uniform_stack)
+        with jax.named_scope("layers"):
+            return run(
                 params, x, positions=positions, mode=mode, cache=cache,
                 prefix_len=prefix_len,
             )
-        return self._run_uniform_stack(
-            params, x, positions=positions, mode=mode, cache=cache,
-            prefix_len=prefix_len,
-        )
 
     # ==================================================================
     # Public entry points
@@ -424,11 +432,12 @@ class TransformerLM:
     def _embed_inputs(
         self, params, tokens, prefix_embed, dtype
     ) -> Tuple[jax.Array, int]:
-        x = embed_tokens(params["embed"], tokens, dtype)
-        prefix_len = 0
-        if prefix_embed is not None:
-            x = jnp.concatenate([prefix_embed.astype(dtype), x], axis=1)
-            prefix_len = prefix_embed.shape[1]
+        with jax.named_scope("embed"):
+            x = embed_tokens(params["embed"], tokens, dtype)
+            prefix_len = 0
+            if prefix_embed is not None:
+                x = jnp.concatenate([prefix_embed.astype(dtype), x], axis=1)
+                prefix_len = prefix_embed.shape[1]
         return x, prefix_len
 
     def forward(
@@ -498,8 +507,9 @@ class TransformerLM:
             params, x, positions=positions, mode="full", cache=cache,
             prefix_len=prefix_len if self.cfg.prefix_lm else 0,
         )
-        x = rms_norm(x[:, -1:], params["final_norm"], self.cfg.norm_eps)
-        logits = self.logits(params, x)
+        with jax.named_scope("logits"):
+            x = rms_norm(x[:, -1:], params["final_norm"], self.cfg.norm_eps)
+            logits = self.logits(params, x)
         new_cache["len"] = jnp.asarray(positions.shape[0], jnp.int32)
         return logits, new_cache
 
@@ -512,14 +522,16 @@ class TransformerLM:
         dtype=jnp.bfloat16,
     ) -> Tuple[jax.Array, Dict[str, Any]]:
         """One decode step: next-token logits + updated cache."""
-        x = embed_tokens(params["embed"], tokens, dtype)
+        with jax.named_scope("embed"):
+            x = embed_tokens(params["embed"], tokens, dtype)
         positions = cache["len"][None].astype(jnp.int32)
         x, new_cache, _ = self._run_stack(
             params, x, positions=positions, mode="decode", cache=cache,
             prefix_len=0,
         )
-        x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
-        logits = self.logits(params, x)
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
+            logits = self.logits(params, x)
         new_cache["len"] = cache["len"] + 1
         return logits, new_cache
 

@@ -16,6 +16,9 @@ The observability substrate every engine shares:
   Chrome-trace-event (Perfetto-loadable) per-replica timeline.
 * :mod:`repro.obs.attribution` — charges each dollar and each failed
   request back to the policy decision (or preemption) that produced it.
+* :mod:`repro.obs.hostspan` — ``host_span``, the scenario engine's host
+  work marked on ``jax.profiler``'s timeline (wall time, not simulated
+  time).
 * ``python -m repro.obs`` — summarize a run, diff two runs, render the
   attribution report, convert a log to a Perfetto trace.
 
@@ -50,6 +53,7 @@ from repro.obs.export import (
     write_chrome_trace,
     write_jsonl,
 )
+from repro.obs.hostspan import host_span
 from repro.obs.recorder import DETAIL_LEVELS, ObsRecorder
 from repro.obs.registry import (
     MetricsRegistry,
@@ -84,6 +88,7 @@ __all__ = [
     "burn_table",
     "SpanCollector",
     "span_sampled",
+    "host_span",
     "MetricsRegistry",
     "get_registry",
     "use_registry",

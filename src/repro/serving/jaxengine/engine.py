@@ -36,6 +36,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro.obs import hostspan
 from repro.obs.registry import use_registry
 from repro.serving.engine import VectorizedServingEngine, _Rep
 from repro.serving.jaxengine.schedule import (
@@ -112,49 +113,50 @@ class JaxServingEngine(VectorizedServingEngine):
 
         Consumes this engine (the cluster has run); callable once.
         """
-        if self._token_cfg is not None:
-            raise RuntimeError(
-                "token-model cells run on the NumPy data plane; "
-                "call run() directly"
+        with hostspan.host_span(hostspan.PHASE_A):
+            if self._token_cfg is not None:
+                raise RuntimeError(
+                    "token-model cells run on the NumPy data plane; "
+                    "call run() directly"
+                )
+            dt = self.cluster.config.control_interval_s
+            dur = float(duration_s or self.cluster.trace.duration_s)
+            grid = build_grid(dur, dt, self.sub_step_s)
+            self._rec = ScheduleRecorder(grid, self._arr)
+            # phase A is the real control plane: the cluster's obs taps emit
+            # the same decision/lifecycle events as the other engines (no
+            # window samples — this tick override never runs the sampler)
+            with use_registry(self.obs.registry):
+                base = self.cluster.run(duration_s)
+            ready, rtt, kill_slot, kill_g, post = self._rec.control_arrays(
+                len(self._reps),
+                [r.rtt for r in self._reps],
+                len(self._client_regions),
             )
-        dt = self.cluster.config.control_interval_s
-        dur = float(duration_s or self.cluster.trace.duration_s)
-        grid = build_grid(dur, dt, self.sub_step_s)
-        self._rec = ScheduleRecorder(grid, self._arr)
-        # phase A is the real control plane: the cluster's obs taps emit
-        # the same decision/lifecycle events as the other engines (no
-        # window samples — this tick override never runs the sampler)
-        with use_registry(self.obs.registry):
-            base = self.cluster.run(duration_s)
-        ready, rtt, kill_slot, kill_g, post = self._rec.control_arrays(
-            len(self._reps),
-            [r.rtt for r in self._reps],
-            len(self._client_regions),
-        )
-        self._rec = None
-        sched = CellSchedule(
-            policy_name=self.cluster.policy.name,
-            trace_name=self.cluster.trace.name,
-            workload_name=self.workload_name,
-            arr=self._arr,
-            svc=self._svc,
-            rcode=np.asarray(self._rcode, dtype=np.int64),
-            n_regions=max(len(self._client_regions), 1),
-            timeout_s=self.timeout_s,
-            concurrency=self.concurrency,
-            lb_kind=self._lb_kind,
-            grid=grid,
-            ready_mask=ready,
-            rtt=rtt,
-            kill_slot=kill_slot,
-            kill_g=kill_g,
-            post_slots=post,
-            base=base,
-            n_slots=len(self._reps),
-            trace_on=self._spans is not None,
-        )
-        self.schedule = sched
-        return sched
+            self._rec = None
+            sched = CellSchedule(
+                policy_name=self.cluster.policy.name,
+                trace_name=self.cluster.trace.name,
+                workload_name=self.workload_name,
+                arr=self._arr,
+                svc=self._svc,
+                rcode=np.asarray(self._rcode, dtype=np.int64),
+                n_regions=max(len(self._client_regions), 1),
+                timeout_s=self.timeout_s,
+                concurrency=self.concurrency,
+                lb_kind=self._lb_kind,
+                grid=grid,
+                ready_mask=ready,
+                rtt=rtt,
+                kill_slot=kill_slot,
+                kill_g=kill_g,
+                post_slots=post,
+                base=base,
+                n_slots=len(self._reps),
+                trace_on=self._spans is not None,
+            )
+            self.schedule = sched
+            return sched
 
     def _fallback_run(
         self, duration_s: Optional[float]
@@ -294,59 +296,60 @@ def run_schedules(
         groups.setdefault(key, []).append(idx)
 
     for (gsig, C, lb_kind, expire_on, trace_on), idxs in groups.items():
-        cells = [scheds[i] for i in idxs]
-        g = cells[0].grid
-        N = max(c.n for c in cells)
-        R = max(c.n_slots for c in cells)
-        E = max(c.n_events for c in cells)
-        NREG = max(c.n_regions for c in cells)
-        L = len(cells)
-        lanes = {
-            "arr": np.full((L, N), np.inf),
-            "svc": np.ones((L, N)),
-            "rcode": np.zeros((L, N), dtype=np.int64),
-            "rtt": np.zeros((L, R, NREG)),
-            "ready": np.zeros((L, g.ticks, R), dtype=bool),
-            "kill_slot": np.zeros((L, max(E, 1)), dtype=np.int64),
-            "kill_g": np.full(
-                (L, max(E, 1)), g.n_points, dtype=np.int64
-            ),
-            "timeout": np.zeros(L),
-        }
-        amax, atyp = 1, 1
-        for li, c in enumerate(cells):
-            lanes["arr"][li, : c.n] = c.arr
-            lanes["svc"][li, : c.n] = c.svc
-            lanes["rcode"][li, : c.n] = c.rcode
-            lanes["rtt"][li, : c.n_slots, : c.n_regions] = c.rtt
-            lanes["ready"][li, :, : c.n_slots] = c.ready_mask
-            lanes["kill_slot"][li, : c.n_events] = c.kill_slot
-            lanes["kill_g"][li, : c.n_events] = c.kill_g
-            lanes["timeout"][li] = c.timeout_s
-            # exact per-sub-step arrival bound: sizes the kernel's masked
-            # dispatch/start scans (backlog spikes spill to the remainder
-            # loop, so this is a performance knob, not a correctness one)
-            counts = np.diff(
-                np.searchsorted(c.arr, g.ts, side="right"), prepend=0
+        with hostspan.host_span(hostspan.PACK):
+            cells = [scheds[i] for i in idxs]
+            g = cells[0].grid
+            N = max(c.n for c in cells)
+            R = max(c.n_slots for c in cells)
+            E = max(c.n_events for c in cells)
+            NREG = max(c.n_regions for c in cells)
+            L = len(cells)
+            lanes = {
+                "arr": np.full((L, N), np.inf),
+                "svc": np.ones((L, N)),
+                "rcode": np.zeros((L, N), dtype=np.int64),
+                "rtt": np.zeros((L, R, NREG)),
+                "ready": np.zeros((L, g.ticks, R), dtype=bool),
+                "kill_slot": np.zeros((L, max(E, 1)), dtype=np.int64),
+                "kill_g": np.full(
+                    (L, max(E, 1)), g.n_points, dtype=np.int64
+                ),
+                "timeout": np.zeros(L),
+            }
+            amax, atyp = 1, 1
+            for li, c in enumerate(cells):
+                lanes["arr"][li, : c.n] = c.arr
+                lanes["svc"][li, : c.n] = c.svc
+                lanes["rcode"][li, : c.n] = c.rcode
+                lanes["rtt"][li, : c.n_slots, : c.n_regions] = c.rtt
+                lanes["ready"][li, :, : c.n_slots] = c.ready_mask
+                lanes["kill_slot"][li, : c.n_events] = c.kill_slot
+                lanes["kill_g"][li, : c.n_events] = c.kill_g
+                lanes["timeout"][li] = c.timeout_s
+                # exact per-sub-step arrival bound: sizes the kernel's masked
+                # dispatch/start scans (backlog spikes spill to the remainder
+                # loop, so this is a performance knob, not a correctness one)
+                counts = np.diff(
+                    np.searchsorted(c.arr, g.ts, side="right"), prepend=0
+                )
+                if counts.size:
+                    amax = max(amax, int(counts.max()))
+                    atyp = max(atyp, int(np.percentile(counts, 99)))
+            key = K.KernelKey(
+                G=g.n_points,
+                W=g.ticks,
+                N=N,
+                R=R,
+                Q=queue_capacity,
+                C=C,
+                NREG=NREG,
+                E=E,
+                AMAX=amax,
+                ATYP=atyp,
+                lb_rr=(lb_kind == "rr"),
+                expire_on=expire_on,
+                trace_on=trace_on,
             )
-            if counts.size:
-                amax = max(amax, int(counts.max()))
-                atyp = max(atyp, int(np.percentile(counts, 99)))
-        key = K.KernelKey(
-            G=g.n_points,
-            W=g.ticks,
-            N=N,
-            R=R,
-            Q=queue_capacity,
-            C=C,
-            NREG=NREG,
-            E=E,
-            AMAX=amax,
-            ATYP=atyp,
-            lb_rr=(lb_kind == "rr"),
-            expire_on=expire_on,
-            trace_on=trace_on,
-        )
         out = K.run_group(
             key,
             lanes,
@@ -354,13 +357,14 @@ def run_schedules(
             np.arange(g.n_points, dtype=np.int64),
             g.win_of,
         )
-        for li, i in enumerate(idxs):
-            if bool(out["overflow"][li]):
-                continue     # caller falls back to the oracle
-            lane_out = {k2: v[li] for k2, v in out.items()}
-            results[i] = assemble_result(cells[li], lane_out)
-            if outputs is not None:
-                outputs[i] = lane_out
+        with hostspan.host_span(hostspan.ASSEMBLE):
+            for li, i in enumerate(idxs):
+                if bool(out["overflow"][li]):
+                    continue     # caller falls back to the oracle
+                lane_out = {k2: v[li] for k2, v in out.items()}
+                results[i] = assemble_result(cells[li], lane_out)
+                if outputs is not None:
+                    outputs[i] = lane_out
     return results
 
 
@@ -421,7 +425,8 @@ def run_cells(
         if eng._token_cfg is not None:
             # continuous batching stays on the NumPy path
             eng.obs.registry.inc(FALLBACK_COUNTER, reason="token")
-            results[i] = VectorizedServingEngine.run(eng, dur)
+            with hostspan.host_span(hostspan.FALLBACK):
+                results[i] = VectorizedServingEngine.run(eng, dur)
         else:
             scheds.append(eng.record_schedule(dur))
             jax_idx.append(i)
@@ -432,18 +437,20 @@ def run_cells(
         )
         outs: List[Optional[dict]] = []
         group = run_schedules(scheds, queue_capacity=cap, outputs=outs)
-        for k, (i, res) in enumerate(zip(jax_idx, group)):
-            if res is None:     # queue pool overflow → oracle rerun
-                # the rerun's own recorder rides on its result
-                res = engines[i]._fallback_run(durations[i])
-            else:
-                obs = engines[i].obs
-                if outs[k] is not None:
-                    _reconstruct_spans(engines[i], scheds[k], outs[k])
-                res = dataclasses.replace(
-                    res,
-                    metrics=obs.registry.snapshot() or None,
-                    obs=obs if obs.enabled else None,
-                )
-            results[i] = res
+        with hostspan.host_span(hostspan.ASSEMBLE):
+            for k, (i, res) in enumerate(zip(jax_idx, group)):
+                if res is None:     # queue pool overflow → oracle rerun
+                    # the rerun's own recorder rides on its result
+                    with hostspan.host_span(hostspan.FALLBACK):
+                        res = engines[i]._fallback_run(durations[i])
+                else:
+                    obs = engines[i].obs
+                    if outs[k] is not None:
+                        _reconstruct_spans(engines[i], scheds[k], outs[k])
+                    res = dataclasses.replace(
+                        res,
+                        metrics=obs.registry.snapshot() or None,
+                        obs=obs if obs.enabled else None,
+                    )
+                results[i] = res
     return results

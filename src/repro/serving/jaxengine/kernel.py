@@ -48,6 +48,11 @@ while-loop *remainder* that only spins on rare backlog spikes (outage
 recovery, kill re-pends), and queue expiry clears a whole hit slot per
 iteration.  Kills stay a plain while loop — they are control-plane-rare.
 
+Each of the step's six stages (``kill``, ``arrive``, ``dispatch``,
+``complete``, ``expire``, ``start``) runs under a ``jax.named_scope`` of
+that name, which a profile reads from each operation's metadata to
+charge it to its stage; the scopes change nothing else.
+
 A lane whose queue pool would overflow sets a flag; the facade reruns
 that cell on the NumPy oracle, so capacity is a performance knob, never a
 correctness one.
@@ -63,6 +68,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro.obs import hostspan
 
 # float64 parity with the NumPy engines is scoped to run_group's
 # jax.enable_x64(True) context — the Pallas model kernels elsewhere in
@@ -184,293 +191,300 @@ def _build_kernel(key: KernelKey):
             s = {k: st[k] for k in small}
 
             # -- 1) kill events due before this sub-step ----------------
-            if E > 0:
-                def kill_cond(s):
-                    kp = jnp.minimum(s["kill_ptr"], E - 1)
-                    return (s["kill_ptr"] < E) & (kill_g[kp] <= g)
+            with jax.named_scope("kill"):
+                if E > 0:
+                    def kill_cond(s):
+                        kp = jnp.minimum(s["kill_ptr"], E - 1)
+                        return (s["kill_ptr"] < E) & (kill_g[kp] <= g)
 
-                def kill_body(s):
-                    kp = s["kill_ptr"]
-                    slot = kill_slot[kp]
-                    s = dict(s)
-                    s["n_retried"] = (
-                        s["n_retried"] + s["run_n"][slot] + s["q_cnt"][slot]
-                    )
-                    # in-flight work re-pends first, in start order
-                    for c in range(C):
-                        take = c < s["run_n"][slot]
-                        pos = (s["p_head"] + s["p_cnt"]) % N
-                        s["pend"] = s["pend"].at[pos].set(
-                            jnp.where(take, s["run_idx"][slot, c],
-                                      s["pend"][pos])
+                    def kill_body(s):
+                        kp = s["kill_ptr"]
+                        slot = kill_slot[kp]
+                        s = dict(s)
+                        s["n_retried"] = (
+                            s["n_retried"] + s["run_n"][slot]
+                            + s["q_cnt"][slot]
                         )
-                        s["p_cnt"] = s["p_cnt"] + take
-                    # then the queue, FIFO
+                        # in-flight work re-pends first, in start order
+                        for c in range(C):
+                            take = c < s["run_n"][slot]
+                            pos = (s["p_head"] + s["p_cnt"]) % N
+                            s["pend"] = s["pend"].at[pos].set(
+                                jnp.where(take, s["run_idx"][slot, c],
+                                          s["pend"][pos])
+                            )
+                            s["p_cnt"] = s["p_cnt"] + take
+                        # then the queue, FIFO
 
-                    def qm_cond(s2):
-                        return s2["q_cnt"][slot] > 0
+                        def qm_cond(s2):
+                            return s2["q_cnt"][slot] > 0
 
-                    def qm_body(s2):
-                        seqs = jnp.where(
-                            s2["q_valid"][slot], s2["q_seq"][slot], _BIG_I
-                        )
-                        j = jnp.argmin(seqs)
-                        s2 = _pend_push(s2, s2["q_idx"][slot, j])
-                        return _q_pop(s2, slot, j)
+                        def qm_body(s2):
+                            seqs = jnp.where(
+                                s2["q_valid"][slot], s2["q_seq"][slot], _BIG_I
+                            )
+                            j = jnp.argmin(seqs)
+                            s2 = _pend_push(s2, s2["q_idx"][slot, j])
+                            return _q_pop(s2, slot, j)
 
-                    s = lax.while_loop(qm_cond, qm_body, s)
-                    s = dict(s)
-                    s["run_fin"] = s["run_fin"].at[slot].set(jnp.inf)
-                    s["run_n"] = s["run_n"].at[slot].set(0)
-                    s["kill_ptr"] = kp + 1
-                    return s
+                        s = lax.while_loop(qm_cond, qm_body, s)
+                        s = dict(s)
+                        s["run_fin"] = s["run_fin"].at[slot].set(jnp.inf)
+                        s["run_n"] = s["run_n"].at[slot].set(0)
+                        s["kill_ptr"] = kp + 1
+                        return s
 
-                s = lax.while_loop(kill_cond, kill_body, s)
+                    s = lax.while_loop(kill_cond, kill_body, s)
 
             # -- 2) arrivals (vectorized: ≤ AMAX per sub-step by
             #       construction; the flag is insurance, not a path) -----
-            new_ptr = jnp.searchsorted(arr, t, side="right").astype(
-                jnp.int64
-            )
-            cnt = new_ptr - s["a_ptr"]
-            ks = jnp.arange(AMAX, dtype=jnp.int64)
-            src = s["a_ptr"] + ks
-            valid = src < new_ptr
-            pos = jnp.where(valid, (s["p_head"] + s["p_cnt"] + ks) % N, N)
-            s["pend"] = s["pend"].at[pos].set(src)
-            s["p_cnt"] = s["p_cnt"] + cnt
-            s["a_ptr"] = new_ptr
-            s["overflow"] = s["overflow"] | (cnt > AMAX)
+            with jax.named_scope("arrive"):
+                new_ptr = jnp.searchsorted(arr, t, side="right").astype(
+                    jnp.int64
+                )
+                cnt = new_ptr - s["a_ptr"]
+                ks = jnp.arange(AMAX, dtype=jnp.int64)
+                src = s["a_ptr"] + ks
+                valid = src < new_ptr
+                pos = jnp.where(valid, (s["p_head"] + s["p_cnt"] + ks) % N, N)
+                s["pend"] = s["pend"].at[pos].set(src)
+                s["p_cnt"] = s["p_cnt"] + cnt
+                s["a_ptr"] = new_ptr
+                s["overflow"] = s["overflow"] | (cnt > AMAX)
 
             # -- 3) due + dispatch --------------------------------------
-            ready = ready_mask[win]
-            nready = ready.sum()
-            due = (s["run_fin"] <= t).any(axis=1)   # pads/empties are +inf
+            with jax.named_scope("dispatch"):
+                ready = ready_mask[win]
+                nready = ready.sum()
+                due = (s["run_fin"] <= t).any(axis=1)   # pads/empties are +inf
 
-            def disp_body(s, act):
-                s = dict(s)
-                i = s["pend"][s["p_head"]]
-                s["p_head"] = (s["p_head"] + jnp.where(act, 1, 0)) % N
-                s["p_cnt"] = s["p_cnt"] - jnp.where(act, 1, 0)
-                expired = t - arr[i] > timeout
-                loads = s["run_n"] + s["q_cnt"]
-                rc = rcode[i]
-                if lb_rr:
-                    # nready==0 only reaches here masked (act False)
-                    j = s["rr_cur"] % jnp.maximum(nready, 1)
-                    slot = jnp.argmax(jnp.cumsum(ready) == j + 1)
-                    s["rr_cur"] = s["rr_cur"] + jnp.where(
-                        act & (~expired), 1, 0
+                def disp_body(s, act):
+                    s = dict(s)
+                    i = s["pend"][s["p_head"]]
+                    s["p_head"] = (s["p_head"] + jnp.where(act, 1, 0)) % N
+                    s["p_cnt"] = s["p_cnt"] - jnp.where(act, 1, 0)
+                    expired = t - arr[i] > timeout
+                    loads = s["run_n"] + s["q_cnt"]
+                    rc = rcode[i]
+                    if lb_rr:
+                        # nready==0 only reaches here masked (act False)
+                        j = s["rr_cur"] % jnp.maximum(nready, 1)
+                        slot = jnp.argmax(jnp.cumsum(ready) == j + 1)
+                        s["rr_cur"] = s["rr_cur"] + jnp.where(
+                            act & (~expired), 1, 0
+                        )
+                    else:
+                        # least-loaded: lexicographic argmin over (load, rtt);
+                        # ready order == slot order == id order, so the
+                        # first-index tie-break IS the oracle's id tie-break
+                        col = rtt[:, rc]
+                        lmask = jnp.where(ready, loads, _BIG_I)
+                        c1 = ready & (loads == lmask.min())
+                        colm = jnp.where(c1, col, jnp.inf)
+                        c2 = c1 & (col == colm.min())
+                        slot = jnp.argmax(c2)
+                    rn = s["run_n"][slot]
+                    imm = (s["q_cnt"][slot] == 0) & (rn < C) & (~due[slot])
+                    do_start = act & (~expired) & imm
+                    do_queue = act & (~expired) & (~imm)
+                    # immediate start (queue-then-start within this sub-step)
+                    rn_c = jnp.minimum(rn, C - 1)
+                    fin = t + svc[i] * (1.0 + 0.15 * rn)
+                    s["run_fin"] = s["run_fin"].at[slot, rn_c].set(
+                        jnp.where(do_start, fin, s["run_fin"][slot, rn_c])
                     )
-                else:
-                    # least-loaded: lexicographic argmin over (load, rtt);
-                    # ready order == slot order == id order, so the
-                    # first-index tie-break IS the oracle's id tie-break
-                    col = rtt[:, rc]
-                    lmask = jnp.where(ready, loads, _BIG_I)
-                    c1 = ready & (loads == lmask.min())
-                    colm = jnp.where(c1, col, jnp.inf)
-                    c2 = c1 & (col == colm.min())
-                    slot = jnp.argmax(c2)
-                rn = s["run_n"][slot]
-                imm = (s["q_cnt"][slot] == 0) & (rn < C) & (~due[slot])
-                do_start = act & (~expired) & imm
-                do_queue = act & (~expired) & (~imm)
-                # immediate start (queue-then-start within this sub-step)
-                rn_c = jnp.minimum(rn, C - 1)
-                fin = t + svc[i] * (1.0 + 0.15 * rn)
-                s["run_fin"] = s["run_fin"].at[slot, rn_c].set(
-                    jnp.where(do_start, fin, s["run_fin"][slot, rn_c])
-                )
-                s["run_idx"] = s["run_idx"].at[slot, rn_c].set(
-                    jnp.where(do_start, i, s["run_idx"][slot, rn_c])
-                )
-                s["run_n"] = s["run_n"].at[slot].add(do_start)
-                if trace_on:
-                    s["run_disp"] = s["run_disp"].at[slot, rn_c].set(
-                        jnp.where(do_start, t, s["run_disp"][slot, rn_c])
+                    s["run_idx"] = s["run_idx"].at[slot, rn_c].set(
+                        jnp.where(do_start, i, s["run_idx"][slot, rn_c])
                     )
-                    s["run_start"] = s["run_start"].at[slot, rn_c].set(
-                        jnp.where(do_start, t, s["run_start"][slot, rn_c])
+                    s["run_n"] = s["run_n"].at[slot].add(do_start)
+                    if trace_on:
+                        s["run_disp"] = s["run_disp"].at[slot, rn_c].set(
+                            jnp.where(do_start, t, s["run_disp"][slot, rn_c])
+                        )
+                        s["run_start"] = s["run_start"].at[slot, rn_c].set(
+                            jnp.where(do_start, t, s["run_start"][slot, rn_c])
+                        )
+                    # queue append with effective age (arrival − rtt): the
+                    # shared `t - age > timeout` sweep is then RTT-inclusive
+                    age = arr[i] - rtt[slot, rc]
+                    free = jnp.argmin(s["q_valid"][slot])      # first False
+                    s["overflow"] = s["overflow"] | (
+                        do_queue & s["q_valid"][slot].all()
                     )
-                # queue append with effective age (arrival − rtt): the
-                # shared `t - age > timeout` sweep is then RTT-inclusive
-                age = arr[i] - rtt[slot, rc]
-                free = jnp.argmin(s["q_valid"][slot])      # first False
-                s["overflow"] = s["overflow"] | (
-                    do_queue & s["q_valid"][slot].all()
-                )
-                s["q_idx"] = s["q_idx"].at[slot, free].set(
-                    jnp.where(do_queue, i, s["q_idx"][slot, free])
-                )
-                s["q_age"] = s["q_age"].at[slot, free].set(
-                    jnp.where(do_queue, age, s["q_age"][slot, free])
-                )
-                if trace_on:
-                    s["q_disp"] = s["q_disp"].at[slot, free].set(
-                        jnp.where(do_queue, t, s["q_disp"][slot, free])
+                    s["q_idx"] = s["q_idx"].at[slot, free].set(
+                        jnp.where(do_queue, i, s["q_idx"][slot, free])
                     )
-                s["q_seq"] = s["q_seq"].at[slot, free].set(
-                    jnp.where(do_queue, s["seq_ctr"],
-                              s["q_seq"][slot, free])
-                )
-                s["q_valid"] = s["q_valid"].at[slot, free].set(
-                    s["q_valid"][slot, free] | do_queue
-                )
-                s["q_cnt"] = s["q_cnt"].at[slot].add(do_queue)
-                s["qmin"] = s["qmin"].at[slot].set(
-                    jnp.where(
-                        do_queue,
-                        jnp.minimum(s["qmin"][slot], age),
-                        s["qmin"][slot],
+                    s["q_age"] = s["q_age"].at[slot, free].set(
+                        jnp.where(do_queue, age, s["q_age"][slot, free])
                     )
-                )
-                s["seq_ctr"] = s["seq_ctr"] + do_queue
-                # a lazily-expired pending entry is simply dropped here:
-                # status stays 0 and the drain counts it failed
-                return s
+                    if trace_on:
+                        s["q_disp"] = s["q_disp"].at[slot, free].set(
+                            jnp.where(do_queue, t, s["q_disp"][slot, free])
+                        )
+                    s["q_seq"] = s["q_seq"].at[slot, free].set(
+                        jnp.where(do_queue, s["seq_ctr"],
+                                  s["q_seq"][slot, free])
+                    )
+                    s["q_valid"] = s["q_valid"].at[slot, free].set(
+                        s["q_valid"][slot, free] | do_queue
+                    )
+                    s["q_cnt"] = s["q_cnt"].at[slot].add(do_queue)
+                    s["qmin"] = s["qmin"].at[slot].set(
+                        jnp.where(
+                            do_queue,
+                            jnp.minimum(s["qmin"][slot], age),
+                            s["qmin"][slot],
+                        )
+                    )
+                    s["seq_ctr"] = s["seq_ctr"] + do_queue
+                    # a lazily-expired pending entry is simply dropped here:
+                    # status stays 0 and the drain counts it failed
+                    return s
 
-            def disp_cond(s):
-                return (s["p_cnt"] > 0) & (nready > 0)
+                def disp_cond(s):
+                    return (s["p_cnt"] > 0) & (nready > 0)
 
-            def disp_chunk(s, _):
-                # K masked pops per iteration: the per-iteration fixed
-                # cost (op dispatch dominates on CPU) amortizes over K
-                for _k in range(_UNROLL):
-                    s = disp_body(s, disp_cond(s))
-                return s, None
+                def disp_chunk(s, _):
+                    # K masked pops per iteration: the per-iteration fixed
+                    # cost (op dispatch dominates on CPU) amortizes over K
+                    for _k in range(_UNROLL):
+                        s = disp_body(s, disp_cond(s))
+                    return s, None
 
-            s, _ = lax.scan(disp_chunk, s, None, length=NCHUNK)
-            # tail remainder (Poisson spikes, outage recovery, kill
-            # re-pends) — chunked so carry copies stay few
-            s = lax.while_loop(
-                disp_cond, lambda s: disp_chunk(s, None)[0], s
-            )
+                s, _ = lax.scan(disp_chunk, s, None, length=NCHUNK)
+                # tail remainder (Poisson spikes, outage recovery, kill
+                # re-pends) — chunked so carry copies stay few
+                s = lax.while_loop(
+                    disp_cond, lambda s: disp_chunk(s, None)[0], s
+                )
 
             # -- 4) completions (every entry with finish <= t) ----------
-            fin = s["run_fin"]
-            done = fin <= t
-            idxs = s["run_idx"]
-            e2e_v = (fin - arr[idxs]) + rtt[
-                jnp.arange(R)[:, None], rcode[idxs]
-            ]
-            scat = jnp.where(done, idxs, N).ravel()
-            verdict = jnp.where(e2e_v > timeout, 2, 1).astype(jnp.int8)
-            status = st["status"].at[scat].set(verdict.ravel())
-            e2e = st["e2e"].at[scat].set(e2e_v.ravel())
-            if trace_on:
-                # resolve the span timeline at the same scatter (a killed
-                # request overwrites on its retry, so these record the
-                # final — completing — attempt)
-                slot_ids = jnp.broadcast_to(
-                    jnp.arange(R, dtype=jnp.int64)[:, None], (R, C)
+            with jax.named_scope("complete"):
+                fin = s["run_fin"]
+                done = fin <= t
+                idxs = s["run_idx"]
+                e2e_v = (fin - arr[idxs]) + rtt[
+                    jnp.arange(R)[:, None], rcode[idxs]
+                ]
+                scat = jnp.where(done, idxs, N).ravel()
+                verdict = jnp.where(e2e_v > timeout, 2, 1).astype(jnp.int8)
+                status = st["status"].at[scat].set(verdict.ravel())
+                e2e = st["e2e"].at[scat].set(e2e_v.ravel())
+                if trace_on:
+                    # resolve the span timeline at the same scatter (a killed
+                    # request overwrites on its retry, so these record the
+                    # final — completing — attempt)
+                    slot_ids = jnp.broadcast_to(
+                        jnp.arange(R, dtype=jnp.int64)[:, None], (R, C)
+                    )
+                    trace_out = {
+                        "disp_t": st["disp_t"].at[scat].set(
+                            s["run_disp"].ravel()
+                        ),
+                        "start_t": st["start_t"].at[scat].set(
+                            s["run_start"].ravel()
+                        ),
+                        "rep": st["rep"].at[scat].set(slot_ids.ravel()),
+                        "fin_t": st["fin_t"].at[scat].set(fin.ravel()),
+                    }
+                order = jnp.argsort(done.astype(jnp.int8), axis=1,
+                                    stable=True)         # keep start order
+                s["run_fin"] = jnp.take_along_axis(
+                    jnp.where(done, jnp.inf, fin), order, axis=1
                 )
-                trace_out = {
-                    "disp_t": st["disp_t"].at[scat].set(
-                        s["run_disp"].ravel()
-                    ),
-                    "start_t": st["start_t"].at[scat].set(
-                        s["run_start"].ravel()
-                    ),
-                    "rep": st["rep"].at[scat].set(slot_ids.ravel()),
-                    "fin_t": st["fin_t"].at[scat].set(fin.ravel()),
-                }
-            order = jnp.argsort(done.astype(jnp.int8), axis=1,
-                                stable=True)         # keep start order
-            s["run_fin"] = jnp.take_along_axis(
-                jnp.where(done, jnp.inf, fin), order, axis=1
-            )
-            s["run_idx"] = jnp.take_along_axis(idxs, order, axis=1)
-            s["run_n"] = s["run_n"] - done.sum(axis=1)
-            if trace_on:
-                # compact the timelines in lockstep with run_fin/run_idx
-                s["run_disp"] = jnp.take_along_axis(
-                    s["run_disp"], order, axis=1
-                )
-                s["run_start"] = jnp.take_along_axis(
-                    s["run_start"], order, axis=1
-                )
+                s["run_idx"] = jnp.take_along_axis(idxs, order, axis=1)
+                s["run_n"] = s["run_n"] - done.sum(axis=1)
+                if trace_on:
+                    # compact the timelines in lockstep with run_fin/run_idx
+                    s["run_disp"] = jnp.take_along_axis(
+                        s["run_disp"], order, axis=1
+                    )
+                    s["run_start"] = jnp.take_along_axis(
+                        s["run_start"], order, axis=1
+                    )
 
             # -- 5) queue expiry (RTT-inclusive; O(R) guard per step,
             #       one whole slot cleared per iteration) ---------------
-            if expire_on:
-                q_age_c = s["q_age"]     # append-only within this stage
+            with jax.named_scope("expire"):
+                if expire_on:
+                    q_age_c = s["q_age"]     # append-only within this stage
 
-                def exp_cond(e):
-                    hit = (e["q_cnt"] > 0) & (t - e["qmin"] > timeout)
-                    return hit.any()
+                    def exp_cond(e):
+                        hit = (e["q_cnt"] > 0) & (t - e["qmin"] > timeout)
+                        return hit.any()
 
-                def exp_body(e):
-                    hit = (e["q_cnt"] > 0) & (t - e["qmin"] > timeout)
-                    slot = jnp.argmax(hit)
-                    vrow = e["q_valid"][slot]
-                    drop = vrow & (t - q_age_c[slot] > timeout)
-                    nv = vrow & ~drop
-                    ages = jnp.where(nv, q_age_c[slot], jnp.inf)
-                    e = dict(e)
-                    e["q_valid"] = e["q_valid"].at[slot].set(nv)
-                    e["q_cnt"] = e["q_cnt"].at[slot].set(nv.sum())
-                    e["qmin"] = e["qmin"].at[slot].set(ages.min())
-                    return e
+                    def exp_body(e):
+                        hit = (e["q_cnt"] > 0) & (t - e["qmin"] > timeout)
+                        slot = jnp.argmax(hit)
+                        vrow = e["q_valid"][slot]
+                        drop = vrow & (t - q_age_c[slot] > timeout)
+                        nv = vrow & ~drop
+                        ages = jnp.where(nv, q_age_c[slot], jnp.inf)
+                        e = dict(e)
+                        e["q_valid"] = e["q_valid"].at[slot].set(nv)
+                        e["q_cnt"] = e["q_cnt"].at[slot].set(nv.sum())
+                        e["qmin"] = e["qmin"].at[slot].set(ages.min())
+                        return e
 
-                sub = {k: s[k] for k in ("q_valid", "q_cnt", "qmin")}
-                s.update(lax.while_loop(exp_cond, exp_body, sub))
+                    sub = {k: s[k] for k in ("q_valid", "q_cnt", "qmin")}
+                    s.update(lax.while_loop(exp_cond, exp_body, sub))
 
             # -- 6) starts (drain queues into freed capacity) -----------
-            def start_body(s, act):
-                can = ready & (s["run_n"] < C) & (s["q_cnt"] > 0)
-                act = act & can.any()
-                slot = jnp.argmax(can)
-                seqs = jnp.where(
-                    s["q_valid"][slot], s["q_seq"][slot], _BIG_I
-                )
-                j = jnp.argmin(seqs)
-                i = s["q_idx"][slot, j]
-                rn = s["run_n"][slot]
-                rn_c = jnp.minimum(rn, C - 1)
-                fin_t = t + svc[i] * (1.0 + 0.15 * rn)
-                s = dict(s)
-                s["run_fin"] = s["run_fin"].at[slot, rn_c].set(
-                    jnp.where(act, fin_t, s["run_fin"][slot, rn_c])
-                )
-                s["run_idx"] = s["run_idx"].at[slot, rn_c].set(
-                    jnp.where(act, i, s["run_idx"][slot, rn_c])
-                )
-                s["run_n"] = s["run_n"].at[slot].add(act)
-                if trace_on:
-                    s["run_disp"] = s["run_disp"].at[slot, rn_c].set(
-                        jnp.where(act, s["q_disp"][slot, j],
-                                  s["run_disp"][slot, rn_c])
+            with jax.named_scope("start"):
+                def start_body(s, act):
+                    can = ready & (s["run_n"] < C) & (s["q_cnt"] > 0)
+                    act = act & can.any()
+                    slot = jnp.argmax(can)
+                    seqs = jnp.where(
+                        s["q_valid"][slot], s["q_seq"][slot], _BIG_I
                     )
-                    s["run_start"] = s["run_start"].at[slot, rn_c].set(
-                        jnp.where(act, t, s["run_start"][slot, rn_c])
+                    j = jnp.argmin(seqs)
+                    i = s["q_idx"][slot, j]
+                    rn = s["run_n"][slot]
+                    rn_c = jnp.minimum(rn, C - 1)
+                    fin_t = t + svc[i] * (1.0 + 0.15 * rn)
+                    s = dict(s)
+                    s["run_fin"] = s["run_fin"].at[slot, rn_c].set(
+                        jnp.where(act, fin_t, s["run_fin"][slot, rn_c])
                     )
-                s["q_valid"] = s["q_valid"].at[slot, j].set(
-                    s["q_valid"][slot, j] & (~act)
-                )
-                s["q_cnt"] = s["q_cnt"].at[slot].add(
-                    jnp.where(act, -1, 0)
-                )
-                ages = jnp.where(s["q_valid"][slot], s["q_age"][slot],
-                                 jnp.inf)
-                s["qmin"] = s["qmin"].at[slot].set(
-                    jnp.where(act, ages.min(), s["qmin"][slot])
-                )
-                return s
+                    s["run_idx"] = s["run_idx"].at[slot, rn_c].set(
+                        jnp.where(act, i, s["run_idx"][slot, rn_c])
+                    )
+                    s["run_n"] = s["run_n"].at[slot].add(act)
+                    if trace_on:
+                        s["run_disp"] = s["run_disp"].at[slot, rn_c].set(
+                            jnp.where(act, s["q_disp"][slot, j],
+                                      s["run_disp"][slot, rn_c])
+                        )
+                        s["run_start"] = s["run_start"].at[slot, rn_c].set(
+                            jnp.where(act, t, s["run_start"][slot, rn_c])
+                        )
+                    s["q_valid"] = s["q_valid"].at[slot, j].set(
+                        s["q_valid"][slot, j] & (~act)
+                    )
+                    s["q_cnt"] = s["q_cnt"].at[slot].add(
+                        jnp.where(act, -1, 0)
+                    )
+                    ages = jnp.where(s["q_valid"][slot], s["q_age"][slot],
+                                     jnp.inf)
+                    s["qmin"] = s["qmin"].at[slot].set(
+                        jnp.where(act, ages.min(), s["qmin"][slot])
+                    )
+                    return s
 
-            def start_cond(s):
-                can = ready & (s["run_n"] < C) & (s["q_cnt"] > 0)
-                return can.any()
+                def start_cond(s):
+                    can = ready & (s["run_n"] < C) & (s["q_cnt"] > 0)
+                    return can.any()
 
-            def start_chunk(s, _):
-                for _k in range(_UNROLL):
-                    s = start_body(s, jnp.bool_(True))
-                return s, None
+                def start_chunk(s, _):
+                    for _k in range(_UNROLL):
+                        s = start_body(s, jnp.bool_(True))
+                    return s, None
 
-            s, _ = lax.scan(start_chunk, s, None, length=NCHUNK)
-            s = lax.while_loop(
-                start_cond, lambda s: start_chunk(s, None)[0], s
-            )
+                s, _ = lax.scan(start_chunk, s, None, length=NCHUNK)
+                s = lax.while_loop(
+                    start_cond, lambda s: start_chunk(s, None)[0], s
+                )
 
             st = dict(st)
             st.update(s)
@@ -530,17 +544,22 @@ def run_group(
     # trace, compile and execute under x64 (the jit cache keys on the
     # flag, so every call sees one consistent dtype world)
     with jax.enable_x64(True):
-        out = kern(
-            jnp.asarray(lanes["arr"]),
-            jnp.asarray(lanes["svc"]),
-            jnp.asarray(lanes["rcode"]),
-            jnp.asarray(lanes["rtt"]),
-            jnp.asarray(lanes["ready"]),
-            jnp.asarray(lanes["kill_slot"]),
-            jnp.asarray(lanes["kill_g"]),
-            jnp.asarray(lanes["timeout"]),
-            jnp.asarray(ts),
-            jnp.asarray(gs),
-            jnp.asarray(wins),
-        )
-        return {k2: np.asarray(v) for k2, v in out.items()}
+        with hostspan.host_span(hostspan.TO_DEVICE):
+            args = (
+                jnp.asarray(lanes["arr"]),
+                jnp.asarray(lanes["svc"]),
+                jnp.asarray(lanes["rcode"]),
+                jnp.asarray(lanes["rtt"]),
+                jnp.asarray(lanes["ready"]),
+                jnp.asarray(lanes["kill_slot"]),
+                jnp.asarray(lanes["kill_g"]),
+                jnp.asarray(lanes["timeout"]),
+                jnp.asarray(ts),
+                jnp.asarray(gs),
+                jnp.asarray(wins),
+            )
+        # the wait for the outputs is the one np.asarray would make
+        with hostspan.host_span(hostspan.EXECUTE):
+            out = jax.block_until_ready(kern(*args))
+        with hostspan.host_span(hostspan.FROM_DEVICE):
+            return {k2: np.asarray(v) for k2, v in out.items()}

@@ -90,6 +90,35 @@ def test_flash_decode_compiles(one_chip):
     assert "tpu_custom_call" in txt
 
 
+@pytest.mark.parametrize("mode", ["prefill", "decode"])
+def test_model_step_names_its_kernels(one_chip, mode):
+    """The served step (smoke command-r, ``impl="pallas"``) compiles each
+    kernel call to a custom call named after the kernel
+    (``flash_attention.N``, ``flash_decode.N``) whose scope lies under the
+    layer scan's ``attention``: the names a profile's readings match."""
+    import re
+
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+
+    model = build_model(get_smoke_config("command-r-35b"), impl="pallas")
+    params, cache = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        (model.abstract(jnp.bfloat16), model.abstract_cache(2, 136)),
+    )
+    if mode == "prefill":
+        kernel, fn, S = "flash_attention", model.prefill, 128
+    else:
+        kernel, fn, S = "flash_decode", model.decode_step, 1
+    txt = _compile_text(fn, params, _sds(one_chip, (2, S), jnp.int32), cache)
+    calls = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"'
+                       r'[^\n]*op_name="([^"]*)"', txt)
+    assert calls
+    for name, scope in calls:
+        assert re.fullmatch(rf"{kernel}\.\d+", name), name
+        assert "/layers/" in scope and "/attention/" in scope, scope
+
+
 # (Q, C, N): falcon-mamba-7b (d_inner 8192, state 16) at the scan chunk
 # of the kernel table and of the model, zamba2-7b (d_inner 7168, state 64)
 SCAN_CASES = [(64, 8192, 16), (256, 8192, 16), (64, 7168, 64)]
