@@ -23,8 +23,7 @@ from repro.kernels.selective_scan import selective_scan_bqnc
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "window", "prefix_len", "block_q",
-                     "block_kv", "interpret"),
+    static_argnames=("causal", "window", "prefix_len", "interpret"),
 )
 def flash_attention(
     q: jax.Array,                 # model layout (B, S, H, D)
@@ -34,10 +33,9 @@ def flash_attention(
     causal: bool = True,
     window: Optional[int] = None,
     prefix_len: int = 0,
-    block_q: int = 128,
-    block_kv: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
+    """Tiles are chosen from the shapes (``attention_tiles``)."""
     out = flash_attention_bhsd(
         q.transpose(0, 2, 1, 3),
         k.transpose(0, 2, 1, 3),
@@ -45,8 +43,6 @@ def flash_attention(
         causal=causal,
         window=window,
         prefix_len=prefix_len,
-        block_q=block_q,
-        block_kv=block_kv,
         interpret=interpret,
     )
     return out.transpose(0, 2, 1, 3)

@@ -7,7 +7,15 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention_bhsd
+from repro.kernels.flash_attention import (
+    VMEM_LIMIT_BYTES,
+    attention_tiles,
+    block_needs_mask,
+    flash_attention_bhsd,
+    kv_block_index,
+    live_kv_blocks,
+    vmem_bytes,
+)
 from repro.kernels.flash_decode import flash_decode_bhd
 from repro.kernels.moe_gmm import moe_gmm_ecf
 from repro.kernels.selective_scan import selective_scan_bqnc
@@ -55,6 +63,114 @@ def test_flash_attention_matches_ref(case, dtype):
         got.astype(np.float32), want.astype(np.float32),
         atol=TOLS[dtype], rtol=TOLS[dtype],
     )
+
+
+# command-r-35b's heads (H 64, Kv 8, D 128) and an MQA group, bf16, under
+# the tiles the kernel picks (bq 256 / bkv 1024; bq 32 for G 64)
+SHAPE_CASES = [
+    # (H, Kv, S, window, prefix)
+    (64, 8, 1024, None, 0),      # 4 q tiles, one KV tile
+    (64, 8, 1100, None, 0),      # S a multiple of neither tile
+    (64, 8, 2100, 300, 0),       # late q tiles start past KV tile 0
+    (64, 8, 2100, None, 1100),   # prefix zone reaches into KV tile 1
+    (64, 1, 1100, None, 0),      # MQA: 64 heads fold into 2048 rows
+]
+
+
+@pytest.mark.parametrize("case", SHAPE_CASES)
+def test_flash_attention_chosen_tiles_match_ref(case):
+    H, Kv, S, window, prefix = case
+    dtype, D, G = jnp.bfloat16, 128, H // Kv
+    q = rnd(13, (1, H, S, D), dtype)
+    k = rnd(14, (1, Kv, S, D), dtype)
+    v = rnd(15, (1, Kv, S, D), dtype)
+    got = flash_attention_bhsd(q, k, v, causal=True, window=window,
+                               prefix_len=prefix, interpret=True)
+    # the oracle one KV group at a time, to bound its S x S score tensor
+    want = jnp.concatenate([
+        ref.flash_attention_ref(
+            q[:, g * G:(g + 1) * G], k[:, g:g + 1], v[:, g:g + 1],
+            causal=True, window=window, prefix_len=prefix,
+        ) for g in range(Kv)
+    ], axis=1)
+    np.testing.assert_allclose(
+        got.astype(np.float32), want.astype(np.float32),
+        atol=TOLS[dtype], rtol=TOLS[dtype],
+    )
+
+
+@pytest.mark.parametrize("S,G,D,tiles", [
+    (8192, 8, 128, (256, 1024)),    # replica.prefill
+    (512, 8, 128, (256, 512)),      # replica.decode's prefill
+    (8192, 1, 128, (256, 1024)),    # MHA: q tile capped at 256
+    (8192, 64, 128, (32, 1024)),    # MQA: 2048 folded rows
+    (8192, 16, 256, (128, 1024)),
+    (8192, 8, 512, (128, 1024)),    # VMEM halves the q tile
+    (300, 8, 128, (256, 384)),      # KV tile rounded up to the lanes
+    (100, 8, 64, (112, 128)),       # q tile rounded up to 16 rows
+])
+def test_attention_tiles(S, G, D, tiles):
+    bq, bkv = attention_tiles(S, S, G, D)
+    assert (bq, bkv) == tiles
+    assert bq % 16 == 0 and bkv % 128 == 0
+    assert vmem_bytes(G * bq, bkv, D, 4) <= VMEM_LIMIT_BYTES
+
+
+def test_attention_tiles_at_replica_prefill():
+    """B4, S 8192, 64 heads over 8 KV heads: 8,192 grid steps (the
+    per-head 128 x 128 grid had 1,048,576), in 26 MiB of VMEM in bf16
+    and 33 MiB in f32, against a 48 MiB limit."""
+    bq, bkv = attention_tiles(8192, 8192, 8, 128)
+    assert 4 * 8 * (8192 // bq) * (8192 // bkv) == 8_192
+    assert vmem_bytes(8 * bq, bkv, 128, 2) == 27_262_976
+    assert vmem_bytes(8 * bq, bkv, 128, 4) == 34_603_008
+    assert VMEM_LIMIT_BYTES == 48 << 20
+
+
+MASKINGS = {
+    "causal": dict(causal=True, window=None, prefix_len=0),
+    "window": dict(causal=True, window=700, prefix_len=0),
+    "prefix": dict(causal=True, window=None, prefix_len=1100),
+    "window_prefix": dict(causal=True, window=700, prefix_len=1100),
+    "bidirectional": dict(causal=False, window=None, prefix_len=0),
+}
+
+
+@pytest.mark.parametrize("name", MASKINGS)
+def test_dead_kv_steps_reuse_the_resident_tile(name):
+    """Over every q block of a 3000-position call (bq 256, bkv 512): each
+    block with an unmasked element is live, a live block not flagged as an
+    edge has none masked, and along the KV axis the tile index changes only
+    at live steps, each live tile named once — a dead step names the tile
+    already resident, so Pallas copies nothing for it."""
+    m = MASKINGS[name]
+    S, bq, bkv = 3000, 256, 512
+    nq, nkv = -(-S // bq), -(-S // bkv)
+    tiling = dict(block_q=bq, block_kv=bkv, **m)
+    qp, kp = np.arange(nq * bq)[:, None], np.arange(nkv * bkv)[None, :]
+    mask = (qp < S) & (kp < S)
+    if m["causal"]:
+        mask &= (qp >= kp) | (kp < m["prefix_len"])
+    if m["window"] is not None:
+        mask &= qp - kp < m["window"]
+    dead = 0
+    for i in range(nq):
+        lo, hi = (int(x) for x in live_kv_blocks(i, n_kv=nkv, **tiling))
+        idx = [int(kv_block_index(i, j, n_kv=nkv, **tiling))
+               for j in range(nkv)]
+        for j in range(nkv):
+            blk = mask[i * bq:(i + 1) * bq, j * bkv:(j + 1) * bkv]
+            live = lo <= j <= hi
+            if blk.any():
+                assert live, (i, j)
+            if live and not bool(block_needs_mask(i, j, seq_kv=S,
+                                                  **tiling)):
+                assert blk[:S - i * bq].all(), (i, j)
+            if not live:
+                dead += 1
+                assert idx[j] == (idx[j - 1] if j else lo), (i, j)
+        assert idx[0] == lo and sorted(set(idx)) == list(range(lo, hi + 1))
+    assert dead > 0 or not m["causal"]
 
 
 def test_flash_attention_model_layout_wrapper():

@@ -75,6 +75,25 @@ def test_flash_attention_compiles(one_chip, case):
     assert "tpu_custom_call" in txt
 
 
+def test_flash_attention_compiles_at_replica_prefill(one_chip):
+    """replica.prefill's call, B4 over 8192 positions at command-r-35b's
+    heads, under the tiles the kernel picks (bq 256, bkv 512): the folded
+    2048-row score tile fits the kernel's scoped VMEM, as one kernel."""
+    import re
+
+    B, S = 4, 8192
+    txt = _compile_text(
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=True,
+                                            interpret=False),
+        _sds(one_chip, (B, S, 64, 128)),
+        _sds(one_chip, (B, S, 8, 128)),
+        _sds(one_chip, (B, S, 8, 128)),
+    )
+    calls = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"',
+                       txt)
+    assert len(calls) == 1 and calls[0].startswith("flash_attention."), calls
+
+
 def test_flash_decode_compiles(one_chip):
     """B4 over a 4096-token cache, command-r-35b heads (G = 8)."""
     B, S = 4, 4096
