@@ -191,14 +191,17 @@ def phase_kernels(seed: int) -> None:
     )
     _close("selective_scan", ss(a, b, h0), ref.selective_scan_ref(a, b, h0))
 
-    # grouped matmul: qwen3-moe-30b experts, E128 C128 D2048 F768
-    x = normal((128, 128, 2048))
+    # grouped matmul: qwen3-moe-30b experts, 128 groups of 128 rows,
+    # D2048 F768
+    x = normal((128 * 128, 2048))
     w = normal((128, 2048, 768), scale=2048 ** -0.5)
+    sizes = jnp.full((128,), 128, jnp.int32)
     gm, _ = compile_checked(
-        "b", "moe_gmm", lambda x, w: ops.moe_gmm(x, w, interpret=False),
-        x, w,
+        "b", "moe_gmm",
+        lambda x, w, g: ops.moe_gmm(x, w, g, block_m=128, interpret=False),
+        x, w, sizes,
     )
-    _close("moe_gmm", gm(x, w), ref.moe_gmm_ref(x, w))
+    _close("moe_gmm", gm(x, w, sizes), ref.moe_gmm_ref(x, w, sizes))
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ ARCH_IDS: List[str] = [
     "phi3.5-moe-42b",
     "qwen3-moe-30b",
     "zamba2-7b",
+    "mellum2-12b",
 ]
 
 _MODULES: Dict[str, str] = {
@@ -37,6 +38,7 @@ _MODULES: Dict[str, str] = {
     "phi3.5-moe-42b": "phi3_5_moe_42b",
     "qwen3-moe-30b": "qwen3_moe_30b",
     "zamba2-7b": "zamba2_7b",
+    "mellum2-12b": "mellum2_12b",
 }
 
 

@@ -20,7 +20,8 @@ Kernels:
 * ``flash_decode``     — one-query-token attention vs. a long KV cache,
   blocked over KV with running max/denominator.
 * ``selective_scan``   — Mamba-1 within-chunk recurrence h' = a·h + b.
-* ``moe_gmm``          — grouped (per-expert) matmul for MoE FFNs.
+* ``moe_gmm``          — grouped matmul over ragged per-expert row groups
+  (a scalar-prefetched tile→expert table picks each tile's weights).
 
 TPU tiling notes: MXU wants the two minor dims in multiples of (8, 128)
 for fp32 / (16, 128) for bf16; every BlockSpec here keeps each of its

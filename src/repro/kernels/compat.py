@@ -1,8 +1,8 @@
 """Pallas TPU names, resolved in one place.
 
-All four kernels take the compiler-options class and the VMEM scratch
-handle from this module, so a JAX upgrade that moves either is a one-file
-fix.  Only the spellings of the JAX pinned in ``pyproject.toml`` are
+All four kernels take the compiler-options class, the VMEM scratch
+handle and the scalar-prefetch grid spec from this module, so a JAX
+upgrade that moves one is a one-file fix.  Only the spellings of the JAX pinned in ``pyproject.toml`` are
 accepted; an install without them fails at import with an error naming
 the pin.  The ``resolve_*`` helpers take the module as an argument so
 tests can check the failure without touching the installed JAX.
@@ -16,6 +16,7 @@ import jax.experimental.pallas.tpu as _pltpu
 
 __all__ = [
     "CompilerParams",
+    "PrefetchScalarGridSpec",
     "VMEM",
     "compiler_params",
     "resolve_compiler_params_cls",
@@ -47,6 +48,8 @@ def resolve_vmem(module: Any = _pltpu) -> Any:
 
 CompilerParams = resolve_compiler_params_cls()
 VMEM = resolve_vmem()
+#: grid spec whose leading operands are scalars prefetched to SMEM
+PrefetchScalarGridSpec = _pltpu.PrefetchScalarGridSpec
 
 
 def compiler_params(

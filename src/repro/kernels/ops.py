@@ -14,10 +14,11 @@ import functools
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.flash_decode import flash_decode_bhd
-from repro.kernels.moe_gmm import moe_gmm_ecf
+from repro.kernels import moe_gmm as _gmm
 from repro.kernels.selective_scan import selective_scan_bqnc
 
 
@@ -110,14 +111,18 @@ def selective_scan(
     return out.transpose(0, 1, 3, 2)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def moe_gmm(
-    x: jax.Array,                 # (E, C, D)
-    w: jax.Array,                 # (E, D, F)
+    x: jax.Array,                 # (M, D) rows sorted, padded per group
+    w: jax.Array,                 # (G, D, F)
+    group_sizes: jax.Array,       # (G,) int32, multiples of block_m
     *,
+    block_m: int,
     interpret: bool = False,
 ) -> jax.Array:
-    return moe_gmm_ecf(x, w, interpret=interpret)
+    """Ragged grouped matmul (``kernels/moe_gmm.py``); rows past the
+    groups' are left unwritten."""
+    return _gmm.gmm(x, w, group_sizes, block_m=block_m, interpret=interpret)
 
 
 def moe_ffn(
@@ -129,11 +134,21 @@ def moe_ffn(
     act: str = "silu",
     interpret: bool = False,
 ) -> jax.Array:
-    """Full expert FFN via the grouped-matmul kernel."""
-    h = moe_gmm(xe, wi, interpret=interpret)
+    """Full expert FFN of the capacity layout via the grouped-matmul
+    kernel: each expert's C rows are one group, padded to the row tile."""
+    E, C, D = xe.shape
+    bm = _gmm.row_tile(C)
+    Cp = -(-C // bm) * bm
+    x = jnp.pad(xe, ((0, 0), (0, Cp - C), (0, 0))).reshape(E * Cp, D)
+    sizes = jnp.full((E,), Cp, jnp.int32)
+
+    def mm(a, w):
+        return moe_gmm(a, w, sizes, block_m=bm, interpret=interpret)
+
+    h = mm(x, wi)
     a = jax.nn.silu if act == "silu" else jax.nn.gelu
     if wg is not None:
-        h = a(moe_gmm(xe, wg, interpret=interpret)) * h
+        h = a(mm(x, wg)) * h
     else:
         h = a(h)
-    return moe_gmm(h, wo, interpret=interpret)
+    return mm(h, wo).reshape(E, Cp, -1)[:, :C]

@@ -75,7 +75,20 @@ def selective_scan_ref(
     return hs.transpose(1, 0, 2, 3)
 
 
-def moe_gmm_ref(x: jax.Array, w: jax.Array) -> jax.Array:
-    return jnp.einsum(
-        "ecd,edf->ecf", x.astype(jnp.float32), w.astype(jnp.float32)
-    ).astype(x.dtype)
+def moe_gmm_ref(
+    x: jax.Array,                 # (M, D) rows sorted by group
+    w: jax.Array,                 # (G, D, F)
+    group_sizes: jax.Array,       # (G,)
+) -> jax.Array:
+    """Row r of group g (groups own consecutive rows, ``group_sizes[g]``
+    each) times ``w[g]``, in f32; rows past the groups' are zero."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    rows = jnp.arange(x.shape[0])
+    y = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32)
+    for g in range(w.shape[0]):
+        mine = (rows >= starts[g]) & (rows < ends[g])
+        y = y + jnp.where(mine[:, None],
+                          x.astype(jnp.float32) @ w[g].astype(jnp.float32),
+                          0.0)
+    return y.astype(x.dtype)

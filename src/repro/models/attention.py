@@ -407,11 +407,19 @@ def attention_apply(
     impl: str = "blockwise",
     q_block: int = 512,
     kv_block: int = 1024,
+    full: bool = False,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """Returns (output (B,S,d_model), updated layer cache or None)."""
+    """Returns (output (B,S,d_model), updated layer cache or None).
+
+    ``full`` marks a full layer of a window/full pattern
+    (``cfg.full_attn_every``): no window, the full layers' rotary scaling.
+    Every other layer attends within ``cfg.sliding_window`` (if any) and
+    keeps a ring cache of that many slots."""
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     dt = x.dtype
+    window = None if full else cfg.sliding_window
+    yarn = cfg.full_attn_yarn if full else None
 
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(dt))
@@ -424,26 +432,26 @@ def attention_apply(
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     if cfg.rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
 
     if mode == "full":
         if impl == "naive":
             out = naive_attention(
                 q, k, v, q_pos=positions, kv_pos=positions, causal=causal,
-                window=cfg.sliding_window, prefix_len=prefix_len,
+                window=window, prefix_len=prefix_len,
             )
         elif impl == "pallas":
             from repro.kernels import ops as kops
 
             out = kops.flash_attention(
-                q, k, v, causal=causal, window=cfg.sliding_window,
+                q, k, v, causal=causal, window=window,
                 prefix_len=prefix_len,
             )
         else:
             out = blockwise_attention(
                 q, k, v, q_pos=positions, kv_pos=positions, causal=causal,
-                window=cfg.sliding_window, prefix_len=prefix_len,
+                window=window, prefix_len=prefix_len,
                 q_block=q_block, kv_block=kv_block,
             )
         new_cache = None
@@ -451,7 +459,7 @@ def attention_apply(
             # prefill: write K/V (post-RoPE) into the cache
             slots = layer_cache["k"].shape[1]
             with jax.named_scope("kv_write"):
-                if cfg.sliding_window is not None and S > slots:
+                if window is not None and S > slots:
                     # keep the last `slots` positions, ring-aligned
                     k_tail, v_tail = k[:, -slots:], v[:, -slots:]
                     pos_tail = positions[-slots:]
@@ -464,7 +472,7 @@ def attention_apply(
                     )
                 else:
                     start = positions[0]
-                    if cfg.sliding_window is not None:
+                    if window is not None:
                         start = start % slots
                     ck = jax.lax.dynamic_update_slice(
                         layer_cache["k"],
@@ -481,7 +489,7 @@ def attention_apply(
         assert layer_cache is not None and cache_len is not None
         slots = layer_cache["k"].shape[1]
         pos = positions[0]  # scalar: absolute position of the new token
-        slot = pos % slots if cfg.sliding_window is not None else pos
+        slot = pos % slots if window is not None else pos
         with jax.named_scope("kv_write"):
             ck = jax.lax.dynamic_update_slice(
                 layer_cache["k"], k.astype(layer_cache["k"].dtype),
@@ -493,7 +501,7 @@ def attention_apply(
             )
         n_filled = jnp.minimum(cache_len + 1, slots)
         slot_ids = jnp.arange(slots)
-        if cfg.sliding_window is not None:
+        if window is not None:
             valid = slot_ids[None, :] < n_filled
         else:
             valid = slot_ids[None, :] < (cache_len + 1)

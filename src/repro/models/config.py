@@ -14,6 +14,20 @@ from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling (transformers' ``_compute_yarn_parameters``,
+    truncated): frequencies between the ``beta_fast`` and ``beta_slow``
+    rotations are ramped from ``inv / factor`` to ``inv``, and cos and sin
+    are multiplied by ``attention_factor``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     # -- identity ----------------------------------------------------------
     name: str
@@ -37,6 +51,12 @@ class ModelConfig:
     qk_norm: bool = False                  # qwen3-moe
     prefix_lm: bool = False                # paligemma: bidirectional prefix
     logit_softcap: Optional[float] = None  # gemma-style logit soft capping
+    # window and full layers mixed (mellum2): every ``full_attn_every``-th
+    # layer (the last of each period) attends to the whole cache with
+    # ``full_attn_yarn`` rotary scaling; the others use ``sliding_window``.
+    # 0 = every layer is of one kind.
+    full_attn_every: int = 0
+    full_attn_yarn: Optional[YaRN] = None
 
     # -- block structure -------------------------------------------------------
     parallel_block: bool = False      # command-r: attn + FFN in parallel
@@ -54,6 +74,11 @@ class ModelConfig:
     # quantize tokens for the EP dispatch/combine all-to-all (e.g.
     # "float8_e4m3fn" halves MoE collective bytes; None = native dtype)
     moe_dispatch_dtype: Optional[str] = None
+    # expert parallelism: this device holds experts
+    # [expert_offset, expert_offset + experts_held) of the router's
+    # ``num_experts`` (None = all of them)
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
 
     # -- SSM (mamba) -----------------------------------------------------------
     ssm_state: int = 0
@@ -86,6 +111,21 @@ class ModelConfig:
                     f"{self.name}: num_heads {self.num_heads} not divisible "
                     f"by kv heads {self.num_kv_heads}"
                 )
+        if self.full_attn_every and (
+            self.num_layers % self.full_attn_every
+            or self.sliding_window is None
+        ):
+            raise ValueError(
+                f"{self.name}: full_attn_every {self.full_attn_every} needs "
+                f"a sliding_window and whole periods of {self.num_layers} "
+                "layers"
+            )
+        if self.expert_offset + self.held_experts > self.num_experts:
+            raise ValueError(
+                f"{self.name}: experts [{self.expert_offset}, "
+                f"{self.expert_offset + self.held_experts}) outside the "
+                f"router's {self.num_experts}"
+            )
 
     # -- derived -----------------------------------------------------------
     @property
@@ -138,6 +178,27 @@ class ModelConfig:
             self.family in ("ssm", "hybrid")
             or self.sliding_window is not None
         )
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this device holds."""
+        if self.experts_held is None:
+            return self.num_experts
+        return self.experts_held
+
+    @property
+    def periods(self) -> int:
+        """Periods of the window/full layer pattern (0 = no pattern)."""
+        if not self.full_attn_every:
+            return 0
+        return self.num_layers // self.full_attn_every
+
+    @property
+    def window_layers(self) -> int:
+        """Layers whose cache holds at most ``sliding_window`` positions."""
+        if self.sliding_window is None:
+            return 0
+        return self.num_layers - self.periods
 
     @property
     def expert_d_ff(self) -> int:
@@ -218,6 +279,11 @@ class ModelConfig:
             experts_per_token=(
                 min(self.experts_per_token, n_exp) if n_exp else 0
             ),
+            experts_held=(
+                None if self.experts_held is None
+                else min(self.experts_held, n_exp)
+            ),
+            expert_offset=0,
             sliding_window=(
                 min(self.sliding_window, 64)
                 if self.sliding_window is not None
@@ -248,5 +314,5 @@ class ModelConfig:
             mult = 3 if self.act == "silu" else 2
             ffn = mult * d * self.d_ff
         if self.is_moe:
-            ffn = self.num_experts * 3 * d * self.expert_d_ff
+            ffn = self.held_experts * 3 * d * self.expert_d_ff
         return emb + L * (attn + ffn)

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional
 
 import jax
@@ -49,21 +50,50 @@ def layer_norm(x: jax.Array, p: dict, eps: float = 1e-5) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+def rope_frequencies(d: int, theta: float, yarn=None):
+    """(inverse frequencies (d/2,), scale of cos and sin) of a rotary
+    embedding over head size ``d``; with ``yarn`` (a ``config.YaRN``),
+    the frequencies as transformers' ``_compute_yarn_parameters`` sets
+    them, with truncation."""
+    half = d // 2
+    j = jnp.arange(0, half, dtype=jnp.float32)
+    inv = 1.0 / (theta ** (j / half))
+    if yarn is None:
+        return inv, 1.0
+
+    def correction_dim(rotations):
+        return (d * math.log(yarn.original_max_position
+                             / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((j - low) / (high - low), 0.0, 1.0)
+    inv = inv / yarn.factor * ramp + inv * (1.0 - ramp)
+    return inv, yarn.attention_factor
+
+
 def apply_rope(
     x: jax.Array,            # (B, S, H, D)
     positions: jax.Array,    # (S,) or (B, S)
     theta: float,
+    yarn=None,
 ) -> jax.Array:
-    """Rotary position embedding on the trailing head_dim."""
+    """Rotary position embedding on the two halves of the trailing
+    head_dim (``yarn``: see :func:`rope_frequencies`)."""
     assert x.ndim == 4, f"apply_rope expects (B,S,H,D), got {x.shape}"
     d = x.shape[-1]
     half = d // 2
-    freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    freq, scale = rope_frequencies(d, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freq  # (S,half)/(B,S,half)
     if ang.ndim == 2:
         ang = ang[None]                        # (1, S, half)
     cos = jnp.cos(ang)[:, :, None, :]           # (B|1, S, 1, half)
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [

@@ -15,11 +15,21 @@ assignment (everything except Whisper's encoder-decoder, see ``whisper.py``):
 * PaliGemma's vision frontend is a stub per the assignment:
   ``prefix_embed`` (precomputed patch embeddings) is concatenated in front
   of the token embeddings with a bidirectional prefix-LM mask,
+* window and full layers mixed (mellum2, ``cfg.full_attn_every``): the
+  stack scans periods of ``full_attn_every - 1`` window blocks and one
+  full block, with two caches side by side — the window layers' rings of
+  ``sliding_window`` slots and the full layers' whole-context cache,
+* MoE models serve (``prefill``, ``decode_step``) through the dropless
+  expert share ``moe.moe_serve`` and count each held expert's
+  assignments in the cache's ``moe_load``; the full-sequence forward
+  (training) keeps the capacity layer and its aux loss,
 * the serving step names its parts with ``jax.named_scope`` (``embed``,
   ``layers``, ``logits``; in an attention block ``norm``, ``attention``
-  with its ``kv_write``, ``ffn``), which a profile reads from each
-  operation's metadata; what lies under ``layers`` in no block scope is
-  the scan's own slicing and stacking of per-layer weights and cache.
+  with its ``kv_write``, ``ffn`` holding the expert layer's ``moe``;
+  ``window`` and ``full`` around each kind of block), which a profile
+  reads from each operation's metadata; what lies under ``layers`` in no
+  block scope is the scan's own slicing and stacking of per-layer weights
+  and cache.
 
 Modes
 -----
@@ -128,6 +138,16 @@ class TransformerLM:
         bp["final_norm"] = rmsnorm_spec(cfg.d_model)
         if cfg.family == "hybrid":
             bp["decoder"] = self._hybrid_blueprints()
+        elif cfg.full_attn_every:
+            # one period: full_attn_every - 1 window layers, then a full one
+            layer = self._layer_blueprint()
+            bp["decoder"] = {
+                "window": stack_blueprint(
+                    stack_blueprint(layer, cfg.full_attn_every - 1),
+                    cfg.periods,
+                ),
+                "full": stack_blueprint(layer, cfg.periods),
+            }
         else:
             bp["decoder"] = stack_blueprint(
                 self._layer_blueprint(), cfg.num_layers
@@ -180,6 +200,19 @@ class TransformerLM:
                 "k": mk((n_blk, batch, slots, kv, hd), dtype),
                 "v": mk((n_blk, batch, slots, kv, hd), dtype),
             }
+        elif cfg.full_attn_every:
+            # two caches side by side: window layers' rings, full layers'
+            kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
+            P, n = cfg.periods, cfg.full_attn_every - 1
+            ring = min(max_len, cfg.sliding_window)
+            cache["kv_window"] = {
+                "k": mk((P, n, batch, ring, kv, hd), dtype),
+                "v": mk((P, n, batch, ring, kv, hd), dtype),
+            }
+            cache["kv_full"] = {
+                "k": mk((P, batch, max_len, kv, hd), dtype),
+                "v": mk((P, batch, max_len, kv, hd), dtype),
+            }
         else:
             slots = (
                 min(max_len, cfg.sliding_window)
@@ -192,6 +225,11 @@ class TransformerLM:
                 "k": mk((L, batch, slots, kv, hd), dtype),
                 "v": mk((L, batch, slots, kv, hd), dtype),
             }
+        if cfg.is_moe:
+            # assignments each held expert of each layer received, summed
+            # over every serving step on this cache
+            cache["moe_load"] = mk((cfg.num_layers, cfg.held_experts),
+                                   jnp.int32)
         return cache
 
     def init_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -204,10 +242,17 @@ class TransformerLM:
     # Blocks
     # ==================================================================
     def _attn_block(
-        self, lp, x, *, positions, mode, layer_kv, cache_len, prefix_len
+        self, lp, x, *, positions, mode, layer_kv, cache_len, prefix_len,
+        full=False,
     ):
+        """One attention block.  Returns (x, new layer cache, aux loss,
+        load): a serving step (``layer_kv`` given) of an MoE model runs the
+        dropless expert share, whose per-expert ``load`` it returns (else
+        None); the full-sequence forward runs the capacity layer and its
+        aux loss."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
+        load = None
         with jax.named_scope("norm"):
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         with jax.named_scope("attention"):
@@ -215,33 +260,32 @@ class TransformerLM:
                 lp["attn"], cfg, h,
                 positions=positions, mode=mode, layer_cache=layer_kv,
                 cache_len=cache_len, prefix_len=prefix_len, impl=self.impl,
-                q_block=self.q_block, kv_block=self.kv_block,
+                q_block=self.q_block, kv_block=self.kv_block, full=full,
             )
+
+        def ffn(hn):
+            nonlocal aux, load
+            with jax.named_scope("ffn"):
+                if not cfg.is_moe:
+                    return mlp_apply(lp["mlp"], cfg, hn)
+                if layer_kv is not None:
+                    f, load = moe_mod.moe_serve(lp["moe"], cfg, hn,
+                                                impl=self.impl)
+                    return f
+                f, aux_l = moe_mod.moe_apply(lp["moe"], cfg, hn,
+                                             return_aux=True)
+                aux = aux + aux_l
+                return f
+
         if cfg.parallel_block:
             # command-r: attn and FFN read the SAME normed input, summed
-            with jax.named_scope("ffn"):
-                if cfg.is_moe:
-                    f, aux_l = moe_mod.moe_apply(
-                        lp["moe"], cfg, h, return_aux=True
-                    )
-                    aux = aux + aux_l
-                else:
-                    f = mlp_apply(lp["mlp"], cfg, h)
-            x = x + a + f
+            x = x + a + ffn(h)
         else:
             x = x + a
             with jax.named_scope("norm"):
                 h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            with jax.named_scope("ffn"):
-                if cfg.is_moe:
-                    f, aux_l = moe_mod.moe_apply(
-                        lp["moe"], cfg, h2, return_aux=True
-                    )
-                    aux = aux + (aux_l if aux_l is not None else 0.0)
-                    x = x + f
-                else:
-                    x = x + mlp_apply(lp["mlp"], cfg, h2)
-        return x, new_kv, aux
+            x = x + ffn(h2)
+        return x, new_kv, aux, load
 
     def _mamba_block(self, lp, x, *, mode, state, version):
         cfg = self.cfg
@@ -303,12 +347,12 @@ class TransformerLM:
         def body(carry, per_layer):
             xc, aux_acc = carry
             lp, kv_slice = per_layer
-            y, new_kv, aux = self._attn_block(
+            y, new_kv, aux, load = self._attn_block(
                 lp, xc, positions=positions, mode=mode,
                 layer_kv=kv_slice, cache_len=cache_len,
                 prefix_len=prefix_len,
             )
-            return (y, aux_acc + aux), new_kv
+            return (y, aux_acc + aux), (new_kv, load)
 
         if self.remat:
             body = jax.checkpoint(body)
@@ -324,11 +368,93 @@ class TransformerLM:
                 params["decoder"],
             )
             return x, None, aux
-        (x, aux), new_kv = jax.lax.scan(
+        (x, aux), (new_kv, load) = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)), (params["decoder"], kv)
         )
         new_cache = dict(cache)
         new_cache["kv"] = new_kv
+        if load is not None:
+            new_cache["moe_load"] = cache["moe_load"] + load
+        return x, new_cache, aux
+
+    def _run_patterned_stack(
+        self, params, x, *, positions, mode, cache, prefix_len
+    ):
+        """Window and full layers mixed (``cfg.full_attn_every``): one
+        ``lax.scan`` over the periods, whose body scans the period's window
+        blocks (scope ``window``) and then runs its full block (``full``),
+        each kind on its own cache.  The two caches ride in the scans'
+        carries and each layer's slice is written back in place, so
+        neither is copied whole."""
+        cfg = self.cfg
+        dec = params["decoder"]
+        cache_len = None if cache is None else cache["len"]
+        n = cfg.full_attn_every - 1
+        zero = jnp.zeros((), jnp.float32)
+
+        def block(full):
+            def body(xc, lp, kv):
+                return self._attn_block(
+                    lp, xc, positions=positions, mode=mode, layer_kv=kv,
+                    cache_len=cache_len, prefix_len=prefix_len, full=full,
+                )
+
+            return jax.checkpoint(body) if self.remat else body
+
+        window_block, full_block = block(False), block(True)
+
+        if cache is None:
+            def period_nc(carry, per_period):
+                wp, fp = per_period
+
+                def inner(c, lp):
+                    y, _, aux, _ = window_block(c[0], lp, None)
+                    return (y, c[1] + aux), None
+
+                with jax.named_scope("window"):
+                    carry, _ = jax.lax.scan(inner, carry, wp)
+                with jax.named_scope("full"):
+                    y, _, aux, _ = full_block(carry[0], fp, None)
+                return (y, carry[1] + aux), None
+
+            (x, aux), _ = jax.lax.scan(
+                period_nc, (x, zero), (dec["window"], dec["full"]))
+            return x, None, aux
+
+        def period(carry, per_period):
+            xc, aux_acc, kv_w, kv_f, load = carry
+            p, wp, fp = per_period
+
+            def inner(c, per_layer):
+                xi, aux_i, kv_w, load = c
+                j, lp = per_layer
+                kv = jax.tree.map(lambda a: a[p, j], kv_w)
+                y, kv, aux, n_load = window_block(xi, lp, kv)
+                kv_w = jax.tree.map(lambda a, b: a.at[p, j].set(b), kv_w, kv)
+                if n_load is not None:
+                    load = load.at[p * cfg.full_attn_every + j].add(n_load)
+                return (y, aux_i + aux, kv_w, load), None
+
+            with jax.named_scope("window"):
+                (xc, aux_acc, kv_w, load), _ = jax.lax.scan(
+                    inner, (xc, aux_acc, kv_w, load),
+                    (jnp.arange(n), wp))
+            with jax.named_scope("full"):
+                kv = jax.tree.map(lambda a: a[p], kv_f)
+                xc, kv, aux, n_load = full_block(xc, fp, kv)
+                kv_f = jax.tree.map(lambda a, b: a.at[p].set(b), kv_f, kv)
+                if n_load is not None:
+                    load = load.at[p * cfg.full_attn_every + n].add(n_load)
+            return (xc, aux_acc + aux, kv_w, kv_f, load), None
+
+        load = cache.get("moe_load")
+        (x, aux, kv_w, kv_f, load), _ = jax.lax.scan(
+            period, (x, zero, cache["kv_window"], cache["kv_full"], load),
+            (jnp.arange(cfg.periods), dec["window"], dec["full"]),
+        )
+        new_cache = dict(cache, kv_window=kv_w, kv_full=kv_f)
+        if load is not None:
+            new_cache["moe_load"] = load
         return x, new_cache, aux
 
     def _run_hybrid_stack(
@@ -384,7 +510,7 @@ class TransformerLM:
                 xc = carry
                 blk_params, st, blk_kv = per_block
                 # shared attention (weights shared; per-block cache slice)
-                y, new_kv, _ = self._attn_block(
+                y, new_kv, _, _ = self._attn_block(
                     shared, xc, positions=positions, mode=mode,
                     layer_kv=blk_kv, cache_len=cache_len,
                     prefix_len=prefix_len,
@@ -405,7 +531,7 @@ class TransformerLM:
         def block_body_nc(carry, per_block):
             xc = carry
             blk_params, st = per_block
-            y, _, _ = self._attn_block(
+            y, _, _, _ = self._attn_block(
                 shared, xc, positions=positions, mode=mode,
                 layer_kv=None, cache_len=cache_len, prefix_len=prefix_len,
             )
@@ -418,8 +544,12 @@ class TransformerLM:
         return x, None, jnp.zeros((), jnp.float32)
 
     def _run_stack(self, params, x, *, positions, mode, cache, prefix_len):
-        run = (self._run_hybrid_stack if self.cfg.family == "hybrid"
-               else self._run_uniform_stack)
+        if self.cfg.family == "hybrid":
+            run = self._run_hybrid_stack
+        elif self.cfg.full_attn_every:
+            run = self._run_patterned_stack
+        else:
+            run = self._run_uniform_stack
         with jax.named_scope("layers"):
             return run(
                 params, x, positions=positions, mode=mode, cache=cache,

@@ -98,11 +98,13 @@ def _prefill_cases(
     if cfg.is_moe:
         E, C = cfg.num_experts, _MOE_CAPACITY
         D, F = cfg.d_model, cfg.expert_d_ff
-        x = _rnd(6, (E, C, D), jnp.bfloat16)
+        x = _rnd(6, (E * C, D), jnp.bfloat16)
         w = _rnd(7, (E, D, F), jnp.bfloat16)
+        sizes = jnp.full((E,), C, jnp.int32)
         flops = 2.0 * E * C * D * F
         cases.append((
-            lambda: ops.moe_gmm(x, w, interpret=interpret),
+            lambda: ops.moe_gmm(x, w, sizes, block_m=C,
+                                interpret=interpret),
             flops,
         ))
     if not cases:

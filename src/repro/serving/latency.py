@@ -108,15 +108,23 @@ class LatencyModel:
         )
 
     # ------------------------------------------------------------------
-    def kv_bytes_per_token(self) -> float:
-        """K+V bf16 bytes one cached token occupies (0: no KV cache)."""
+    def kv_bytes_per_token(self, ctx: Optional[int] = None) -> float:
+        """K+V bf16 bytes a cached token occupies, averaged over a sequence
+        of ``ctx`` tokens (None: one no window fills).  Only attention
+        layers keep KV (a hybrid's shared attention blocks, not its mamba
+        layers), and a window layer keeps at most its window, so past it
+        a token costs only the full layers.  0: no KV cache."""
         cfg = self.cfg
-        if cfg.num_kv_heads and cfg.resolved_head_dim:
-            return float(
-                2 * cfg.num_layers * cfg.num_kv_heads
-                * cfg.resolved_head_dim * 2
-            )
-        return 0.0
+        if not (cfg.num_kv_heads and cfg.resolved_head_dim):
+            return 0.0
+        layer = 2 * cfg.num_kv_heads * cfg.resolved_head_dim * 2
+        attn = (cfg.hybrid_blocks if cfg.family == "hybrid"
+                else cfg.num_layers)
+        windowed = cfg.window_layers
+        if ctx is None or cfg.sliding_window is None:
+            return float(layer * attn)
+        held = min(ctx, cfg.sliding_window) / ctx
+        return float(layer * ((attn - windowed) + windowed * held))
 
     def free_kv_hbm_bytes(self) -> float:
         """HBM left for KV cache: 90% usable minus bf16 weights,
@@ -131,15 +139,9 @@ class LatencyModel:
     def max_concurrency(self, max_ctx: int = 4096) -> int:
         """Requests servable concurrently from leftover HBM (KV budget).
         Attention-free archs are compute-limited instead (use 32)."""
-        cfg = self.cfg
-        kv_tok = self.kv_bytes_per_token()
-        if kv_tok:
-            slots = (
-                min(max_ctx, cfg.sliding_window or max_ctx)
-            )
-            return max(
-                1, int(self.free_kv_hbm_bytes() / (kv_tok * slots))
-            )
+        kv_seq = self.kv_bytes_per_token(max_ctx) * max_ctx
+        if kv_seq:
+            return max(1, int(self.free_kv_hbm_bytes() / kv_seq))
         return 32
 
 

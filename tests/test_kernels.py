@@ -17,7 +17,7 @@ from repro.kernels.flash_attention import (
     vmem_bytes,
 )
 from repro.kernels.flash_decode import flash_decode_bhd
-from repro.kernels.moe_gmm import moe_gmm_ecf
+from repro.kernels import moe_gmm as gmm_mod
 from repro.kernels.selective_scan import selective_scan_bqnc
 
 
@@ -265,7 +265,7 @@ def test_selective_scan_equals_mamba_chunked_path():
 
 GMM_CASES = [
     (4, 64, 128, 256),
-    (8, 96, 200, 64),       # non-aligned dims exercise padding
+    (8, 96, 200, 64),       # dims not multiples of 128: whole-dim blocks
     (2, 256, 512, 512),
 ]
 
@@ -273,12 +273,14 @@ GMM_CASES = [
 @pytest.mark.parametrize("case", GMM_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_moe_gmm_matches_ref(case, dtype):
+    """E groups of C rows each (the capacity layout) through the ragged
+    kernel."""
     E, C, D, F = case
-    x = rnd(15, (E, C, D), dtype)
+    x = rnd(15, (E * C, D), dtype)
     w = rnd(16, (E, D, F), dtype)
-    got = moe_gmm_ecf(x, w, block_c=64, block_d=64, block_f=64,
-                      interpret=True)
-    want = ref.moe_gmm_ref(x, w)
+    sizes = jnp.full((E,), C, jnp.int32)
+    got = ops.moe_gmm(x, w, sizes, block_m=32, interpret=True)
+    want = ref.moe_gmm_ref(x, w, sizes)
     np.testing.assert_allclose(
         got.astype(np.float32), want.astype(np.float32),
         atol=5e-2 if dtype == jnp.bfloat16 else 1e-4,
@@ -298,3 +300,53 @@ def test_moe_ffn_matches_dense_path():
     g = jnp.einsum("ecd,edf->ecf", xe, wg)
     want = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * h, wo)
     np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-3)
+
+
+# padded group sizes (multiples of the 16-row tile) with empty groups,
+# groups of one tile and runs of several, then dead tiles past them
+RAGGED_CASES = [
+    ([0, 16, 48, 0, 16], 160),
+    ([32, 0, 0, 0], 64),
+    ([0, 0, 0], 48),                 # nothing routed here
+    ([16] * 6, 96),
+]
+
+
+@pytest.mark.parametrize("sizes,M", RAGGED_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ragged_moe_gmm_matches_grouped_product(sizes, M, dtype):
+    G, D, F = len(sizes), 64, 96
+    x = rnd(21, (M, D), dtype)
+    w = rnd(22, (G, D, F), dtype)
+    g = jnp.asarray(sizes, jnp.int32)
+    live = sum(sizes)
+    got = ops.moe_gmm(x, w, g, block_m=16, interpret=True)[:live]
+    want = ref.moe_gmm_ref(x, w, g)[:live]
+    np.testing.assert_allclose(
+        got.astype(np.float32), want.astype(np.float32),
+        atol=5e-2 if dtype == jnp.bfloat16 else 1e-4,
+        rtol=5e-2 if dtype == jnp.bfloat16 else 1e-4,
+    )
+
+
+def test_ragged_moe_gmm_splits_wide_weights(monkeypatch):
+    """A weight block over the byte budget is split along F into
+    multiples of 128 that divide it."""
+    monkeypatch.setattr(gmm_mod, "_W_BLOCK_BYTES", 64 * 128 * 4)
+    assert gmm_mod.f_block(64, 384, 4) == 128
+    x = rnd(23, (64, 64), jnp.float32)
+    w = rnd(24, (3, 64, 384), jnp.float32)
+    g = jnp.asarray([16, 0, 32], jnp.int32)
+    got = gmm_mod.gmm(x, w, g, block_m=16, interpret=True)[:48]
+    np.testing.assert_allclose(got, ref.moe_gmm_ref(x, w, g)[:48],
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_ragged_layout_arithmetic():
+    # 6 expected rows a group at decode, 2048 in a prefill chunk
+    assert gmm_mod.row_tile(6) == 16
+    assert gmm_mod.row_tile(2048) == 512
+    # 384 rows over 16 groups may pad each by 15: 624 rows, 39 tiles
+    assert gmm_mod.padded_rows(384, 16, 16) == 624
+    group, live = gmm_mod.tile_groups(jnp.asarray([0, 32, 16, 0]), 5, 16)
+    assert group.tolist() == [1, 1, 2, 3, 3] and live.tolist() == [3]

@@ -2,7 +2,8 @@
 
 Each case lowers and compiles one program of the main path for one device
 of a described ``v5e:2x2`` topology: the four Pallas kernels at the widths
-of the served models, and the scenario engine's phase-B kernel under x64.
+of the served models, the served steps of two models, and the scenario
+engine's phase-B kernel under x64.
 A compile that passes is not a chip run, but it catches what interpret
 mode cannot: blocks not aligned to the tiling, kernels that overflow
 scoped VMEM, programs the TPU compiler refuses.
@@ -157,13 +158,77 @@ def test_selective_scan_compiles(one_chip, case):
 
 
 def test_moe_gmm_compiles(one_chip):
-    """qwen3-moe-30b expert widths: E128 C128 D2048 F768."""
+    """qwen3-moe-30b expert widths: 128 groups of 128 rows, D2048 F768."""
     txt = _compile_text(
-        lambda x, w: ops.moe_gmm(x, w, interpret=False),
-        _sds(one_chip, (128, 128, 2048)),
+        lambda x, w, g: ops.moe_gmm(x, w, g, block_m=128, interpret=False),
+        _sds(one_chip, (128 * 128, 2048)),
         _sds(one_chip, (128, 2048, 768)),
+        _sds(one_chip, (128,), jnp.int32),
     )
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("rows,block_m", [(384, 16), (131072, 512)])
+def test_moe_gmm_compiles_at_mellum_widths(one_chip, rows, block_m):
+    """The held share of mellum2 (16 experts, D2304 F896 and back) at a
+    decode step's and a prefill chunk's rows, padded for 16 groups: one
+    ``moe_gmm.N`` custom call each, no padded weights."""
+    import re
+
+    from repro.kernels import moe_gmm as gmm_mod
+
+    M = gmm_mod.padded_rows(rows, 16, block_m)
+    for D, F in ((2304, 896), (896, 2304)):
+        txt = _compile_text(
+            lambda x, w, g: ops.moe_gmm(x, w, g, block_m=block_m,
+                                        interpret=False),
+            _sds(one_chip, (M, D)),
+            _sds(one_chip, (16, D, F)),
+            _sds(one_chip, (16,), jnp.int32),
+        )
+        calls = re.findall(
+            r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', txt)
+        assert len(calls) == 1 and calls[0].startswith("moe_gmm."), calls
+        assert not re.search(r"\[16,\d+,\d+\]\S* pad\(", txt)
+
+
+@pytest.mark.parametrize("mode", ["prefill", "decode"])
+def test_mellum_step_compiles(one_chip, mode):
+    """The smoke mellum2's pallas step, prefill past its window: each
+    kernel is a custom call named after it, ``moe_gmm.N`` (three a layer
+    kind) under ``ffn/moe/experts`` and the attention kernel under
+    ``attention``, all inside ``window`` or ``full``."""
+    import re
+
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+
+    model = build_model(get_smoke_config("mellum2-12b"), impl="pallas")
+    params, cache = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        (model.abstract(jnp.bfloat16), model.abstract_cache(2, 40)),
+    )
+    if mode == "prefill":
+        fn, S, attention = model.prefill, 32, "flash_attention"
+    else:
+        fn, S, attention = model.decode_step, 1, "flash_decode"
+    txt = _compile_text(fn, params, _sds(one_chip, (2, S), jnp.int32), cache)
+    calls = re.findall(r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"'
+                       r'[^\n]*op_name="([^"]*)"', txt)
+    kinds = {}
+    for name, scope in calls:
+        kernel = name.rsplit(".", 1)[0]
+        assert kernel in (attention, "moe_gmm"), name
+        kind = "window" if "/window/" in scope else "full"
+        assert f"/{kind}/" in scope and "/layers/" in scope, scope
+        if kernel == "moe_gmm":
+            assert "/ffn/moe/experts/" in scope, scope
+        else:
+            assert "/attention/" in scope, scope
+        kinds.setdefault((kernel, kind), 0)
+        kinds[(kernel, kind)] += 1
+    assert kinds == {(attention, "window"): 1, (attention, "full"): 1,
+                     ("moe_gmm", "window"): 3, ("moe_gmm", "full"): 3}
 
 
 def test_phase_b_kernel_compiles_under_x64(one_chip):

@@ -11,6 +11,9 @@ pin every name on the CPU:
 * the model step scopes ``embed``, ``layers``, ``logits`` and, in each
   attention block, ``norm``, ``attention`` (with ``kv_write``) and
   ``ffn``; the Pallas kernels run under ``attention``;
+* a window/full model (mellum2) scopes each kind of block ``window`` or
+  ``full`` under ``layers``, and its serving expert layer ``moe`` under
+  ``ffn``, with ``route``, ``sort``, ``experts`` and ``combine`` inside;
 * a profiled scenario-engine run holds each engine span, inside the run.
 """
 
@@ -135,6 +138,46 @@ def test_pallas_kernels_run_under_attention(monkeypatch):
         under = [n for n in _op_names(hlo[mode])
                  if "layers/" in n and f"attention/jit({kernel})/" in n]
         assert under, f"{mode}: no {kernel} operation under attention"
+
+
+MELLUM_SCOPES = {
+    # scope: the scope it lies directly under
+    "window": "layers", "full": "layers",
+    "moe": "ffn", "route": "moe", "sort": "moe", "experts": "moe",
+    "combine": "moe",
+}
+
+
+@pytest.fixture(scope="module")
+def mellum_hlo():
+    """Compiled prefill (past the window) and decode of the smoke
+    mellum2."""
+    model = build_model(get_smoke_config("mellum2-12b"))
+    params = model.abstract(jnp.bfloat16)
+    B, S = 2, 24
+    cache = model.abstract_cache(B, S + 1)
+    tok = lambda n: jax.ShapeDtypeStruct((B, n), jnp.int32)  # noqa: E731
+    return {
+        "prefill": jax.jit(model.prefill).lower(
+            params, tok(S), cache).compile().as_text(),
+        "decode": jax.jit(model.decode_step).lower(
+            params, tok(1), cache).compile().as_text(),
+    }
+
+
+@pytest.mark.parametrize("mode", ["prefill", "decode"])
+@pytest.mark.parametrize("scope", sorted(MELLUM_SCOPES))
+def test_mellum_step_has_scope(mellum_hlo, mode, scope):
+    """Each scope holds operations under the scope it belongs to
+    (``.../ffn/moe/route/...``), inside the layer scan."""
+    outer = MELLUM_SCOPES[scope]
+
+    def placed(parts):
+        i = parts.index(scope)
+        return "layers" in parts[:i] and outer in parts[:i]
+
+    names = [n.split("/") for n in _op_names(mellum_hlo[mode])]
+    assert any(scope in p and placed(p) for p in names), scope
 
 
 # ---------------------------------------------------------------------------
