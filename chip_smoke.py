@@ -162,23 +162,23 @@ def phase_kernels(seed: int) -> None:
     ).transpose(0, 2, 1, 3)
     _close("flash_attention", fa(q, k, v), want)
 
-    # flash decode: B4 over a 4096-token cache, ragged occupancy
+    # flash decode: B4 over a 4096-token cache, ragged occupancy, read at
+    # layer 2 of a 3-layer head-major stack
     S = 4096
     q = normal((4, 1, 64, 128))
-    kc = normal((4, S, 8, 128))
-    vc = normal((4, S, 8, 128))
+    kc = normal((3, 4, 8, S, 128))
+    vc = normal((3, 4, 8, S, 128))
     lens = jnp.array([S, 3000, 1234, 1])
     valid = jnp.arange(S)[None, :] < lens[:, None]
+    layer = jnp.int32(2)
     fd, _ = compile_checked(
         "b", "flash_decode",
-        lambda q, k, v, m: ops.flash_decode(q, k, v, kv_valid=m,
-                                            interpret=False),
-        q, kc, vc, valid,
+        lambda q, k, v, i, m: ops.flash_decode(q, k, v, i, kv_valid=m,
+                                               interpret=False),
+        q, kc, vc, layer, valid,
     )
-    want = ref.flash_decode_ref(
-        q[:, 0], kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3), valid
-    )[:, None]
-    _close("flash_decode", fd(q, kc, vc, valid), want)
+    want = ref.flash_decode_ref(q[:, 0], kc[2], vc[2], valid)[:, None]
+    _close("flash_decode", fd(q, kc, vc, layer, valid), want)
 
     # selective scan: falcon-mamba-7b, chunk 64 of d_inner 8192, state 16
     a = jax.nn.sigmoid(normal((1, 64, 8192, 16), f32))

@@ -54,20 +54,19 @@ def flash_attention(
 )
 def flash_decode(
     q: jax.Array,                 # (B, 1, H, D) model layout
-    k_cache: jax.Array,           # (B, S, Kv, D)
+    k_cache: jax.Array,           # (L, B, Kv, S, D) a stack's whole cache
     v_cache: jax.Array,
+    layer: jax.Array,             # () int32: the stack's layer to read
     *,
     kv_valid: jax.Array,          # (B, S)
     block_kv: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
+    """One query token of ``layer`` against that layer's cache, read in
+    place from the head-major stack the model keeps."""
     out = flash_decode_bhd(
-        q[:, 0],
-        k_cache.transpose(0, 2, 1, 3),
-        v_cache.transpose(0, 2, 1, 3),
-        kv_valid,
-        block_kv=block_kv,
-        interpret=interpret,
+        q[:, 0], k_cache, v_cache, kv_valid, layer,
+        block_kv=block_kv, interpret=interpret,
     )
     return out[:, None]
 

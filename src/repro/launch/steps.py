@@ -102,10 +102,10 @@ def _data_axis_size(mesh: Mesh) -> int:
 # ---------------------------------------------------------------------------
 
 _CACHE_LOGICAL_AXES = {
-    # kv caches: (layers/blocks, batch, seq, kv_heads, head_dim)
-    "kv": ("layers", "batch", "kv_seq", "kv_heads", "head_dim"),
+    # kv caches, head-major: (layers/blocks, batch, kv_heads, seq, head_dim)
+    "kv": ("layers", "batch", "kv_heads", "kv_seq", "head_dim"),
     # whisper cross kv: seq is the (short) encoder length
-    "cross": ("layers", "batch", None, "kv_heads", "head_dim"),
+    "cross": ("layers", "batch", "kv_heads", None, "head_dim"),
 }
 
 

@@ -337,21 +337,21 @@ def _attend_raw(
 
 def decode_attention(
     q: jax.Array,            # (B, 1, H, D)
-    k_cache: jax.Array,      # (B, S_cache, Kv, D) — RoPE already applied
+    k_cache: jax.Array,      # (B, Kv, S_cache, D) — RoPE already applied
     v_cache: jax.Array,
     *,
     kv_valid: jax.Array,     # (B, S_cache) bool — slot validity
 ) -> jax.Array:
     B, _, H, D = q.shape
-    Kv = k_cache.shape[2]
+    Kv = k_cache.shape[1]
     G = H // Kv
     qg = q.reshape(B, Kv, G, D).astype(jnp.float32)
     s = jnp.einsum(
-        "bkgd,bmkd->bkgm", qg, k_cache.astype(jnp.float32)
+        "bkgd,bkmd->bkgm", qg, k_cache.astype(jnp.float32)
     ) / math.sqrt(D)
     s = jnp.where(kv_valid[:, None, None, :], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgm,bmkd->bkgd", w, v_cache.astype(jnp.float32))
+    out = jnp.einsum("bkgm,bkmd->bkgd", w, v_cache.astype(jnp.float32))
     return out.reshape(B, 1, H, D).astype(q.dtype)
 
 
@@ -360,37 +360,17 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 
 
-def init_kv_cache(
-    cfg: ModelConfig, batch: int, max_len: int, dtype: Any
-) -> Dict[str, Any]:
-    """Per-layer-stack KV cache.  SWA archs use a ring buffer of the window
-    size; dense archs use the full context length."""
-    if cfg.sliding_window is not None:
-        slots = min(max_len, cfg.sliding_window)
-    else:
-        slots = max_len
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    L = cfg.num_layers
-    return {
-        "k": jnp.zeros((L, batch, slots, kv, hd), dtype),
-        "v": jnp.zeros((L, batch, slots, kv, hd), dtype),
-    }
-
-
-def kv_cache_abstract(
-    cfg: ModelConfig, batch: int, max_len: int, dtype: Any
-) -> Dict[str, Any]:
-    if cfg.sliding_window is not None:
-        slots = min(max_len, cfg.sliding_window)
-    else:
-        slots = max_len
-    kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    L = cfg.num_layers
-    shape = (L, batch, slots, kv, hd)
-    return {
-        "k": jax.ShapeDtypeStruct(shape, dtype),
-        "v": jax.ShapeDtypeStruct(shape, dtype),
-    }
+def _cache_write(
+    stack: jax.Array,        # (L, B, Kv, slots, D)
+    new: jax.Array,          # (B, S, Kv, D)
+    layer: jax.Array,
+    start: jax.Array,
+) -> jax.Array:
+    """``new`` written into ``layer``'s slots [start, start + S) of the
+    head-major stack: an update in place when the stack is a scan's
+    carry."""
+    upd = new.transpose(0, 2, 1, 3)[None].astype(stack.dtype)
+    return jax.lax.dynamic_update_slice(stack, upd, (layer, 0, 0, start, 0))
 
 
 def attention_apply(
@@ -400,7 +380,8 @@ def attention_apply(
     *,
     positions: jax.Array,              # (S,) absolute positions
     mode: str,                         # "full" | "decode"
-    layer_cache: Optional[Dict[str, jax.Array]] = None,  # (B, slots, Kv, D)
+    kv: Optional[Dict[str, jax.Array]] = None,  # (L, B, Kv, slots, D)
+    layer: Optional[jax.Array] = None,  # () int32: this layer's index in kv
     cache_len: Optional[jax.Array] = None,   # scalar int32: tokens already in cache
     causal: bool = True,
     prefix_len: int = 0,
@@ -409,7 +390,11 @@ def attention_apply(
     kv_block: int = 1024,
     full: bool = False,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """Returns (output (B,S,d_model), updated layer cache or None).
+    """Returns (output (B,S,d_model), the updated cache stacks or None).
+
+    ``kv`` is the whole head-major K/V cache of the layer stack, which
+    this layer reads and writes at ``layer`` where it lies: no slice of
+    it is taken out or stacked again.
 
     ``full`` marks a full layer of a window/full pattern
     (``cfg.full_attn_every``): no window, the full layers' rotary scaling.
@@ -455,50 +440,32 @@ def attention_apply(
                 q_block=q_block, kv_block=kv_block,
             )
         new_cache = None
-        if layer_cache is not None:
+        if kv is not None:
             # prefill: write K/V (post-RoPE) into the cache
-            slots = layer_cache["k"].shape[1]
+            slots = kv["k"].shape[3]
             with jax.named_scope("kv_write"):
                 if window is not None and S > slots:
-                    # keep the last `slots` positions, ring-aligned
-                    k_tail, v_tail = k[:, -slots:], v[:, -slots:]
-                    pos_tail = positions[-slots:]
-                    idx = pos_tail % slots
-                    ck = layer_cache["k"].at[:, idx].set(
-                        k_tail.astype(layer_cache["k"].dtype)
-                    )
-                    cv = layer_cache["v"].at[:, idx].set(
-                        v_tail.astype(layer_cache["v"].dtype)
-                    )
+                    # keep the last `slots` positions, ring-aligned: the
+                    # tail's first position lands in its slot
+                    shift = positions[-slots] % slots
+                    k, v = (jnp.roll(t[:, -slots:], shift, axis=1)
+                            for t in (k, v))
+                    start = 0
                 else:
                     start = positions[0]
                     if window is not None:
                         start = start % slots
-                    ck = jax.lax.dynamic_update_slice(
-                        layer_cache["k"],
-                        k.astype(layer_cache["k"].dtype),
-                        (0, start, 0, 0),
-                    )
-                    cv = jax.lax.dynamic_update_slice(
-                        layer_cache["v"],
-                        v.astype(layer_cache["v"].dtype),
-                        (0, start, 0, 0),
-                    )
-            new_cache = {"k": ck, "v": cv}
+                new_cache = {n: _cache_write(kv[n], t, layer, start)
+                             for n, t in (("k", k), ("v", v))}
     elif mode == "decode":
-        assert layer_cache is not None and cache_len is not None
-        slots = layer_cache["k"].shape[1]
+        assert kv is not None and cache_len is not None and layer is not None
+        slots = kv["k"].shape[3]
         pos = positions[0]  # scalar: absolute position of the new token
         slot = pos % slots if window is not None else pos
         with jax.named_scope("kv_write"):
-            ck = jax.lax.dynamic_update_slice(
-                layer_cache["k"], k.astype(layer_cache["k"].dtype),
-                (0, slot, 0, 0),
-            )
-            cv = jax.lax.dynamic_update_slice(
-                layer_cache["v"], v.astype(layer_cache["v"].dtype),
-                (0, slot, 0, 0),
-            )
+            new_cache = {n: _cache_write(kv[n], t, layer, slot)
+                         for n, t in (("k", k), ("v", v))}
+        ck, cv = new_cache["k"], new_cache["v"]
         n_filled = jnp.minimum(cache_len + 1, slots)
         slot_ids = jnp.arange(slots)
         if window is not None:
@@ -509,10 +476,9 @@ def attention_apply(
         if impl == "pallas":
             from repro.kernels import ops as kops
 
-            out = kops.flash_decode(q, ck, cv, kv_valid=valid)
+            out = kops.flash_decode(q, ck, cv, layer, kv_valid=valid)
         else:
-            out = decode_attention(q, ck, cv, kv_valid=valid)
-        new_cache = {"k": ck, "v": cv}
+            out = decode_attention(q, ck[layer], cv[layer], kv_valid=valid)
     else:
         raise ValueError(f"unknown mode {mode!r}")
 

@@ -6,10 +6,15 @@ assignment (everything except Whisper's encoder-decoder, see ``whisper.py``):
 * per-layer parameters are stacked and the layer stack is a single
   ``lax.scan`` (compile time is O(1) in depth — an 81-layer zamba2 compiles
   one block),
+* every attention cache is one head-major stack per kind of layer,
+  ``(layers, B, Kv, slots, D)``, carried by the layer scan, whose xs are
+  (layer index, layer params): each layer writes its new K/V in place and
+  ``flash_decode`` reads its slots where they lie, so no layer's cache is
+  sliced out, transposed or stacked again,
 * zamba2's *shared* attention block is closed over by the scan body: its
   weights appear once in the pytree but are applied every
-  ``hybrid_attn_every``-th step, each application with its own KV-cache
-  slice (weight sharing ≠ cache sharing),
+  ``hybrid_attn_every``-th step, each application with its own layer of
+  the KV-cache stack (weight sharing ≠ cache sharing),
 * the loss head is a *chunked* cross-entropy: logits are never materialized
   for the full sequence (vocab 257k × seq 4k would be hundreds of GB),
 * PaliGemma's vision frontend is a stub per the assignment:
@@ -28,8 +33,7 @@ assignment (everything except Whisper's encoder-decoder, see ``whisper.py``):
   with its ``kv_write``, ``ffn`` holding the expert layer's ``moe``;
   ``window`` and ``full`` around each kind of block), which a profile
   reads from each operation's metadata; what lies under ``layers`` in no
-  block scope is the scan's own slicing and stacking of per-layer weights
-  and cache.
+  block scope is the scan's own slicing of per-layer weights.
 
 Modes
 -----
@@ -197,21 +201,22 @@ class TransformerLM:
             kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
             slots = max_len
             cache["attn_kv"] = {
-                "k": mk((n_blk, batch, slots, kv, hd), dtype),
-                "v": mk((n_blk, batch, slots, kv, hd), dtype),
+                "k": mk((n_blk, batch, kv, slots, hd), dtype),
+                "v": mk((n_blk, batch, kv, slots, hd), dtype),
             }
         elif cfg.full_attn_every:
-            # two caches side by side: window layers' rings, full layers'
+            # two caches side by side: window layers' rings (window layer
+            # j of period p at p * n + j), full layers'
             kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
             P, n = cfg.periods, cfg.full_attn_every - 1
             ring = min(max_len, cfg.sliding_window)
             cache["kv_window"] = {
-                "k": mk((P, n, batch, ring, kv, hd), dtype),
-                "v": mk((P, n, batch, ring, kv, hd), dtype),
+                "k": mk((P * n, batch, kv, ring, hd), dtype),
+                "v": mk((P * n, batch, kv, ring, hd), dtype),
             }
             cache["kv_full"] = {
-                "k": mk((P, batch, max_len, kv, hd), dtype),
-                "v": mk((P, batch, max_len, kv, hd), dtype),
+                "k": mk((P, batch, kv, max_len, hd), dtype),
+                "v": mk((P, batch, kv, max_len, hd), dtype),
             }
         else:
             slots = (
@@ -222,8 +227,8 @@ class TransformerLM:
             kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
             L = cfg.num_layers
             cache["kv"] = {
-                "k": mk((L, batch, slots, kv, hd), dtype),
-                "v": mk((L, batch, slots, kv, hd), dtype),
+                "k": mk((L, batch, kv, slots, hd), dtype),
+                "v": mk((L, batch, kv, slots, hd), dtype),
             }
         if cfg.is_moe:
             # assignments each held expert of each layer received, summed
@@ -242,14 +247,15 @@ class TransformerLM:
     # Blocks
     # ==================================================================
     def _attn_block(
-        self, lp, x, *, positions, mode, layer_kv, cache_len, prefix_len,
+        self, lp, x, *, positions, mode, kv, layer, cache_len, prefix_len,
         full=False,
     ):
-        """One attention block.  Returns (x, new layer cache, aux loss,
-        load): a serving step (``layer_kv`` given) of an MoE model runs the
-        dropless expert share, whose per-expert ``load`` it returns (else
-        None); the full-sequence forward runs the capacity layer and its
-        aux loss."""
+        """One attention block, layer ``layer`` of the stack whose cache is
+        ``kv``.  Returns (x, the updated cache stacks, aux loss, load): a
+        serving step (``kv`` given) of an MoE model runs the dropless
+        expert share, whose per-expert ``load`` it returns (else None);
+        the full-sequence forward runs the capacity layer and its aux
+        loss."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         load = None
@@ -258,7 +264,7 @@ class TransformerLM:
         with jax.named_scope("attention"):
             a, new_kv = attn.attention_apply(
                 lp["attn"], cfg, h,
-                positions=positions, mode=mode, layer_cache=layer_kv,
+                positions=positions, mode=mode, kv=kv, layer=layer,
                 cache_len=cache_len, prefix_len=prefix_len, impl=self.impl,
                 q_block=self.q_block, kv_block=self.kv_block, full=full,
             )
@@ -268,7 +274,7 @@ class TransformerLM:
             with jax.named_scope("ffn"):
                 if not cfg.is_moe:
                     return mlp_apply(lp["mlp"], cfg, hn)
-                if layer_kv is not None:
+                if kv is not None:
                     f, load = moe_mod.moe_serve(lp["moe"], cfg, hn,
                                                 impl=self.impl)
                     return f
@@ -309,7 +315,9 @@ class TransformerLM:
     def _run_uniform_stack(
         self, params, x, *, positions, mode, cache, prefix_len
     ):
-        """Dense / MoE / SSM: one scanned stack."""
+        """Dense / MoE / SSM: one scanned stack.  An attention stack's
+        head-major cache ``(L, B, Kv, slots, D)`` rides in the scan's carry;
+        each layer writes and reads its own slots at its index."""
         cfg = self.cfg
         cache_len = None if cache is None else cache["len"]
 
@@ -343,36 +351,27 @@ class TransformerLM:
                 new_cache["ssm_state"] = new_states
             return x, new_cache, jnp.zeros((), jnp.float32)
 
-        # attention families
+        # attention families: the cache stacks ride in the carry, the
+        # scan's xs are (layer index, layer params)
         def body(carry, per_layer):
-            xc, aux_acc = carry
-            lp, kv_slice = per_layer
-            y, new_kv, aux, load = self._attn_block(
-                lp, xc, positions=positions, mode=mode,
-                layer_kv=kv_slice, cache_len=cache_len,
-                prefix_len=prefix_len,
+            xc, aux_acc, kv = carry
+            layer, lp = per_layer
+            y, kv, aux, load = self._attn_block(
+                lp, xc, positions=positions, mode=mode, kv=kv, layer=layer,
+                cache_len=cache_len, prefix_len=prefix_len,
             )
-            return (y, aux_acc + aux), (new_kv, load)
+            return (y, aux_acc + aux, kv), load
 
         if self.remat:
             body = jax.checkpoint(body)
         kv = cache["kv"] if cache is not None else None
-        if kv is None:
-            # no-cache forward still scans a dummy so the body is uniform
-            (x, aux), _ = jax.lax.scan(
-                lambda c, lp: (
-                    body(c, (lp, None))[0],
-                    0.0,
-                ),
-                (x, jnp.zeros((), jnp.float32)),
-                params["decoder"],
-            )
-            return x, None, aux
-        (x, aux), (new_kv, load) = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), (params["decoder"], kv)
+        (x, aux, kv), load = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32), kv),
+            (jnp.arange(cfg.num_layers), params["decoder"]),
         )
-        new_cache = dict(cache)
-        new_cache["kv"] = new_kv
+        if cache is None:
+            return x, None, aux
+        new_cache = dict(cache, kv=kv)
         if load is not None:
             new_cache["moe_load"] = cache["moe_load"] + load
         return x, new_cache, aux
@@ -383,9 +382,11 @@ class TransformerLM:
         """Window and full layers mixed (``cfg.full_attn_every``): one
         ``lax.scan`` over the periods, whose body scans the period's window
         blocks (scope ``window``) and then runs its full block (``full``),
-        each kind on its own cache.  The two caches ride in the scans'
-        carries and each layer's slice is written back in place, so
-        neither is copied whole."""
+        each kind on its own head-major cache stack: the window rings
+        ``(P·n, B, Kv, ring, D)``, window layer j of period p at
+        ``p·n + j``, and the full layers' ``(P, B, Kv, slots, D)``.  Both
+        stacks ride in the scans' carries; each layer reads and writes its
+        own slots where they lie, at its index."""
         cfg = self.cfg
         dec = params["decoder"]
         cache_len = None if cache is None else cache["len"]
@@ -393,33 +394,20 @@ class TransformerLM:
         zero = jnp.zeros((), jnp.float32)
 
         def block(full):
-            def body(xc, lp, kv):
+            def body(xc, lp, kv, layer):
                 return self._attn_block(
-                    lp, xc, positions=positions, mode=mode, layer_kv=kv,
-                    cache_len=cache_len, prefix_len=prefix_len, full=full,
+                    lp, xc, positions=positions, mode=mode, kv=kv,
+                    layer=layer, cache_len=cache_len, prefix_len=prefix_len,
+                    full=full,
                 )
 
             return jax.checkpoint(body) if self.remat else body
 
         window_block, full_block = block(False), block(True)
-
-        if cache is None:
-            def period_nc(carry, per_period):
-                wp, fp = per_period
-
-                def inner(c, lp):
-                    y, _, aux, _ = window_block(c[0], lp, None)
-                    return (y, c[1] + aux), None
-
-                with jax.named_scope("window"):
-                    carry, _ = jax.lax.scan(inner, carry, wp)
-                with jax.named_scope("full"):
-                    y, _, aux, _ = full_block(carry[0], fp, None)
-                return (y, carry[1] + aux), None
-
-            (x, aux), _ = jax.lax.scan(
-                period_nc, (x, zero), (dec["window"], dec["full"]))
-            return x, None, aux
+        kv_w = kv_f = load = None
+        if cache is not None:
+            kv_w, kv_f = cache["kv_window"], cache["kv_full"]
+            load = cache.get("moe_load")
 
         def period(carry, per_period):
             xc, aux_acc, kv_w, kv_f, load = carry
@@ -428,9 +416,7 @@ class TransformerLM:
             def inner(c, per_layer):
                 xi, aux_i, kv_w, load = c
                 j, lp = per_layer
-                kv = jax.tree.map(lambda a: a[p, j], kv_w)
-                y, kv, aux, n_load = window_block(xi, lp, kv)
-                kv_w = jax.tree.map(lambda a, b: a.at[p, j].set(b), kv_w, kv)
+                y, kv_w, aux, n_load = window_block(xi, lp, kv_w, p * n + j)
                 if n_load is not None:
                     load = load.at[p * cfg.full_attn_every + j].add(n_load)
                 return (y, aux_i + aux, kv_w, load), None
@@ -440,18 +426,17 @@ class TransformerLM:
                     inner, (xc, aux_acc, kv_w, load),
                     (jnp.arange(n), wp))
             with jax.named_scope("full"):
-                kv = jax.tree.map(lambda a: a[p], kv_f)
-                xc, kv, aux, n_load = full_block(xc, fp, kv)
-                kv_f = jax.tree.map(lambda a, b: a.at[p].set(b), kv_f, kv)
+                xc, kv_f, aux, n_load = full_block(xc, fp, kv_f, p)
                 if n_load is not None:
                     load = load.at[p * cfg.full_attn_every + n].add(n_load)
             return (xc, aux_acc + aux, kv_w, kv_f, load), None
 
-        load = cache.get("moe_load")
         (x, aux, kv_w, kv_f, load), _ = jax.lax.scan(
-            period, (x, zero, cache["kv_window"], cache["kv_full"], load),
+            period, (x, zero, kv_w, kv_f, load),
             (jnp.arange(cfg.periods), dec["window"], dec["full"]),
         )
+        if cache is None:
+            return x, None, aux
         new_cache = dict(cache, kv_window=kv_w, kv_full=kv_f)
         if load is not None:
             new_cache["moe_load"] = load
@@ -505,43 +490,30 @@ class TransformerLM:
             else zero_states((cfg.hybrid_blocks, cfg.hybrid_attn_every - 1))
         )
 
-        if cache is not None:
-            def block_body(carry, per_block):
-                xc = carry
-                blk_params, st, blk_kv = per_block
-                # shared attention (weights shared; per-block cache slice)
-                y, new_kv, _, _ = self._attn_block(
-                    shared, xc, positions=positions, mode=mode,
-                    layer_kv=blk_kv, cache_len=cache_len,
-                    prefix_len=prefix_len,
-                )
-                y, new_state = jax.lax.scan(mamba_body, y, (blk_params, st))
-                return y, (new_state, new_kv)
-
-            x, (new_blk_state, new_blk_kv) = jax.lax.scan(
-                block_body, x, (dec["blocks"], blk_state, cache["attn_kv"])
-            )
-            new_cache = dict(cache)
-            if new_prelude_state is not None:
-                new_cache["prelude_state"] = new_prelude_state
-            new_cache["block_state"] = new_blk_state
-            new_cache["attn_kv"] = new_blk_kv
-            return x, new_cache, jnp.zeros((), jnp.float32)
-
-        def block_body_nc(carry, per_block):
-            xc = carry
-            blk_params, st = per_block
-            y, _, _, _ = self._attn_block(
-                shared, xc, positions=positions, mode=mode,
-                layer_kv=None, cache_len=cache_len, prefix_len=prefix_len,
+        def block_body(carry, per_block):
+            xc, kv = carry
+            blk, blk_params, st = per_block
+            # shared attention (weights shared; block blk's own cache)
+            y, kv, _, _ = self._attn_block(
+                shared, xc, positions=positions, mode=mode, kv=kv,
+                layer=blk, cache_len=cache_len, prefix_len=prefix_len,
             )
             y, new_state = jax.lax.scan(mamba_body, y, (blk_params, st))
-            return y, new_state
+            return (y, kv), new_state
 
         if self.remat:
-            block_body_nc = jax.checkpoint(block_body_nc)
-        x, _ = jax.lax.scan(block_body_nc, x, (dec["blocks"], blk_state))
-        return x, None, jnp.zeros((), jnp.float32)
+            block_body = jax.checkpoint(block_body)
+        kv = cache["attn_kv"] if cache is not None else None
+        (x, kv), new_blk_state = jax.lax.scan(
+            block_body, (x, kv),
+            (jnp.arange(cfg.hybrid_blocks), dec["blocks"], blk_state),
+        )
+        if cache is None:
+            return x, None, jnp.zeros((), jnp.float32)
+        new_cache = dict(cache, block_state=new_blk_state, attn_kv=kv)
+        if new_prelude_state is not None:
+            new_cache["prelude_state"] = new_prelude_state
+        return x, new_cache, jnp.zeros((), jnp.float32)
 
     def _run_stack(self, params, x, *, positions, mode, cache, prefix_len):
         if self.cfg.family == "hybrid":

@@ -146,15 +146,16 @@ class EncDecLM:
     # Cross attention
     # ------------------------------------------------------------------
     def _cross_kv(self, lp, enc_out: jax.Array):
+        """Cross K/V, head-major (B, Kv, S_enc, D) as the caches are."""
         dt = enc_out.dtype
-        k = jnp.einsum("bsd,dhk->bshk", enc_out, lp["wk"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bshk", enc_out, lp["wv"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bhsk", enc_out, lp["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bhsk", enc_out, lp["wv"].astype(dt))
         return k, v
 
     def _cross_attend(self, lp, cfg, x, ck, cv):
         dt = x.dtype
         q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(dt))
-        S_enc = ck.shape[1]
+        S_enc = ck.shape[2]
         valid = jnp.ones((x.shape[0], S_enc), bool)
         if x.shape[1] == 1:
             out = attn.decode_attention(q, ck, cv, kv_valid=valid)
@@ -162,7 +163,8 @@ class EncDecLM:
             pos_q = jnp.arange(x.shape[1], dtype=jnp.int32)
             pos_k = jnp.arange(S_enc, dtype=jnp.int32)
             out = attn.blockwise_attention(
-                q, ck, cv, q_pos=pos_q, kv_pos=pos_k, causal=False,
+                q, ck.transpose(0, 2, 1, 3), cv.transpose(0, 2, 1, 3),
+                q_pos=pos_q, kv_pos=pos_k, causal=False,
                 q_block=self.q_block, kv_block=self.kv_block,
             )
         return jnp.einsum("bshk,hkd->bsd", out, lp["wo"].astype(dt))
@@ -170,13 +172,13 @@ class EncDecLM:
     # ------------------------------------------------------------------
     # Decoder
     # ------------------------------------------------------------------
-    def _dec_block(self, lp, x, *, positions, mode, self_kv, cross_k,
+    def _dec_block(self, lp, x, *, positions, mode, kv, layer, cross_k,
                    cross_v, cache_len):
         cfg = self.cfg
         h = layer_norm(x, lp["ln1"], cfg.norm_eps)
         a, new_kv = attn.attention_apply(
             lp["self_attn"], cfg, h, positions=positions, mode=mode,
-            layer_cache=self_kv, cache_len=cache_len, impl=self.impl,
+            kv=kv, layer=layer, cache_len=cache_len, impl=self.impl,
             q_block=self.q_block, kv_block=self.kv_block,
         )
         x = x + a
@@ -196,8 +198,8 @@ class EncDecLM:
             def body(xc, lp):
                 ck, cv = self._cross_kv(lp["cross_attn"], enc_out)
                 y, _ = self._dec_block(
-                    lp, xc, positions=positions, mode=mode, self_kv=None,
-                    cross_k=ck, cross_v=cv, cache_len=None,
+                    lp, xc, positions=positions, mode=mode, kv=None,
+                    layer=None, cross_k=ck, cross_v=cv, cache_len=None,
                 )
                 return y, None
 
@@ -206,18 +208,21 @@ class EncDecLM:
             x, _ = jax.lax.scan(body, x, params["decoder"])
             return x, None
 
-        def body(xc, per_layer):
-            lp, kv_slice, ck, cv = per_layer
-            y, new_kv = self._dec_block(
-                lp, xc, positions=positions, mode=mode, self_kv=kv_slice,
+        # the self-attention cache stack rides in the carry, read and
+        # written at each layer's index
+        def body(carry, per_layer):
+            xc, kv = carry
+            layer, lp, ck, cv = per_layer
+            y, kv = self._dec_block(
+                lp, xc, positions=positions, mode=mode, kv=kv, layer=layer,
                 cross_k=ck, cross_v=cv, cache_len=cache_len,
             )
-            return y, new_kv
+            return (y, kv), None
 
-        x, new_kv = jax.lax.scan(
+        (x, new_kv), _ = jax.lax.scan(
             body,
-            x,
-            (params["decoder"], cache["kv"], cache["cross_k"],
+            (x, cache["kv"]),
+            (jnp.arange(cfg.num_layers), params["decoder"], cache["cross_k"],
              cache["cross_v"]),
         )
         new_cache = dict(cache)
@@ -239,11 +244,11 @@ class EncDecLM:
         return {
             "len": mk((), jnp.int32),
             "kv": {
-                "k": mk((L, batch, max_len, kv, hd), dtype),
-                "v": mk((L, batch, max_len, kv, hd), dtype),
+                "k": mk((L, batch, kv, max_len, hd), dtype),
+                "v": mk((L, batch, kv, max_len, hd), dtype),
             },
-            "cross_k": mk((L, batch, enc_len, kv, hd), dtype),
-            "cross_v": mk((L, batch, enc_len, kv, hd), dtype),
+            "cross_k": mk((L, batch, kv, enc_len, hd), dtype),
+            "cross_v": mk((L, batch, kv, enc_len, hd), dtype),
         }
 
     def init_cache(self, batch, max_len, dtype=jnp.bfloat16,

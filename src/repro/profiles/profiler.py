@@ -123,14 +123,15 @@ def _decode_cases(
         B, S = batch, cache_tokens
         H, Kv, D = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
         q = _rnd(8, (B, 1, H, D), jnp.bfloat16)
-        kc = _rnd(9, (B, S, Kv, D), jnp.bfloat16)
-        vc = _rnd(10, (B, S, Kv, D), jnp.bfloat16)
+        # one layer's head-major cache, as a one-layer stack
+        kc = _rnd(9, (1, B, Kv, S, D), jnp.bfloat16)
+        vc = _rnd(10, (1, B, Kv, S, D), jnp.bfloat16)
         valid = jnp.ones((B, S), jnp.int8)
         # decode attention streams the whole K and V cache once
         nbytes = 2.0 * B * Kv * S * D * kc.dtype.itemsize
         cases.append((
             lambda: ops.flash_decode(
-                q, kc, vc, kv_valid=valid, interpret=interpret
+                q, kc, vc, jnp.int32(0), kv_valid=valid, interpret=interpret
             ),
             nbytes,
         ))

@@ -197,22 +197,88 @@ DECODE_CASES = [
     (2, 4, 4, 384, 64, 100),     # short occupancy
 ]
 
+#: layers of the stacked caches the decode tests read from
+DECODE_LAYERS = 3
+
+
+def _decode_stack(B, Kv, S, D, dtype):
+    """(k, v) stacks of DECODE_LAYERS layers, each layer its own draw."""
+    shape = (DECODE_LAYERS, B, Kv, S, D)
+    return rnd(8, shape, dtype), rnd(9, shape, dtype)
+
 
 @pytest.mark.parametrize("case", DECODE_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_decode_matches_ref(case, dtype):
     B, H, Kv, S, D, n_valid = case
     q = rnd(7, (B, H, D), dtype)
-    k = rnd(8, (B, Kv, S, D), dtype)
-    v = rnd(9, (B, Kv, S, D), dtype)
+    k, v = _decode_stack(B, Kv, S, D, dtype)
     valid = (jnp.arange(S)[None, :] < n_valid).astype(jnp.int8)
     valid = jnp.broadcast_to(valid, (B, S))
-    got = flash_decode_bhd(q, k, v, valid, block_kv=128, interpret=True)
-    want = ref.flash_decode_ref(q, k, v, valid)
-    np.testing.assert_allclose(
-        got.astype(np.float32), want.astype(np.float32),
-        atol=TOLS[dtype], rtol=TOLS[dtype],
-    )
+    for layer in range(DECODE_LAYERS):
+        got = flash_decode_bhd(q, k, v, valid, jnp.int32(layer),
+                               block_kv=128, interpret=True)
+        want = ref.flash_decode_ref(q, k[layer], v[layer], valid)
+        np.testing.assert_allclose(
+            got.astype(np.float32), want.astype(np.float32),
+            atol=TOLS[dtype], rtol=TOLS[dtype],
+        )
+
+
+def _decode_mask(kind, B, S):
+    """(B, S) validity: every slot; a prefix per row (a cache filling
+    up); or a ring that wrapped, each row live from its own slot on and
+    again from slot 0 (the oldest entries overwritten)."""
+    slot = jnp.arange(S)[None, :]
+    if kind == "full":
+        return jnp.ones((B, S), bool)
+    ends = jnp.array([S // 3, S - 5, 1, S // 2])[:B, None]
+    if kind == "partial":
+        return slot < ends
+    return (slot >= ends) | (slot < ends // 2)
+
+
+@pytest.mark.parametrize("mask", ["full", "partial", "ring"])
+@pytest.mark.parametrize("H,Kv", [(8, 1), (4, 4)], ids=["G8", "G1"])
+def test_flash_decode_reads_the_indexed_layer(H, Kv, mask):
+    """Through the model-layout wrapper, with 512-row KV blocks: each
+    layer of the stack gives that layer's attention, which differs from
+    every other layer's, so reading the wrong layer fails."""
+    B, S, D = 4, 1024, 128
+    q = rnd(7, (B, 1, H, D), jnp.float32)
+    k, v = _decode_stack(B, Kv, S, D, jnp.float32)
+    valid = _decode_mask(mask, B, S)
+    want = [ref.flash_decode_ref(q[:, 0], k[i], v[i], valid)
+            for i in range(DECODE_LAYERS)]
+    for layer in range(DECODE_LAYERS):
+        got = ops.flash_decode(q, k, v, jnp.int32(layer), kv_valid=valid,
+                               interpret=True)[:, 0]
+        np.testing.assert_allclose(got, want[layer], atol=2e-5, rtol=2e-5)
+        for other in range(DECODE_LAYERS):
+            if other != layer:
+                assert float(jnp.abs(got - want[other]).max()) > 0.1
+
+
+@pytest.mark.parametrize("S,block", [(256, 256), (1024, 512), (8704, 512),
+                                     (640, 128), (544, 544), (136, 136)])
+def test_flash_decode_kv_block(S, block):
+    """KV blocks divide the cache, so no caller pads the stack: 512 rows
+    where they divide it, a smaller multiple of 128 where that does, else
+    the whole cache as one block."""
+    from repro.kernels.flash_decode import kv_block
+
+    assert kv_block(S, 512) == block
+
+
+def test_flash_decode_undivided_cache_matches_ref():
+    """544 slots (no multiple of 128 divides them) read as one block."""
+    B, H, Kv, S, D = 2, 8, 2, 544, 64
+    q = rnd(7, (B, H, D), jnp.float32)
+    k, v = _decode_stack(B, Kv, S, D, jnp.float32)
+    valid = _decode_mask("partial", B, S)
+    got = flash_decode_bhd(q, k, v, valid, jnp.int32(2), interpret=True)
+    want = ref.flash_decode_ref(q, k[2], v[2], valid)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,7 @@ def test_build_step_shapes(shape_name):
         tokens = built.abstract_args[1]
         assert tokens.shape == (spec.global_batch, 1)
         cache = built.abstract_args[2]
-        assert cache["kv"]["k"].shape[2] == spec.seq_len  # cache slots
+        assert cache["kv"]["k"].shape[3] == spec.seq_len  # cache slots
         assert built.donate == (2,)
 
 
@@ -55,7 +55,7 @@ def test_swa_cache_is_ring_sized():
     built = build_step("h2o-danube3-4b", "decode_32k", mesh)
     cache = built.abstract_args[2]
     cfg = get_config("h2o-danube3-4b")
-    assert cache["kv"]["k"].shape[2] == cfg.sliding_window
+    assert cache["kv"]["k"].shape[3] == cfg.sliding_window
 
 
 def test_ssm_decode_has_o1_state():

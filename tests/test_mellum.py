@@ -187,6 +187,42 @@ def test_prefill_then_decode_matches_reference(params, tokens, impl,
     assert _gap(params, tokens, served) <= TOL
 
 
+def test_pallas_decode_matches_blockwise_every_step(params, tokens,
+                                                   interpret):
+    """Two periods served past the prompt for two more wraps of the
+    16-slot ring, float32: the pallas step gives the jnp path's logits,
+    window rings and full cache at every step, each layer reading and
+    writing its own slots of the stacked caches."""
+    steps = 2 * CFG.sliding_window
+    toks = jax.random.randint(jax.random.PRNGKey(6), (B, PROMPT + steps), 0,
+                              CFG.vocab_size)
+    caches, step = {}, {}
+    for impl in ("blockwise", "pallas"):
+        model = build_model(CFG, impl=impl)
+        _, caches[impl] = model.prefill(
+            params, toks[:, :PROMPT],
+            model.init_cache(B, PROMPT + steps, dtype=F32), dtype=F32)
+        step[impl] = jax.jit(functools.partial(model.decode_step, dtype=F32))
+
+    def close(t):
+        for kind in ("kv_window", "kv_full"):
+            for name in ("k", "v"):
+                np.testing.assert_allclose(
+                    caches["pallas"][kind][name],
+                    caches["blockwise"][kind][name], atol=1e-4, rtol=1e-4,
+                    err_msg=f"{kind} {name} at {t}")
+
+    close(PROMPT)
+    for t in range(PROMPT, PROMPT + steps):
+        out = {}
+        for impl in step:
+            out[impl], caches[impl] = step[impl](params, toks[:, t:t + 1],
+                                                 caches[impl])
+        np.testing.assert_allclose(out["pallas"], out["blockwise"],
+                                   atol=TOL, rtol=0, err_msg=f"step {t}")
+        close(t)
+
+
 @pytest.mark.parametrize("drop", ["yarn", "window"])
 def test_reference_without_a_mechanism_disagrees(params, tokens, drop):
     served = _served(params, tokens, "blockwise")

@@ -141,6 +141,50 @@ def test_pallas_serving_step_matches_blockwise(arch, monkeypatch):
         assert err <= 0.02 + 0.004 * float(jnp.abs(want32).max()), err
 
 
+def _assert_close(want, got, what):
+    want32, got32 = want.astype(jnp.float32), got.astype(jnp.float32)
+    err = float(jnp.abs(want32 - got32).max())
+    assert err <= 1e-4 * (1.0 + float(jnp.abs(want32).max())), (what, err)
+
+
+def test_pallas_decode_matches_blockwise_every_step(monkeypatch):
+    """A three-layer command-r served for 40 decode steps, float32: the
+    pallas step (each layer's ``flash_decode`` reading the stacked cache
+    at its index) gives the jnp path's logits and cache at every step.
+    A kernel that read another layer's cache, or a write that landed in
+    another layer's slots, fails at the first step."""
+    from repro.kernels import ops
+
+    for name in ("flash_attention", "flash_decode"):
+        monkeypatch.setattr(ops, name, functools.partial(
+            getattr(ops, name), interpret=True))
+    cfg = get_config("command-r-35b").scaled(num_layers=3)
+    params = build_model(cfg).init(KEY, jnp.float32)
+    B, S, STEPS = 2, 24, 40
+    toks, _, _ = _inputs(cfg, B=B, S=S + STEPS)
+    models = {impl: build_model(cfg, impl=impl)
+              for impl in ("blockwise", "pallas")}
+    caches, steps = {}, {}
+    for impl, model in models.items():
+        lg, caches[impl] = model.prefill(
+            params, toks[:, :S], model.init_cache(B, S + STEPS, jnp.float32),
+            dtype=jnp.float32)
+        steps[impl] = jax.jit(functools.partial(model.decode_step,
+                                                dtype=jnp.float32))
+    for name in ("k", "v"):
+        _assert_close(caches["blockwise"]["kv"][name],
+                      caches["pallas"]["kv"][name], ("prefill", name))
+    for t in range(S, S + STEPS):
+        out = {}
+        for impl in models:
+            out[impl], caches[impl] = steps[impl](
+                params, toks[:, t:t + 1], caches[impl])
+        _assert_close(out["blockwise"], out["pallas"], ("logits", t))
+        for name in ("k", "v"):
+            _assert_close(caches["blockwise"]["kv"][name],
+                          caches["pallas"]["kv"][name], (name, t))
+
+
 @pytest.mark.parametrize("arch", ARCH_IDS)
 def test_long_context_rule(arch):
     """long_500k runs only for sub-quadratic archs (assignment rule)."""
@@ -163,7 +207,7 @@ def test_sliding_window_ring_buffer():
     toks = jax.random.randint(jax.random.PRNGKey(3), (B, S), 0,
                               cfg.vocab_size)
     cache = model.init_cache(B, S + 8)
-    assert cache["kv"]["k"].shape[2] == cfg.sliding_window  # ring slots
+    assert cache["kv"]["k"].shape[3] == cfg.sliding_window  # ring slots
     lg, cache = model.prefill(params, toks[:, :8], cache)
     for t in range(8, S):
         lg, cache = model.decode_step(params, toks[:, t : t + 1], cache)

@@ -96,18 +96,86 @@ def test_flash_attention_compiles_at_replica_prefill(one_chip):
 
 
 def test_flash_decode_compiles(one_chip):
-    """B4 over a 4096-token cache, command-r-35b heads (G = 8)."""
+    """B4 over a 4096-token cache, command-r-35b heads (G = 8), read at
+    layer 3 of a 5-layer stack."""
     B, S = 4, 4096
     txt = _compile_text(
-        lambda q, k, v, m: ops.flash_decode(
-            q, k, v, kv_valid=m, interpret=False
+        lambda q, k, v, layer, m: ops.flash_decode(
+            q, k, v, layer, kv_valid=m, interpret=False
         ),
         _sds(one_chip, (B, 1, 64, 128)),
-        _sds(one_chip, (B, S, 8, 128)),
-        _sds(one_chip, (B, S, 8, 128)),
+        _sds(one_chip, (5, B, 8, S, 128)),
+        _sds(one_chip, (5, B, 8, S, 128)),
+        _sds(one_chip, (), jnp.int32),
         _sds(one_chip, (B, S), jnp.bool_),
     )
     assert "tpu_custom_call" in txt
+
+
+def _cache_shaped(txt: str, stacks) -> list:
+    """(name, opcode, operands) of each instruction of the compiled
+    program, outside fused computations, whose result holds a whole
+    cache stack of ``stacks`` or one layer of it: the same dimensions in
+    any order, ones dropped.  Instructions that move nothing (parameters,
+    tuples and their elements, bitcasts) are left out."""
+    import re
+
+    def dims(shape):
+        return tuple(sorted(d for d in shape if d != 1))
+
+    wanted = {dims(s) for s in stacks} | {dims(s[1:]) for s in stacks}
+    found, fused = [], False
+    for line in txt.splitlines():
+        head = re.match(r"(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            fused = head.group(2).startswith("fused")
+            continue
+        m = re.match(r"\s+(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w-]+)\((.*)", line)
+        if fused or not m or m.group(3) in (
+                "parameter", "get-tuple-element", "tuple", "bitcast"):
+            continue
+        if dims(int(d) for d in m.group(2).split(",") if d) in wanted:
+            found.append((m.group(1), m.group(3), m.group(4)))
+    return found
+
+
+@pytest.mark.parametrize("arch", ["command-r-35b", "mellum2-12b"])
+def test_decode_step_leaves_the_cache_where_it_lies(one_chip, arch):
+    """The served decode step (smoke config, ``impl="pallas"``, the cache
+    donated as the benchmark's drivers donate it) touches its cache stacks
+    only through one in-place ``dynamic-update-slice`` of K and one of V
+    per kind of layer, which each ``flash_decode`` call reads: no copy,
+    slice, transpose or restack of a layer's cache or of a whole stack.
+    The head width (128) and window (1024) are the published ones and the
+    cache is serving-sized, so the stacks stay in HBM as when served (the
+    compiler moves small ones to VMEM whole)."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+
+    cfg = dataclasses.replace(get_smoke_config(arch), head_dim=128)
+    if cfg.sliding_window:
+        cfg = dataclasses.replace(cfg, sliding_window=1024)
+    model = build_model(cfg, impl="pallas")
+    params, cache = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        (model.abstract(jnp.bfloat16), model.abstract_cache(32, 8192)),
+    )
+    stacks = [c["k"].shape for n, c in cache.items() if n.startswith("kv")]
+    txt = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+        params, _sds(one_chip, (32, 1), jnp.int32), cache).compile().as_text()
+    found = _cache_shaped(txt, stacks)
+    writes = [name for name, op, _ in found if op == "dynamic-update-slice"]
+    assert len(writes) == 2 * len(stacks), found
+    assert [f for f in found if f[1] != "dynamic-update-slice"] == [], found
+    reads = re.findall(r"%flash_decode\.\d+ = .*? custom-call\(([^)]*)\)",
+                       txt)
+    assert len(reads) == len(stacks)
+    for operands in reads:
+        assert sum(f"%{w}," in operands + "," for w in writes) == 2, operands
 
 
 @pytest.mark.parametrize("mode", ["prefill", "decode"])
