@@ -18,7 +18,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from repro.cluster.traces import SpotTrace
 from repro.experiments import ScenarioReport, ScenarioSuite
-from repro.serving.sim import ServingResult
+from repro.serving.result import ServingResult
 from repro.service import Service, ServiceSpec
 from repro.workloads import Request
 

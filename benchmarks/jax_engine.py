@@ -7,18 +7,10 @@ and records wall-clock to ``artifacts/bench/jax_engine.json``:
 * ``jax`` — phase A (per-cell control-plane replay) + phase B (all
   request-model data planes batched into one ``lax.scan`` program per
   shape group), cold (includes XLA compile) and warm;
-* ``vector`` — the per-cell NumPy engine on the same matrix, serial;
-* ``legacy`` — the per-request object simulator on a sampled sub-matrix
-  (it is far too slow to run the full grid), reported per-cell.
+* ``vector`` — the per-cell NumPy engine on the same matrix, serial.
 
-The headline ``speedup_vs_recorded_legacy_x`` compares matrix throughput
-(cells/s) against the legacy serial throughput recorded in
-``artifacts/bench/engine_speedup.json``.  Cell composition differs
-between the two artifacts (this matrix: 1 h cells at 1 req/s; the
-recorded baseline: 8 h e2e cells at ~2.5 req/s), so the row also carries
-``hours`` / ``requests_per_cell`` for this matrix, the baseline's
-``recorded_*`` fields, and same-matrix ratios (``same_matrix_*``)
-measured on identical cells — read the ratio you care about.
+These are host timings of whatever backend JAX runs on; the chip's
+numbers come from the on-chip benchmark (``bench/run.py``).
 
 Jax and vector metrics are asserted identical cell-for-cell (the
 differential guarantee of tests/test_jax_engine.py, re-checked here
@@ -27,18 +19,11 @@ end-to-end), so the timing comparison is apples-to-apples.
 
 from __future__ import annotations
 
-import json
 import math
-import os
 from typing import Dict, List
 
-from benchmarks.common import ART, emit_csv, save
+from benchmarks.common import emit_csv, save
 from repro.experiments import ScenarioSuite
-
-# recorded headline of benchmarks/engine_speedup.py (the pre-jax
-# artifact this benchmark is measured against); used as a fallback when
-# artifacts/bench/engine_speedup.json is absent
-_RECORDED_LEGACY = {"legacy_serial_s": 28.73, "n_cells": 10, "hours": 8.0}
 
 
 def _spec(n_seeds: int, hours: float) -> Dict:
@@ -97,16 +82,6 @@ def _cells_match(a: List[Dict], b: List[Dict]) -> bool:
     return True
 
 
-def _recorded_baseline() -> Dict:
-    path = os.path.join(ART, "engine_speedup.json")
-    if os.path.exists(path):
-        with open(path) as f:
-            for row in json.load(f):
-                if row.get("metric") == "e2e_matrix_wall_clock":
-                    return row
-    return dict(_RECORDED_LEGACY)
-
-
 def run(quick: bool = False) -> List[Dict]:
     n_seeds = 4 if quick else 48
     hours = 1.0
@@ -126,13 +101,6 @@ def run(quick: bool = False) -> List[Dict]:
             "matrix — differential guarantee violated"
         )
 
-    # legacy on a sampled sub-matrix: same spec, first seeds only
-    legacy_seeds = min(2, n_seeds)
-    legacy = build_suite(legacy_seeds, hours).run(engine="legacy")
-    legacy_per_cell = legacy.wall_s / len(legacy.cells)
-
-    base = _recorded_baseline()
-    recorded_cells_per_s = base["n_cells"] / base["legacy_serial_s"]
     thpt = n_cells / jax_warm.wall_s
 
     spec = _spec(n_seeds, hours)
@@ -150,19 +118,9 @@ def run(quick: bool = False) -> List[Dict]:
             "jax_warm_s": round(jax_warm.wall_s, 2),
             "compile_s": round(jax_cold.wall_s - jax_warm.wall_s, 2),
             "throughput_cells_per_s": round(thpt, 2),
-            "recorded_legacy_cells_per_s": round(recorded_cells_per_s, 3),
-            "recorded_legacy_hours": base["hours"],
-            "speedup_vs_recorded_legacy_x": round(
-                thpt / recorded_cells_per_s, 1
-            ),
             "vector_serial_s": round(vector.wall_s, 2),
             "same_matrix_vs_vector_x": round(
                 vector.wall_s / jax_warm.wall_s, 2
-            ),
-            "legacy_sampled_cells": len(legacy.cells),
-            "legacy_sample_per_cell_s": round(legacy_per_cell, 3),
-            "same_matrix_vs_legacy_x": round(
-                legacy_per_cell / (jax_warm.wall_s / n_cells), 2
             ),
             "metrics_identical": True,
         }

@@ -22,7 +22,6 @@ from benchmarks import (
     cost,
     e2e_compare,
     engine_bench,
-    engine_speedup,
     jax_engine,
     latency,
     migration,
@@ -39,7 +38,6 @@ MODULES = {
     "latency": latency,              # Fig. 15
     "sensitivity": sensitivity,      # Fig. 14c/d
     "engine_bench": engine_bench,    # Fig. 6
-    "engine_speedup": engine_speedup,  # legacy vs vector matrix timing
     "jax_engine": jax_engine,        # jit/vmap batched matrix throughput
     "roofline": roofline,            # deliverable (g)
     "token_engine": token_engine,    # request- vs token-level replicas

@@ -1,25 +1,27 @@
 """engine-parity: a spec field one engine honors, every engine must honor.
 
-The RTT-timeout bug class (PR 7): ``ServingSimulator`` applied the
-client-RTT term to timeout expiry while ``VectorizedServingEngine``
-initially did not — both "supported" ``SimSpec.timeout_s`` yet made
-different decisions from the same spec.  The cheap, statically checkable
-proxy for that invariant: every ``SimSpec`` / ``ServingSpec`` /
-``MigrationSpec`` field *consumed* (attribute read, keyword, parameter)
-by one engine's file set must be consumed by all three, or the field
-must be exempted with a justification naming the fallback contract.
+The RTT-timeout bug class (PR 7): one serving engine applied the
+client-RTT term to timeout expiry while another did not — both
+"supported" ``SimSpec.timeout_s`` yet made different decisions from the
+same spec.  The cheap, statically checkable proxy for that invariant:
+every ``SimSpec`` / ``ServingSpec`` / ``MigrationSpec`` field *consumed*
+(attribute read, keyword, parameter) by one engine's file set must be
+consumed by the other, or the field must be exempted with a
+justification naming the fallback contract.
 
 File sets:
 
-* ``legacy`` — ``serving/sim.py`` + ``serving/replica.py``;
-* ``vector`` — ``serving/engine.py``;
+* ``vector`` — ``serving/engine.py``, the reference engine;
 * ``jax`` — ``serving/jaxengine/*``, which *inherits* the vector set
   (``JaxServingEngine`` subclasses ``VectorizedServingEngine``, so
   everything the vector engine consumes is consumed on the jax path);
 * shared data-plane/migration modules (``serving/token/*``,
-  ``migration/planner|runtime``) count for every engine — both engines
-  drive the same token batches and migration runtime, and jax delegates
-  token cells to the vector path.
+  ``migration/planner|runtime``) count for every engine — jax delegates
+  token cells to the vector path's token batches and migration runtime.
+
+Because jax inherits vector, what the pass can flag is a field the jax
+files consume and the vector engine does not: a knob only the fast
+engine honors.
 
 Fields consumed by *no* engine are builder-resolved (horizon, seeds,
 engine selection) and are the spec-drift pass's problem, not parity's.
@@ -46,10 +48,6 @@ SPEC_CLASSES: Dict[str, str] = {
 }
 
 ENGINE_FILES: Dict[str, Tuple[str, ...]] = {
-    "legacy": (
-        "src/repro/serving/sim.py",
-        "src/repro/serving/replica.py",
-    ),
     "vector": ("src/repro/serving/engine.py",),
     "jax": (
         "src/repro/serving/jaxengine/engine.py",
@@ -66,7 +64,6 @@ SHARED_FILES: Tuple[str, ...] = (
     "src/repro/serving/token/batch.py",
     "src/repro/serving/token/config.py",
     "src/repro/serving/token/metrics.py",
-    "src/repro/serving/token/replica.py",
     "src/repro/migration/planner.py",
     "src/repro/migration/runtime.py",
 )

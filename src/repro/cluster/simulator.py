@@ -16,7 +16,7 @@ Replays a spot obtainability trace against a policy: at each control tick,
 4. **metrics** — availability (ready >= N_Tar), ready-count time series and
    per-second billing (including the provisioning period, §2.3).
 
-The serving-quality simulator (``repro.serving.sim``) composes this class
+The serving engines (``repro.serving.engine``) compose this class
 with the request/LB layer; this module is policy-vs-trace only, which is all
 Fig. 14a/14b need.
 """

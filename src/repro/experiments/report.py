@@ -38,7 +38,7 @@ from typing import Any, Dict, List, Mapping, Optional
 
 import numpy as np
 
-from repro.serving.sim import ServingResult
+from repro.serving.result import ServingResult
 
 __all__ = ["CellResult", "ScenarioReport", "SCHEMA_VERSION"]
 

@@ -391,7 +391,7 @@ class ScenarioSuite:
         """Run every scenario; returns the aggregated report.
 
         ``engine`` overrides ``spec.sim.engine`` for every cell
-        ("vector" / "legacy" / "jax").  ``workers`` fans independent
+        ("vector" / "jax").  ``workers`` fans independent
         cells out over processes ("auto" = one per CPU); results are
         identical for any worker count.  ``save_to`` writes the JSON
         artifact into the given directory (e.g. ``artifacts/bench``).

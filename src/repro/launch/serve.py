@@ -28,6 +28,7 @@ from repro.compile_cache import use_compile_cache
 from repro.configs import ARCH_IDS
 from repro.core.policy import registered_policies
 from repro.service import Service, load_spec
+from repro.service.spec import ENGINE_NAMES
 
 
 def spec_from_args(args: argparse.Namespace) -> dict:
@@ -102,7 +103,7 @@ def main(argv=None) -> int:
                     help="run sweep cells in N worker processes "
                     "('auto' = one per CPU); default serial")
     ap.add_argument("--engine", default=None,
-                    choices=["vector", "legacy", "jax"],
+                    choices=list(ENGINE_NAMES),
                     help="override sim.engine for this run")
     ap.add_argument("--replica-model", default=None,
                     choices=["request", "token"],

@@ -14,7 +14,7 @@ Two cost surfaces, both pure arithmetic:
   movement.  Shrinking the ``data`` axis relocates the dropped replicas'
   KV; shrinking a model axis additionally re-partitions the weights.
   This is a pricing API for the planner and reports — replicas in the
-  serving simulators are single-instance, so the engines consume only
+  serving engines are single-instance, so the engines consume only
   the KV-transfer surface.
 """
 

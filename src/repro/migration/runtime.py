@@ -74,7 +74,7 @@ class MigrationRuntime:
         self.spec = spec
         self.engine_cfg = engine_cfg    # TokenEngineConfig (duck-typed)
         # events derive solely from inputs + the pure planner's outcome,
-        # so both engines emit identical streams through here
+        # so the stream through here is deterministic
         self.obs = obs if obs is not None else ObsRecorder(detail="off")
 
     # ------------------------------------------------------------------

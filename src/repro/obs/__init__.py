@@ -23,11 +23,10 @@ The observability substrate every engine shares:
   attribution report, convert a log to a Perfetto trace.
 
 Events are emitted at the *shared* choke points (``ClusterSimulator``,
-``MigrationRuntime``, the engine tick), so the legacy and vectorized
-engines produce byte-identical JSONL on the same spec and the JAX engine
-reproduces the control-plane stream through its phase-A replay —
-differential-testable like every other engine surface in this repo
-(tests/test_obs.py).
+``MigrationRuntime``, the engine tick), so the vector engine's JSONL is
+deterministic (its bytes are pinned in tests/data/engine_record.json)
+and the JAX engine reproduces the control-plane stream through its
+phase-A replay (tests/test_obs.py).
 """
 
 from repro.obs.attribution import attribution_report

@@ -9,8 +9,9 @@ live (event objects) or from its log file (dicts) with the same code.
 
 Determinism contract: events carry *simulation* time only — no wall
 clocks, no ids derived from memory addresses — so two decision-identical
-engines produce byte-identical logs (the differential test in
-tests/test_obs.py holds legacy == vector on the serialized bytes).
+runs produce byte-identical logs (tests/test_obs.py holds the vector
+engine to recorded bytes, and the JAX engine to the vector engine's
+control-plane records).
 """
 
 from __future__ import annotations

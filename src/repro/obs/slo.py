@@ -16,11 +16,11 @@ Three SLOs are tracked where signals exist:
   targets, token replica model only (request cells have no token
   timings).
 
-The monitor is fed once per sample window from the engines' shared
+The monitor is fed once per sample window from the engine's
 ``WindowSampler`` choke point with *order-independent* inputs (window
 deltas of cumulative counters, violation counts over the window's new
-token records), so the legacy and vectorized engines emit byte
--identical ``SLOBurnEvent`` streams.
+token records), so the ``SLOBurnEvent`` stream does not depend on the
+order in which requests resolved inside a window.
 """
 
 from __future__ import annotations

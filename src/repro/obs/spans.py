@@ -9,26 +9,26 @@ record with contiguous, time-ordered segments.
 
 Design constraints (mirrors ``repro.obs.events``):
 
-* **byte-identical across engines** — the legacy ``ServingSimulator``
-  and the ``VectorizedServingEngine`` tap the collector with the same
-  float values at the same simulated instants, and records serialize
-  sorted by ordinal, so the JSONL streams match byte for byte
-  regardless of internal iteration order;
+* **independent of iteration order** — records are keyed by ordinal
+  and serialize sorted by it, so the JSONL depends only on the collector
+  calls each ordinal received, not on the order in which the engine
+  visited requests (the vector engine's bytes are pinned in
+  ``tests/data/engine_record.json``; the JAX reconstruction matches
+  them on the requests it can see);
 * **deterministic sampling without an RNG** — whether a request is
   traced depends only on its run ordinal (position in the stable
   arrival-time sort of the request tape) and the configured rate, via a
   Knuth multiplicative hash.  No RNG state, no seed plumbing, and every
   engine (including the JAX phase-B reconstruction) agrees on the
   sampled set by construction;
-* **cheap when off** — engines bind ``want_l`` / ``want_ids`` locally
-  and skip all collector calls for unsampled ordinals, so the default
-  1% rate stays inside the observability overhead budget.
+* **cheap when off** — the engine binds ``want_l`` locally and skips
+  all collector calls for unsampled ordinals, so the default 1% rate
+  stays inside the observability overhead budget.
 
-Per-request call-sequence contract (what byte-identity actually
-requires): for any single ordinal, both engines issue the same
-collector calls with the same arguments in the same order.  Cross
--request interleaving is free to differ — records are keyed and sorted
-by ordinal.
+Per-request call-sequence contract: a span is fixed by the collector
+calls one ordinal receives, with their arguments, in order.  Cross
+-request interleaving is free — records are keyed and sorted by
+ordinal.
 """
 
 from __future__ import annotations
@@ -85,24 +85,17 @@ class SpanCollector:
     """Collects per-request span traces for the sampled ordinal set.
 
     ``requests`` is the raw request tape; ordinals are positions in the
-    stable sort by ``arrival_s`` — exactly the tape order both serving
-    engines compile, so the vector engine's tape index *is* the
-    ordinal and the legacy engine maps ``request.id`` through
-    ``want_ids``.
+    stable sort by ``arrival_s`` — exactly the tape order the engines
+    compile, so a tape index *is* the ordinal.
     """
 
     def __init__(self, rate: float, requests: Sequence) -> None:
         self.rate = float(rate)
-        reqs = sorted(requests, key=lambda r: r.arrival_s)
-        self.n = len(reqs)
-        #: per-ordinal sampled flag (vector engine: ordinal == index)
+        self.n = len(requests)
+        #: per-ordinal sampled flag (ordinal == tape index)
         self.want_l: List[bool] = [
             span_sampled(o, self.rate) for o in range(self.n)
         ]
-        #: request id -> ordinal, sampled requests only (legacy engine)
-        self.want_ids: Dict[int, int] = {
-            r.id: o for o, r in enumerate(reqs) if self.want_l[o]
-        }
         self._traces: Dict[int, _Trace] = {}
 
     # -- internals ----------------------------------------------------

@@ -1,7 +1,7 @@
 """Compile a :class:`ServiceSpec` into the runnable simulator stack.
 
 ``build_service`` is the only place in the repo that assembles the
-trace × catalog × policy × autoscaler × LB × :class:`ServingSimulator`
+trace × catalog × policy × autoscaler × LB × serving-engine
 pipeline; every driver (launch/serve, examples, benchmarks) goes through
 it, so a new scenario is a spec file, not a new driver.
 
@@ -26,14 +26,8 @@ from repro.obs.recorder import ObsRecorder
 from repro.obs.registry import use_registry
 from repro.obs.slo import SLOBurnConfig
 from repro.serving.latency import make_latency_model
-from repro.serving.load_balancer import (
-    LeastLoadedBalancer,
-    LoadBalancer,
-    RoundRobinBalancer,
-)
 from repro.serving.token.config import TokenSchedulerConfig
 from repro.serving.engine import VectorizedServingEngine
-from repro.serving.sim import ServingSimulator
 from repro.service.spec import ResourceSpec, ServiceSpec, SpecError
 from repro.workloads import Request, make_workload
 
@@ -122,12 +116,6 @@ def _build_autoscaler(spec: ServiceSpec) -> Autoscaler:
     )
 
 
-def _build_lb(spec: ServiceSpec) -> LoadBalancer:
-    if spec.load_balancer == "round_robin":
-        return RoundRobinBalancer()
-    return LeastLoadedBalancer()
-
-
 def build_requests(spec: ServiceSpec) -> List[Request]:
     """Generate the spec's request tape (empty for ``workload: none``).
 
@@ -162,11 +150,10 @@ class ResolvedService:
     zones: List[str]
     policy: Policy
     autoscaler: Autoscaler
-    load_balancer: LoadBalancer
     requests: List[Request]
-    # ServingSimulator, VectorizedServingEngine or JaxServingEngine,
-    # per spec.sim.engine
-    simulator: "ServingSimulator | VectorizedServingEngine"
+    # VectorizedServingEngine or its subclass JaxServingEngine, per
+    # spec.sim.engine
+    simulator: VectorizedServingEngine
     # the run's shared event recorder + metrics registry, built from the
     # spec's observability: section (detail / window_s)
     obs: Optional[ObsRecorder] = None
@@ -194,7 +181,6 @@ def build_service(
 
     policy = _build_policy(spec, trace, catalog)
     autoscaler = _build_autoscaler(spec)
-    lb = _build_lb(spec)
     reqs = list(requests) if requests is not None else build_requests(spec)
 
     sim_spec = spec.sim
@@ -205,9 +191,7 @@ def build_service(
         if spec.workload.kind == "none" and requests is None
         else sim_spec.sub_step_s
     )
-    if sim_spec.engine == "legacy":
-        engine_cls = ServingSimulator
-    elif sim_spec.engine == "jax":
+    if sim_spec.engine == "jax":
         # lazy: only sim.engine: "jax" runs pay the jax import
         from repro.serving.jaxengine import JaxServingEngine
 
@@ -255,42 +239,34 @@ def build_service(
             iter_overhead_s=serving.iter_overhead_s,
             goodput_window_s=serving.goodput_window_s,
         )
-    try:
-        simulator = engine_cls(
-            trace,
-            policy,
-            reqs,
-            model_cfg,
+    simulator = engine_cls(
+        trace,
+        policy,
+        reqs,
+        model_cfg,
+        itype=spec.resources.instance_type,
+        catalog=catalog,
+        autoscaler=autoscaler,
+        lb=spec.load_balancer,
+        sim_config=SimConfig(
             itype=spec.resources.instance_type,
-            catalog=catalog,
-            autoscaler=autoscaler,
-            lb=lb,
-            sim_config=SimConfig(
-                itype=spec.resources.instance_type,
-                cold_start_s=sim_spec.cold_start_s,
-                control_interval_s=sim_spec.control_interval_s,
-                warning_enabled=sim_spec.warning_enabled,
-                seed=sim_spec.seed,
-                record_series=sim_spec.record_series,
-            ),
-            timeout_s=sim_spec.timeout_s,
-            sub_step_s=sub_step,
-            workload_name=spec.workload.kind,
-            concurrency=sim_spec.concurrency,
-            concurrency_cap=serving.concurrency_cap,
-            latency_model=latency_model,
-            replica_model=sim_spec.replica_model,
-            token_scheduler=token_knobs,
-            migration=migration,
-            obs=obs,
-        )
-    except TypeError as e:
-        # the array engines reject configurations they cannot simulate
-        # exactly (e.g. custom balancer subclasses); surface that as a
-        # spec problem with the engine that would accept it
-        raise SpecError(
-            f"sim.engine {sim_spec.engine!r} rejected this spec: {e}"
-        ) from e
+            cold_start_s=sim_spec.cold_start_s,
+            control_interval_s=sim_spec.control_interval_s,
+            warning_enabled=sim_spec.warning_enabled,
+            seed=sim_spec.seed,
+            record_series=sim_spec.record_series,
+        ),
+        timeout_s=sim_spec.timeout_s,
+        sub_step_s=sub_step,
+        workload_name=spec.workload.kind,
+        concurrency=sim_spec.concurrency,
+        concurrency_cap=serving.concurrency_cap,
+        latency_model=latency_model,
+        replica_model=sim_spec.replica_model,
+        token_scheduler=token_knobs,
+        migration=migration,
+        obs=obs,
+    )
     return ResolvedService(
         spec=spec,
         trace=trace,
@@ -299,7 +275,6 @@ def build_service(
         zones=zones,
         policy=policy,
         autoscaler=autoscaler,
-        load_balancer=lb,
         requests=reqs,
         simulator=simulator,
         obs=obs,

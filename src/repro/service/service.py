@@ -19,7 +19,7 @@ from typing import Any, Dict, Mapping, Optional, Sequence
 
 from repro.cluster.catalog import Catalog
 from repro.cluster.traces import SpotTrace
-from repro.serving.sim import ServingResult
+from repro.serving.result import ServingResult
 from repro.service.builder import ResolvedService, build_service
 from repro.service.loader import load_spec
 from repro.service.spec import ServiceSpec

@@ -4,8 +4,8 @@ A :class:`ServiceSpec` is the single front door to this repro: it names the
 model, the spot trace, the ``any_of`` resource filter, the replica policy
 (SpotHedge or a baseline) with its knobs, the autoscaler, the request
 workload and the simulation horizon.  ``repro.service.builder`` compiles a
-spec into the resolved Catalog/SpotTrace/Policy/Autoscaler/LoadBalancer/
-ServingSimulator stack; ``repro.service.Service`` runs it.
+spec into the resolved Catalog/SpotTrace/Policy/Autoscaler/serving-engine
+stack; ``repro.service.Service`` runs it.
 
 All specs are frozen dataclasses with ``to_dict`` round-trips, so a spec is
 equally a Python literal, a JSON object, or a YAML file:
@@ -629,7 +629,7 @@ class ObservabilitySpec:
 # ---------------------------------------------------------------------------
 
 
-ENGINE_NAMES = ("vector", "legacy", "jax")
+ENGINE_NAMES = ("vector", "jax")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -637,21 +637,21 @@ class SimSpec:
     """Simulation fabric: horizon, cold start, control cadence, SLO.
 
     ``engine`` picks the serving hot path: ``"vector"`` (default) is the
-    NumPy array engine in ``repro.serving.engine``; ``"legacy"`` is the
-    per-request object simulator in ``repro.serving.sim``; ``"jax"`` is
-    the two-phase jit/vmap engine in ``repro.serving.jaxengine`` that
+    NumPy array reference engine in ``repro.serving.engine``; ``"jax"``
+    is the two-phase jit/vmap engine in ``repro.serving.jaxengine`` that
     compiles the request-model data plane with ``lax.scan`` and batches
     whole scenario matrices with ``vmap`` (token-model cells fall back
-    to the vector data plane).  All three are decision-for-decision
-    equivalent (see ``tests/test_differential.py`` and
-    ``tests/test_jax_engine.py``); they differ only in throughput.
+    to the vector data plane).  The two are decision-for-decision
+    equivalent (``tests/test_jax_engine.py``; the vector engine's
+    answers are pinned by ``tests/test_differential.py``); they differ
+    only in throughput.
 
     ``replica_model`` picks how a replica prices work: ``"request"``
     (default) is the M/G/c model with frozen per-request service times;
     ``"token"`` is the iteration-level continuous-batching model in
     ``repro.serving.token`` (KV-budget admission, chunked prefill,
     batch-dependent decode steps, TTFT/TPOT/goodput metrics).  Both
-    engines support both models; token-mode knobs live in the
+    engines accept both models; token-mode knobs live in the
     ``serving:`` section.
     """
 
@@ -680,7 +680,7 @@ class SimSpec:
         )
         # single source of truth for valid models is the serving layer
         # (deferred import keeps spec module import cheap)
-        from repro.serving.sim import REPLICA_MODELS
+        from repro.serving.result import REPLICA_MODELS
 
         _require(
             self.replica_model in REPLICA_MODELS,
@@ -795,7 +795,7 @@ class SweepSpec:
                 "sweep.forecasters entries must be non-empty strings",
             )
         if self.replica_models:
-            from repro.serving.sim import REPLICA_MODELS
+            from repro.serving.result import REPLICA_MODELS
 
             for rm in self.replica_models:
                 _require(
@@ -844,9 +844,6 @@ class SweepSpec:
 # ---------------------------------------------------------------------------
 
 
-LB_NAMES = ("least_loaded", "round_robin")
-
-
 @dataclasses.dataclass(frozen=True)
 class ServiceSpec:
     """The complete declarative description of one service run."""
@@ -877,9 +874,12 @@ class ServiceSpec:
         _require(bool(self.name), "service.name must be set")
         _require(bool(self.model), "service.model must be set")
         _require(bool(self.trace), "service.trace must be set")
+        # the engine owns the dispatch rules, so it names the balancers
+        from repro.serving.engine import LB_KINDS
+
         _require(
-            self.load_balancer in LB_NAMES,
-            f"service.load_balancer must be one of {list(LB_NAMES)}, "
+            self.load_balancer in LB_KINDS,
+            f"service.load_balancer must be one of {list(LB_KINDS)}, "
             f"got {self.load_balancer!r}",
         )
         if self.migration is not None and self.migration.enabled:

@@ -1,48 +1,43 @@
-"""Serving data plane: latency model, replicas, LB, serving engines.
+"""Serving data plane: latency model, token batches, serving engines.
 
-Two equivalent simulation engines share the control plane (policy /
-autoscaler / controller / LB) and the roofline latency model:
+Two engines share the control plane (policy / autoscaler / controller)
+and the latency model:
 
-* ``engine.py`` — :class:`VectorizedServingEngine`, the default hot path:
-  NumPy array state, event-skipping sub-ticks, several times faster;
-* ``sim.py`` — :class:`ServingSimulator`, the legacy per-request object
-  simulator; kept as the readable reference implementation and the
-  differential-test oracle (``tests/test_differential.py``).
+* ``engine.py`` — :class:`VectorizedServingEngine`, the reference engine:
+  NumPy array state, event-skipping sub-ticks, both replica models;
+* ``jaxengine/`` — :class:`~repro.serving.jaxengine.JaxServingEngine`,
+  the fast engine: the request-model data plane as one ``lax.scan``,
+  whole scenario matrices as one ``vmap``-ed program.
+
+``result.py`` holds what both return (:class:`ServingResult`).
 
 Live token-serving (real JAX prefill/decode) lives in
 ``examples/serve_llm.py`` / ``benchmarks/engine_bench.py``.
 """
 
-from repro.serving.engine import VectorizedServingEngine
+from repro.serving.engine import LB_KINDS, VectorizedServingEngine
 from repro.serving.latency import (
     LatencyModel,
     ProfiledLatencyModel,
     make_latency_model,
 )
-from repro.serving.load_balancer import LeastLoadedBalancer, RoundRobinBalancer
-from repro.serving.replica import Replica, ReplicaState
-from repro.serving.sim import ServingSimulator, ServingResult
+from repro.serving.result import REPLICA_MODELS, ServingResult
 from repro.serving.token import (
     ContinuousBatch,
     TokenEngineConfig,
-    TokenReplica,
     TokenSchedulerConfig,
     TokenStats,
 )
 
 __all__ = [
+    "LB_KINDS",
     "LatencyModel",
     "ProfiledLatencyModel",
     "make_latency_model",
-    "LeastLoadedBalancer",
-    "RoundRobinBalancer",
-    "Replica",
-    "ReplicaState",
-    "ServingSimulator",
+    "REPLICA_MODELS",
     "ServingResult",
     "ContinuousBatch",
     "TokenEngineConfig",
-    "TokenReplica",
     "TokenSchedulerConfig",
     "TokenStats",
     "VectorizedServingEngine",

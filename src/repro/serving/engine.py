@@ -1,8 +1,27 @@
-"""Vectorized serving engine — the hot-path replacement for ``sim.py``.
+"""Vectorized serving engine — the reference serving engine.
 
-``VectorizedServingEngine`` runs the same §5.1 serving methodology as
-:class:`repro.serving.sim.ServingSimulator` but replaces the per-request /
-per-replica Python object loops with NumPy array state:
+``VectorizedServingEngine`` runs the §5.1 serving methodology: the
+cluster simulator (policy × spot trace × instances) composed with the
+request path (workload → LB → replica queues → latency model).  It
+produces the paper's headline metrics: P50/P90/P99 end-to-end latency,
+failure rate (timeouts from preemption + queueing), cost, and
+ready-replica series (Fig. 9/10/13/15).
+
+Mechanics:
+
+* requests arrive continuously; the LB routes to ready replicas only,
+  least-loaded over ``(load, rtt, id)`` or round-robin;
+* a preemption kills a replica; its in-flight and queued requests are
+  retried by the client — the wasted time counts into that request's
+  e2e latency;
+* a request that cannot complete within ``timeout_s`` of its arrival
+  (client RTT included) is a failure (the paper's definition);
+* replica service times come from the latency model; queueing is M/G/c
+  per replica on a ``sub_step_s`` grid, with a mild interference factor
+  for concurrent decodes.
+
+State lives in NumPy arrays and plain lists, not one object per request
+or replica:
 
 * the request tape is compiled once into ``float64`` arrays (arrival
   times, roofline service times, client-region codes) — no
@@ -18,17 +37,13 @@ per-replica Python object loops with NumPy array state:
 * completions are tracked in a global min-heap of finish times: a sub-tick
   only visits replicas that have a finish due or received new work, and
   sub-ticks where provably nothing can happen are skipped outright;
-* dead replicas cost nothing — the legacy simulator probes every replica
-  it ever created on every sub-tick, which degrades linearly with
-  preemption churn over long volatile traces.
+* dead replicas cost nothing: they leave the live list at the next
+  control tick.
 
-The engine is **decision-for-decision equivalent** to the legacy
-simulator: it visits the same sub-tick grid points (same float
-accumulation), delivers the same arrival batches to the autoscaler,
-assigns requests to replicas with the same ``(load, rtt, id)`` /
-round-robin rules, applies the same interference factor at dispatch, and
-fails the same requests at the same instants.  ``tests/test_differential.py``
-locks the equivalence down; ``tests/test_golden.py`` pins the metrics.
+The engine's answers are pinned: ``tests/test_differential.py`` holds it
+to the answers recorded from the per-request object engine it replaced
+(``tests/data/engine_record.json``), ``tests/test_golden.py`` pins the
+metrics, and the JAX engine (``repro.serving.jaxengine``) is held to it.
 
 ``replica_model="token"`` switches the request path to the
 continuous-batching model (``repro.serving.token``): each replica slot
@@ -36,9 +51,8 @@ carries a :class:`ContinuousBatch` whose per-sequence state lives in
 NumPy arrays, dispatch enqueues tape indices into batches instead of
 pushing precomputed finish times, and a per-sub-tick batched step loop
 advances every busy batch (closed-form decode blocks, so cost scales
-with joins/leaves, not decode iterations).  Token mode is
-decision-for-decision equivalent to the legacy simulator's
-``TokenReplica`` path (``tests/test_token_engine.py``).
+with joins/leaves, not decode iterations).  Token mode is pinned to the
+same record (``tests/test_token_engine.py``, ``tests/test_migration.py``).
 """
 
 from __future__ import annotations
@@ -62,12 +76,7 @@ from repro.models.config import ModelConfig
 from repro.obs.recorder import ObsRecorder
 from repro.obs.registry import use_registry
 from repro.serving.latency import LatencyModel
-from repro.serving.load_balancer import (
-    LeastLoadedBalancer,
-    LoadBalancer,
-    RoundRobinBalancer,
-)
-from repro.serving.sim import REPLICA_MODELS, ServingResult, WindowSampler
+from repro.serving.result import REPLICA_MODELS, ServingResult, WindowSampler
 from repro.serving.token.batch import ContinuousBatch
 from repro.serving.token.config import (
     TokenEngineConfig,
@@ -76,7 +85,11 @@ from repro.serving.token.config import (
 from repro.serving.token.metrics import TokenRecord, TokenStats
 from repro.workloads.arrivals import Request
 
-__all__ = ["VectorizedServingEngine"]
+__all__ = ["LB_KINDS", "VectorizedServingEngine"]
+
+#: load-balancer name (``service.load_balancer``) -> dispatch rule:
+#: least-loaded over ``(load, rtt, id)``, or a round-robin cursor
+LB_KINDS = {"least_loaded": "ll", "round_robin": "rr"}
 
 _INF = float("inf")
 # below this size a plain Python scan beats numpy call overhead
@@ -115,7 +128,7 @@ class _Rep:
 
 
 class VectorizedServingEngine:
-    """Drop-in for :class:`ServingSimulator` with an array-based hot path."""
+    """The reference serving engine: one run of one scenario."""
 
     def __init__(
         self,
@@ -127,7 +140,7 @@ class VectorizedServingEngine:
         itype: str = "p3.2xlarge",
         catalog: Optional[Catalog] = None,
         autoscaler: Optional[Autoscaler] = None,
-        lb: Optional[LoadBalancer] = None,
+        lb: str = "least_loaded",
         sim_config: Optional[SimConfig] = None,
         timeout_s: float = 100.0,
         sub_step_s: float = 1.0,
@@ -141,8 +154,7 @@ class VectorizedServingEngine:
         obs: Optional[ObsRecorder] = None,
     ) -> None:
         # shared event recorder (repro.obs): the cluster, migration
-        # runtime and window sampler all emit into this one sink, so the
-        # stream is byte-identical to the legacy simulator's
+        # runtime and window sampler all emit into this one ordered sink
         self.obs = obs if obs is not None else ObsRecorder()
         self.catalog = catalog or default_catalog()
         self.cfg = cfg
@@ -211,20 +223,11 @@ class VectorizedServingEngine:
         self._migration_transfer_s = 0.0
         self._recompute_saved_s = 0.0
 
-        lb = lb or LeastLoadedBalancer()
-        # exact types only: a subclass may override pick(), and silently
-        # simulating it as the vanilla balancer would be wrong
-        if type(lb) is RoundRobinBalancer:
-            self._lb_kind = "rr"
-        elif type(lb) is LeastLoadedBalancer:
-            self._lb_kind = "ll"
-        else:
-            raise TypeError(
-                f"VectorizedServingEngine supports LeastLoadedBalancer and "
-                f"RoundRobinBalancer, got {type(lb).__name__}; use the "
-                "legacy ServingSimulator (sim.engine: legacy) for custom "
-                "balancers"
+        if lb not in LB_KINDS:
+            raise ValueError(
+                f"lb must be one of {list(LB_KINDS)}, got {lb!r}"
             )
+        self._lb_kind = LB_KINDS[lb]
         self._rr_cursor = 0
 
         # ---- compile the request tape into arrays ---------------------
@@ -247,7 +250,7 @@ class VectorizedServingEngine:
         )
         lm = self.latency_model
         # same operation order as LatencyModel.service_s so every value is
-        # bit-identical to the legacy per-request computation
+        # bit-identical to a per-request service_s() call
         prefill = (2.0 * lm._active_params) * p_tok / lm.flops_per_s
         self._svc = (lm.overhead_s + prefill) + o_tok * lm.decode_s_per_token()
         # Python-list mirrors for scalar access: list indexing and float
@@ -407,8 +410,7 @@ class VectorizedServingEngine:
 
     def _kill_with_migration(self, rep: _Rep, now: float):
         """Warned preemption with migration on: drain/migrate/kill the
-        dying batch (decision-identical to the legacy simulator's path).
-        Returns the residual KillReport."""
+        dying batch.  Returns the residual KillReport."""
         inst = rep.inst
         grace = now - inst.warned_at
         cands = sorted(
@@ -481,8 +483,8 @@ class VectorizedServingEngine:
     def _sync(self, now: Optional[float] = None) -> None:
         """Reconcile the replica set with the cluster's active instances.
 
-        Instance state only changes at control ticks, so (unlike the legacy
-        per-sub-tick probe loop) one reconciliation per window is exact.
+        Instance state only changes at control ticks, so one
+        reconciliation per window is exact (no per-sub-tick probes).
         The window-constant LB state (ready order, loads, rtt columns) is
         rebuilt here.
         """
@@ -510,8 +512,8 @@ class VectorizedServingEngine:
     def _active(self, t: float) -> bool:
         """Could anything at all happen at grid point ``t``?
 
-        Conservative: a false positive costs one no-op pass (exactly what
-        the legacy simulator does on every sub-tick), never correctness.
+        Conservative: a false positive costs one no-op pass, never
+        correctness.
         """
         if self._ptr < self._n and self._arr_l[self._ptr] <= t:
             return True
@@ -532,8 +534,8 @@ class VectorizedServingEngine:
         t = now
         end = now + dt
         token = self._token_cfg is not None
-        # identical float accumulation to the legacy loop so grid points,
-        # arrival batches and timeout instants match bit-for-bit
+        # plain float accumulation from the tick start: the jax engine's
+        # grid (jaxengine.schedule.build_grid) repeats it bit-for-bit
         while t < end:
             if token:
                 if self._active_token(t):
@@ -583,8 +585,8 @@ class VectorizedServingEngine:
                 due.add(s)
         # 3) dispatch (fills self._touched with slots that got new queued
         #    work; replicas with free capacity, an empty queue and no due
-        #    completion start the request immediately — identical to the
-        #    legacy queue-then-start within the same sub-tick, because the
+        #    completion start the request immediately — the same outcome
+        #    as queue-then-start within the sub-tick, because the
         #    dispatch timeout filter already applied the expiry predicate)
         touched = self._touched
         touched.clear()
@@ -708,7 +710,8 @@ class VectorizedServingEngine:
             self._rr_cursor = cur
         else:
             # least-loaded waterfill: sequentially assign each request to
-            # argmin (load, rtt, id) — the pick the legacy LB's min() makes
+            # argmin (load, rtt, id) — ties go to the lower-RTT region,
+            # then the lower replica id
             ready_reps = self._ready_reps
             loads = self._loads
             ids = self._ids
@@ -780,7 +783,7 @@ class VectorizedServingEngine:
         for s in slots:
             rep = reps[s]
             run = rep.running
-            # completions (in start order, like the legacy running list)
+            # completions, in start order
             if s in due:
                 still: List[Tuple[float, int]] = []
                 n_done = 0
@@ -948,8 +951,8 @@ class VectorizedServingEngine:
                 else:
                     self.failed += 1     # can never fit the KV budget
                 if want is not None and want[i]:
-                    # same tap order as TokenReplica.submit: dispatch,
-                    # then track (admitted) or reject (unservable)
+                    # tap order: dispatch, then track (admitted) or
+                    # reject (unservable)
                     spans.dispatch(
                         i, t, rep.ord, rep.rtt[rcode[i]], arr[i],
                         token=True,
@@ -961,7 +964,7 @@ class VectorizedServingEngine:
             self._rr_cursor = cur
         else:
             # least-loaded waterfill over (load, rtt, id), load = batch
-            # occupancy + admission queue — same pick as the legacy LB
+            # occupancy + admission queue
             ready_reps = self._ready_reps
             loads = self._loads
             ids = self._ids

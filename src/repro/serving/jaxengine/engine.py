@@ -44,7 +44,7 @@ from repro.serving.jaxengine.schedule import (
     ScheduleRecorder,
     build_grid,
 )
-from repro.serving.sim import ServingResult
+from repro.serving.result import ServingResult
 
 __all__ = [
     "JaxServingEngine",
@@ -67,14 +67,14 @@ class JaxServingEngine(VectorizedServingEngine):
 
     def __init__(self, trace, policy, requests, cfg, **kw) -> None:
         # pristine control-plane state for the overflow fallback (phase A
-        # consumes the policy/autoscaler/balancer rng and counters)
+        # consumes the policy/autoscaler rng and counters)
         self._pristine = {
             "trace": trace,
             "policy": copy.deepcopy(policy),
             "requests": requests,
             "cfg": cfg,
             "kw": {
-                k: (copy.deepcopy(v) if k in ("autoscaler", "lb") else v)
+                k: (copy.deepcopy(v) if k == "autoscaler" else v)
                 for k, v in kw.items()
             },
         }
@@ -164,7 +164,7 @@ class JaxServingEngine(VectorizedServingEngine):
         """Oracle rerun from pristine control-plane state (overflow)."""
         p = self._pristine
         kw = {
-            k: (copy.deepcopy(v) if k in ("autoscaler", "lb") else v)
+            k: (copy.deepcopy(v) if k == "autoscaler" else v)
             for k, v in p["kw"].items()
         }
         # fresh recorder: the rerun replays the whole control plane, and

@@ -57,9 +57,9 @@ class SubStepGrid:
 
 
 def build_grid(duration_s: float, dt: float, sub_step_s: float) -> SubStepGrid:
-    """Replicate the engines' per-window float accumulation exactly.
+    """Replicate the vector engine's per-window float accumulation exactly.
 
-    Both the legacy simulator and the vectorized engine walk
+    The vectorized engine walks
     ``t = now; while t < now + dt: ...; t += sub_step_s`` inside each
     control tick, so the grid must be built with the *same* accumulation
     (not ``arange``) for timeout instants to match bit-for-bit.
@@ -92,7 +92,7 @@ class CellSchedule:
     """One cell's complete phase-B input: tape + control-plane replay.
 
     Self-contained and picklable: the data plane needs nothing else, and
-    the final :class:`~repro.serving.sim.ServingResult` is assembled
+    the final :class:`~repro.serving.result.ServingResult` is assembled
     from this plus the kernel outputs (see ``engine.assemble_result``).
     """
 

@@ -1,6 +1,6 @@
 """Token-level continuous-batching replica model (``repro.serving.token``).
 
-The request-level simulators price a request with one frozen
+The request-level replica model prices a request with one frozen
 ``service_s`` number and an ad-hoc interference factor.  This package
 models what an LLM replica actually does: iteration-level (Orca-style)
 batching where requests join/leave per decode step, a KV-cache token
@@ -11,12 +11,12 @@ sequence), chunked prefill, and preemptions that destroy in-flight KV
 state so retried requests re-prefill elsewhere.
 
 Select it per run with ``sim.replica_model: token`` in a ``ServiceSpec``;
-tune it with the ``serving:`` section.  Both serving engines consume the
-same :class:`ContinuousBatch` core: the legacy ``ServingSimulator``
-through :class:`TokenReplica`, the ``VectorizedServingEngine`` through a
-per-slot batched step loop.  Runs in token mode attach a
-:class:`TokenStats` (TTFT/TPOT percentiles, windowed goodput-vs-SLO,
-preemption KV-loss accounting) to their ``ServingResult``.
+tune it with the ``serving:`` section.  ``VectorizedServingEngine``
+drives one :class:`ContinuousBatch` per replica slot through a batched
+step loop (the JAX engine hands token cells to it).  Runs in token mode
+attach a :class:`TokenStats` (TTFT/TPOT percentiles, windowed
+goodput-vs-SLO, preemption KV-loss accounting) to their
+``ServingResult``.
 """
 
 from repro.serving.token.batch import (
@@ -30,7 +30,6 @@ from repro.serving.token.config import (
     UNBOUNDED_KV_TOKENS,
 )
 from repro.serving.token.metrics import TokenRecord, TokenStats
-from repro.serving.token.replica import TokenReplica
 
 __all__ = [
     "ContinuousBatch",
@@ -39,7 +38,6 @@ __all__ = [
     "TokenEngineConfig",
     "TokenSchedulerConfig",
     "TokenRecord",
-    "TokenReplica",
     "TokenStats",
     "UNBOUNDED_KV_TOKENS",
 ]

@@ -22,8 +22,8 @@ One :class:`ContinuousBatch` models the inference engine on one replica:
 The hot path is exact but not naive: pure-decode stretches advance in
 closed form (the iteration time is affine in the iteration index, so the
 time of ``n`` iterations is a quadratic — solved, not summed), and the
-per-sequence state lives in parallel NumPy arrays so both serving engines
-share one vectorized implementation.
+per-sequence state lives in parallel NumPy arrays, one vectorized
+implementation per replica slot.
 
 Clock discipline: ``advance(t)`` runs whole iterations whose *end* is
 ``<= t`` — the internal clock never passes ``t``, and a request enqueued
@@ -157,22 +157,6 @@ class ContinuousBatch:
              float(self._first[j]))
             for j in range(len(self._keys))
         ]
-
-    def backlog_hint_s(self) -> float:
-        """Rough seconds of work ahead of a new arrival (LB estimates)."""
-        cfg = self.cfg
-        rem_dec = int((self._out - self._dec).sum())
-        rem_pref = int((self._prompt - self._pref).sum())
-        q_pref = sum(p for _, p, _, _, _, _ in self.queue)
-        q_dec = sum(o for _, _, o, _, _, _ in self.queue)
-        b = max(self.n_active, 1)
-        # decode tokens of concurrent sequences overlap (one iteration
-        # serves the whole batch); queued work runs after them
-        return (
-            rem_dec * cfg.weight_read_s / b
-            + (rem_pref + q_pref) * cfg.prefill_s_per_token
-            + q_dec * cfg.weight_read_s
-        )
 
     def track(self, key: int, ordinal: int) -> None:
         """Register a span-sampled request: batch events for ``key`` tap

@@ -63,17 +63,17 @@ def _parity_tree(root, engine_body):
             timeout_s: float = 100.0
             concurrency: int = 4
     """)
-    _write(root, "src/repro/serving/sim.py", """\
-        class ServingSimulator:
-            def run(self, spec):
-                return spec.timeout_s + spec.concurrency
+    _write(root, "src/repro/serving/jaxengine/kernel.py", """\
+        def run_group(spec):
+            return spec.timeout_s + spec.concurrency
     """)
     _write(root, "src/repro/serving/engine.py", engine_body)
 
 
 def test_engine_parity_flags_one_sided_field(tmp_path):
     root = str(tmp_path)
-    # vector engine never consumes timeout_s -> parity violation
+    # the vector engine never consumes timeout_s, the jax kernel does ->
+    # parity violation (jax inherits what vector consumes, not the reverse)
     _parity_tree(root, """\
         class VectorizedServingEngine:
             def run(self, spec):
@@ -84,7 +84,7 @@ def test_engine_parity_flags_one_sided_field(tmp_path):
     assert [f.symbol for f in found] == ["SimSpec.timeout_s"]
     assert found[0].path == "src/repro/service/spec.py"
     assert found[0].line > 0
-    assert "legacy" in found[0].message
+    assert "consumed by the jax engine but not by vector" in found[0].message
 
 
 def test_engine_parity_clean_when_both_consume(tmp_path):

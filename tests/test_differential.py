@@ -1,25 +1,28 @@
-"""Differential tests: the vectorized engine is decision-for-decision
-equivalent to the legacy per-request ServingSimulator.
+"""Differential tests: the vectorized engine gives the answers recorded
+from the per-request object engine it replaced.
 
-Each scenario runs the same trace / policy / request tape / autoscaler /
-LB through both engines and asserts identical completion, failure and
-preemption counts, identical cost, and (sorted) latency arrays equal to
-1e-6 — the lockdown the ISSUE's vectorization rests on.  Scenarios are
-chosen to cross the behavioral regimes: multi-zone spot churn, round-robin
-vs least-loaded balancing, load autoscaling with terminations, saturation
-with queue expiry, and an on-demand-only fleet.
+Each scenario runs a fixed trace / policy / request tape / autoscaler /
+LB through ``VectorizedServingEngine`` and holds it to the record in
+``tests/data/engine_record.json`` (see ``engine_record.py``): identical
+completion, failure, preemption and retry counts, cost to 1e-9 relative,
+and the sorted latency sample (size, sum, extremes, percentiles 1..99)
+to 1e-6.  Scenarios are chosen to cross the behavioral regimes:
+multi-zone spot churn, round-robin vs least-loaded balancing, load
+autoscaling with terminations, saturation with queue expiry, and an
+on-demand-only fleet.  The same scenarios run through
+``JaxServingEngine`` too, which must answer on its device path.
 """
 
-import numpy as np
 import pytest
 
+from engine_record import assert_matches
 from repro.cluster.traces import synth_correlated_trace
 from repro.configs import get_config
 from repro.core.autoscaler import ConstantTarget, LoadAutoscaler
 from repro.core.policy import make_policy
 from repro.serving.engine import VectorizedServingEngine
-from repro.serving.load_balancer import RoundRobinBalancer
-from repro.serving.sim import ServingSimulator
+from repro.serving.jaxengine import JaxServingEngine
+from repro.serving.jaxengine.engine import FALLBACK_COUNTER
 from repro.workloads import make_workload
 
 CFG = get_config("llama3.2-1b")
@@ -32,97 +35,99 @@ def _mini_trace(steps, seed):
                                   seed=seed, max_capacity=4, name="mini")
 
 
-def _run_both(policy, workload, *, hours=2.0, seed=3, rate=0.8,
-              autoscaler=None, lb_cls=None, timeout_s=60.0,
-              concurrency=2):
+def _load_autoscaler():
+    return LoadAutoscaler(
+        0.8, min_replicas=1, max_replicas=6, initial_target=2,
+        upscale_delay_s=60.0, downscale_delay_s=300.0,
+    )
+
+
+#: request-mode scenarios: record name -> (policy, workload, overrides)
+SCENARIOS = {
+    # spot churn + retries through the least-loaded balancer
+    "spothedge_poisson_least_loaded": ("spothedge", "poisson", {}),
+    # bursty arrivals through the round-robin balancer
+    "even_spread_arena_round_robin": (
+        "even_spread", "arena", {"lb": "round_robin"}
+    ),
+    # diurnal load + autoscaler-driven launches AND terminations
+    "aws_spot_maf_load_autoscaler": (
+        "aws_spot", "maf", {"autoscaler": _load_autoscaler}
+    ),
+    # no preemptions; the steady immediate-start fast path
+    "ondemand_only_stable_fleet": ("ondemand_only", "poisson", {}),
+    # overload: deep queues, client-timeout expiry, request failures
+    "saturated_queues_and_expiry": (
+        "spothedge", "poisson",
+        {"rate": 6.0, "concurrency": 1, "timeout_s": 30.0, "hours": 1.0},
+    ),
+}
+
+
+def _run(name, engine_cls=VectorizedServingEngine):
+    policy, workload, over = SCENARIOS[name]
+    hours = over.get("hours", 2.0)
+    seed, rate = 3, over.get("rate", 0.8)
     trace = _mini_trace(steps=int(hours * 60) + 60, seed=seed)
     rate_key = "rate_per_s" if workload == "poisson" else "base_rate_per_s"
     reqs = make_workload(workload, **{rate_key: rate}, seed=seed).generate(
         hours * 3600.0
     )
-    results = []
-    for cls in (ServingSimulator, VectorizedServingEngine):
-        kwargs = dict(
-            itype="g5.48xlarge",
-            autoscaler=autoscaler() if autoscaler else ConstantTarget(3),
-            timeout_s=timeout_s,
-            concurrency=concurrency,
-            workload_name=workload,
-        )
-        if lb_cls is not None:
-            kwargs["lb"] = lb_cls()
-        sim = cls(trace, make_policy(policy), reqs, CFG, **kwargs)
-        results.append(sim.run(hours * 3600.0 + 600.0))
-    return results
-
-
-def _assert_equivalent(legacy, vector):
-    assert vector.n_requests == legacy.n_requests
-    assert vector.n_completed == legacy.n_completed
-    assert vector.n_failed == legacy.n_failed
-    assert vector.n_preemptions == legacy.n_preemptions
-    assert vector.n_launch_failures == legacy.n_launch_failures
-    assert vector.total_cost == pytest.approx(legacy.total_cost, abs=1e-9)
-    assert vector.availability == pytest.approx(
-        legacy.availability, abs=1e-12
+    autoscaler = over.get("autoscaler")
+    sim = engine_cls(
+        trace, make_policy(policy), reqs, CFG,
+        itype="g5.48xlarge",
+        autoscaler=autoscaler() if autoscaler else ConstantTarget(3),
+        timeout_s=over.get("timeout_s", 60.0),
+        concurrency=over.get("concurrency", 2),
+        workload_name=workload,
+        lb=over.get("lb", "least_loaded"),
     )
-    lat_l = np.sort(legacy.latencies_s)
-    lat_v = np.sort(vector.latencies_s)
-    assert len(lat_l) == len(lat_v)
-    if len(lat_l):
-        np.testing.assert_allclose(lat_v, lat_l, atol=1e-6, rtol=0)
+    return sim.run(hours * 3600.0 + 600.0)
 
 
 def test_spothedge_poisson_least_loaded():
-    """Spot churn + retries through the least-loaded balancer."""
-    legacy, vector = _run_both("spothedge", "poisson")
-    assert legacy.n_completed > 0
-    _assert_equivalent(legacy, vector)
+    res = _run("spothedge_poisson_least_loaded")
+    assert res.n_completed > 0
+    assert_matches(res, "spothedge_poisson_least_loaded")
 
 
 def test_even_spread_arena_round_robin():
-    """Bursty arrivals through the round-robin balancer."""
-    legacy, vector = _run_both(
-        "even_spread", "arena", lb_cls=RoundRobinBalancer
-    )
-    assert legacy.n_completed > 0
-    _assert_equivalent(legacy, vector)
+    res = _run("even_spread_arena_round_robin")
+    assert res.n_completed > 0
+    assert_matches(res, "even_spread_arena_round_robin")
 
 
 def test_aws_spot_maf_load_autoscaler():
-    """Diurnal load + autoscaler-driven launches AND terminations."""
-    legacy, vector = _run_both(
-        "aws_spot", "maf",
-        autoscaler=lambda: LoadAutoscaler(
-            0.8, min_replicas=1, max_replicas=6, initial_target=2,
-            upscale_delay_s=60.0, downscale_delay_s=300.0,
-        ),
-    )
-    assert legacy.n_completed > 0
-    _assert_equivalent(legacy, vector)
+    res = _run("aws_spot_maf_load_autoscaler")
+    assert res.n_completed > 0
+    assert_matches(res, "aws_spot_maf_load_autoscaler")
 
 
 def test_ondemand_only_stable_fleet():
-    """No preemptions; exercises the steady immediate-start fast path."""
-    legacy, vector = _run_both("ondemand_only", "poisson")
-    assert legacy.n_preemptions == 0
-    _assert_equivalent(legacy, vector)
+    res = _run("ondemand_only_stable_fleet")
+    assert res.n_preemptions == 0
+    assert_matches(res, "ondemand_only_stable_fleet")
 
 
 def test_saturated_queues_and_expiry():
-    """Overload: deep queues, client-timeout expiry, request failures."""
-    legacy, vector = _run_both(
-        "spothedge", "poisson", rate=6.0, concurrency=1,
-        timeout_s=30.0, hours=1.0,
-    )
-    assert legacy.n_failed > 0          # saturation must actually occur
-    _assert_equivalent(legacy, vector)
+    res = _run("saturated_queues_and_expiry")
+    assert res.n_failed > 0          # saturation must actually occur
+    assert_matches(res, "saturated_queues_and_expiry")
+
+
+@pytest.mark.parametrize("name", list(SCENARIOS))
+def test_jax_engine_gives_the_recorded_answers(name):
+    """The fast engine, on the device path (no NumPy fallback), gives
+    the same recorded answers."""
+    res = _run(name, JaxServingEngine)
+    counters = (res.metrics or {}).get("counters", {})
+    assert not any(k.startswith(FALLBACK_COUNTER) for k in counters)
+    assert_matches(res, name)
 
 
 def test_engine_via_service_spec_matches_legacy():
-    """The spec-level engine switch drives the same equivalence."""
-    import dataclasses
-
+    """A spec run through Service gives the recorded answers."""
     from repro.service import Service, spec_from_dict
 
     spec = spec_from_dict({
@@ -133,9 +138,5 @@ def test_engine_via_service_spec_matches_legacy():
         "sim": {"duration_hours": 1.0, "timeout_s": 60.0,
                 "concurrency": 2, "drain_s": 300.0},
     })
-    res_v = Service(spec).run()
-    spec_l = dataclasses.replace(
-        spec, sim=dataclasses.replace(spec.sim, engine="legacy")
-    )
-    res_l = Service(spec_l).run()
-    _assert_equivalent(res_l, res_v)
+    assert spec.sim.engine == "vector"
+    assert_matches(Service(spec).run(), "engine_via_service_spec")

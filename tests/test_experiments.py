@@ -174,8 +174,8 @@ def test_suite_shares_request_tapes_across_cells():
 def test_suite_engine_override_matches_default():
     suite = _small_suite()
     vec = suite.run()
-    leg = suite.run(engine="legacy")
-    for a, b in zip(vec.cells, leg.cells):
+    jx = suite.run(engine="jax")
+    for a, b in zip(vec.cells, jx.cells):
         assert a.n_completed == b.n_completed
         assert a.n_failed == b.n_failed
         assert a.p50_s == pytest.approx(b.p50_s, abs=1e-9)

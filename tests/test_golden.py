@@ -8,9 +8,10 @@ diff here means a semantic change to the pipeline, not noise.  If a
 change is *intended*, rerun the scenario and update the constants in the
 same commit (the diff then documents the metric shift).
 
-The spec runs the default engine ("vector"); the differential suite
-(tests/test_differential.py) guarantees the legacy simulator produces
-the same numbers.
+The spec runs the default engine ("vector"); tests/test_jax_engine.py
+holds the JAX engine to the same numbers, and tests/test_differential.py
+holds the vector engine to the answers recorded from the per-request
+object engine it replaced.
 
 Timeout-semantics note: queue expiry became RTT-inclusive
 (``t - arrival + rtt > timeout``, matching the deadline long applied to

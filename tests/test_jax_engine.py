@@ -33,7 +33,6 @@ from repro.experiments.suite import (
 from repro.serving.engine import VectorizedServingEngine
 from repro.serving.jaxengine import JaxServingEngine, run_cells
 from repro.serving.jaxengine.engine import FALLBACK_COUNTER
-from repro.serving.load_balancer import RoundRobinBalancer
 from repro.service import Service, SpecError, spec_from_dict
 from repro.workloads import make_workload
 
@@ -48,7 +47,7 @@ def _mini_trace(steps, seed):
 
 
 def _run_both(policy, workload, *, hours=1.0, seed=3, rate=0.8,
-              autoscaler=None, lb_cls=None, timeout_s=60.0,
+              autoscaler=None, lb=None, timeout_s=60.0,
               concurrency=2, client_regions=None, replica_model="request"):
     """Run (vector, jax) on one scenario; identical inputs for both."""
     trace = _mini_trace(steps=int(hours * 60) + 60, seed=seed)
@@ -67,8 +66,8 @@ def _run_both(policy, workload, *, hours=1.0, seed=3, rate=0.8,
             workload_name=workload,
             replica_model=replica_model,
         )
-        if lb_cls is not None:
-            kwargs["lb"] = lb_cls()
+        if lb is not None:
+            kwargs["lb"] = lb
         sim = cls(trace, make_policy(policy), reqs, CFG, **kwargs)
         out.append(sim.run(hours * 3600.0 + 600.0))
     return out
@@ -105,7 +104,7 @@ def test_spothedge_poisson_least_loaded():
 def test_even_spread_arena_round_robin():
     """Bursty arrivals through the round-robin cursor."""
     vector, jx = _run_both(
-        "even_spread", "arena", hours=2.0, lb_cls=RoundRobinBalancer
+        "even_spread", "arena", hours=2.0, lb="round_robin"
     )
     assert vector.n_completed > 0
     _assert_equivalent(vector, jx)

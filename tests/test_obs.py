@@ -1,12 +1,12 @@
 """Lockdown for repro.obs: events, registry, exporters, engine parity.
 
 The heavy fixtures run one fixed scenario (the differential-test mini
-trace) through all three engines at observability detail ``full`` and
-pin:
+trace) through both engines at observability detail ``full`` and pin:
 
-* **byte identity** — legacy ``ServingSimulator`` and
-  ``VectorizedServingEngine`` serialize to byte-identical JSONL
-  (request mode *and* token+migration mode);
+* **byte identity** — ``VectorizedServingEngine`` serializes to the
+  JSONL bytes recorded from the per-request object engine it replaced
+  (SHA-256 in tests/data/engine_record.json; request mode *and*
+  token+migration mode);
 * **JAX parity** — ``JaxServingEngine``'s phase-A replay reproduces the
   control-plane stream exactly (the vector stream minus data-plane
   records);
@@ -47,10 +47,10 @@ from repro.obs import (
     write_chrome_trace,
     write_jsonl,
 )
+from engine_record import scenario, sha256
 from repro.obs.__main__ import main as obs_main
 from repro.serving.engine import VectorizedServingEngine
 from repro.serving.jaxengine import JaxServingEngine
-from repro.serving.sim import ServingSimulator
 from repro.service import Service, SpecError, spec_from_dict
 from repro.workloads import make_workload
 
@@ -94,10 +94,9 @@ def _run(cls, *, detail="full", replica_model="request", migration=None,
 
 
 @pytest.fixture(scope="module")
-def three_runs():
-    """(legacy, vector, jax) results for the fixed request-mode scenario."""
+def engine_runs():
+    """(vector, jax) results for the fixed request-mode scenario."""
     return (
-        _run(ServingSimulator),
         _run(VectorizedServingEngine),
         _run(JaxServingEngine),
     )
@@ -212,17 +211,18 @@ def test_recorder_replica_ordinals_are_dense_and_stable():
 # engine parity (the tentpole contract)
 
 
-def test_legacy_vector_jsonl_byte_identical(three_runs):
-    legacy, vector, _ = three_runs
-    a = dumps_jsonl(legacy.obs.events)
+def test_legacy_vector_jsonl_byte_identical(engine_runs):
+    vector, _ = engine_runs
+    want = scenario("obs_request_events")
     b = dumps_jsonl(vector.obs.events)
-    assert a == b
-    assert len(a.splitlines()) == sum(GOLDEN_COUNTS.values())
+    assert len(b.splitlines()) == want["n_lines"]
+    assert sha256(b) == want["sha256"]
+    assert want["n_lines"] == sum(GOLDEN_COUNTS.values())
+    assert want["event_counts"] == GOLDEN_COUNTS
 
 
-def test_golden_event_counts(three_runs):
-    legacy, vector, jx = three_runs
-    assert legacy.obs.event_counts() == GOLDEN_COUNTS
+def test_golden_event_counts(engine_runs):
+    vector, jx = engine_runs
     assert vector.obs.event_counts() == GOLDEN_COUNTS
     # jax phase-A replays the control plane; no data-plane windows and
     # hence no per-window burn-rate events either
@@ -232,14 +232,14 @@ def test_golden_event_counts(three_runs):
     }
 
 
-def test_jax_matches_vector_control_plane(three_runs):
-    _, vector, jx = three_runs
+def test_jax_matches_vector_control_plane(engine_runs):
+    vector, jx = engine_runs
     want = control_plane_records(vector.obs.records())
     assert dumps_jsonl(jx.obs.records()) == dumps_jsonl(want)
 
 
-def test_decisions_carry_reasons_and_replica_links(three_runs):
-    _, vector, _ = three_runs
+def test_decisions_carry_reasons_and_replica_links(engine_runs):
+    vector, _ = engine_runs
     decisions = [r for r in vector.obs.records() if r["event"] == "decision"]
     launches = [d for d in decisions if d["action"].startswith("launch")]
     assert launches
@@ -271,11 +271,12 @@ def test_detail_off_and_full_are_metric_identical():
 
 def test_token_migration_byte_identical():
     spec = MigrationSpec(enabled=True, drain_threshold_s=2.0)
-    legacy = _run(ServingSimulator, replica_model="token",
-                  migration=spec, hours=1.0)
     vector = _run(VectorizedServingEngine, replica_model="token",
                   migration=spec, hours=1.0)
-    assert dumps_jsonl(legacy.obs.events) == dumps_jsonl(vector.obs.events)
+    want = scenario("obs_token_migration_events")
+    b = dumps_jsonl(vector.obs.events)
+    assert len(b.splitlines()) == want["n_lines"]
+    assert sha256(b) == want["sha256"]
     counts = vector.obs.event_counts()
     assert counts.get("migration_plan", 0) > 0
 
@@ -284,8 +285,8 @@ def test_token_migration_byte_identical():
 # exporters
 
 
-def test_jsonl_roundtrip(tmp_path, three_runs):
-    _, vector, _ = three_runs
+def test_jsonl_roundtrip(tmp_path, engine_runs):
+    vector, _ = engine_runs
     path = write_jsonl(vector.obs.events, str(tmp_path / "run.jsonl"))
     records = read_jsonl(path)
     # compare serialized: JSON turns reason tuples into lists
@@ -293,8 +294,8 @@ def test_jsonl_roundtrip(tmp_path, three_runs):
     assert all(r["schema"] == 1 for r in records)
 
 
-def test_chrome_trace_roundtrip(tmp_path, three_runs):
-    _, vector, _ = three_runs
+def test_chrome_trace_roundtrip(tmp_path, engine_runs):
+    vector, _ = engine_runs
     path = write_chrome_trace(
         vector.obs.events, str(tmp_path / "run.trace.json")
     )
@@ -313,8 +314,8 @@ def test_chrome_trace_roundtrip(tmp_path, three_runs):
     ) == json.dumps(trace, sort_keys=True)
 
 
-def test_summarize_and_diff(three_runs):
-    _, vector, jx = three_runs
+def test_summarize_and_diff(engine_runs):
+    vector, jx = engine_runs
     s = summarize(vector.obs.records())
     assert s["n_events"] == sum(GOLDEN_COUNTS.values())
     assert s["event_counts"] == GOLDEN_COUNTS
@@ -326,8 +327,8 @@ def test_summarize_and_diff(three_runs):
     assert diff["event_counts"]["window"]["delta"] == -GOLDEN_COUNTS["window"]
 
 
-def test_attribution_report(three_runs):
-    _, vector, _ = three_runs
+def test_attribution_report(engine_runs):
+    vector, _ = engine_runs
     rep = attribution_report(vector.obs.records(), top=5)
     assert rep["n_decisions"] == GOLDEN_COUNTS["decision"]
     assert rep["n_replicas"] > 0
@@ -339,8 +340,8 @@ def test_attribution_report(three_runs):
     assert tops == sorted(tops, reverse=True)
 
 
-def test_cli_smoke(tmp_path, three_runs, capsys):
-    _, vector, jx = three_runs
+def test_cli_smoke(tmp_path, engine_runs, capsys):
+    vector, jx = engine_runs
     a = write_jsonl(vector.obs.events, str(tmp_path / "a.jsonl"))
     b = write_jsonl(jx.obs.records(), str(tmp_path / "b.jsonl"))
     assert obs_main(["summarize", a]) == 0
@@ -416,8 +417,8 @@ def test_service_default_detail_writes_nothing(tmp_path):
     assert list(tmp_path.iterdir()) == []
 
 
-def test_cell_result_carries_obs_snapshots(three_runs):
-    _, vector, _ = three_runs
+def test_cell_result_carries_obs_snapshots(engine_runs):
+    vector, _ = engine_runs
     cell = CellResult.from_result({"policy": "spothedge"}, vector, 0.1)
     assert cell.obs_event_counts == GOLDEN_COUNTS
     assert cell.obs_windows is not None

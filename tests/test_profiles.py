@@ -320,7 +320,7 @@ def test_builder_injects_profiled_model(tmp_path):
         "workload": {"kind": "poisson", "rate_per_s": 0.5},
         "sim": {"duration_hours": 1.0},
     }
-    for engine in ("vector", "legacy"):
+    for engine in ("vector", "jax"):
         spec = spec_from_dict({
             **base,
             "latency": {"source": "profile", "profile": path},

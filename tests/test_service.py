@@ -21,8 +21,7 @@ from repro.core.policy import (
     Terminate,
     make_policy,
 )
-from repro.serving.load_balancer import LeastLoadedBalancer
-from repro.serving.sim import ServingSimulator
+from repro.serving.engine import VectorizedServingEngine
 from repro.service import (
     PlacementFilter,
     ReplicaPolicySpec,
@@ -127,6 +126,8 @@ def test_spec_from_yaml_text():
         ({"resources": {"instance_type": "q9.mega"}}, "instance_type"),
         ({"sim": {"duration_hours": -1}}, "duration_hours"),
         ({"sim": {"drain_s": -600.0}}, "drain_s"),
+        ({"sim": {"engine": "legacy"}},
+         r"sim.engine must be one of \['vector', 'jax'\]"),
         ({"load_balancer": "random"}, "load_balancer"),
         ({"typo_key": 1}, "unknown keys"),
         ({"replica_policy": {"overprovision": -1}}, "overprovision"),
@@ -212,11 +213,11 @@ def test_service_run_matches_hand_assembled_defaults():
     reqs = make_workload("poisson", rate_per_s=0.4, seed=2).generate(
         duration - 600.0
     )
-    sim = ServingSimulator(
+    sim = VectorizedServingEngine(
         trace, make_policy("spothedge"), reqs, get_config("llama3.2-1b"),
         itype="p3.2xlarge",
         autoscaler=ConstantTarget(2),
-        lb=LeastLoadedBalancer(),
+        lb="least_loaded",
         sim_config=SimConfig(itype="p3.2xlarge", cold_start_s=183.0,
                              control_interval_s=15.0, seed=0),
         timeout_s=100.0, sub_step_s=1.0, workload_name="poisson",
@@ -328,7 +329,7 @@ def test_serving_sim_does_not_mutate_shared_sim_config():
     shared = SimConfig(itype="p3.2xlarge", control_interval_s=30.0)
     trace = _tiny_trace()
     reqs = make_workload("poisson", rate_per_s=0.2, seed=1).generate(300.0)
-    ServingSimulator(
+    VectorizedServingEngine(
         trace, make_policy("spothedge"), reqs, get_config("llama3.2-1b"),
         itype="g5.48xlarge", sim_config=shared,
     )

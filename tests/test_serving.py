@@ -1,42 +1,25 @@
 """Serving data plane: latency model, replica queueing, LB, e2e sim."""
 
+from collections import Counter
+
 import numpy as np
 import pytest
 
+import fleet
+from fleet import ready_times, run_fleet, segments
 from repro.cluster.catalog import default_catalog
-from repro.cluster.instance import Instance, InstanceKind
-from repro.cluster.traces import SpotTrace, synth_correlated_trace
+from repro.cluster.traces import synth_correlated_trace
 from repro.configs import get_config
 from repro.core.autoscaler import ConstantTarget
 from repro.core.policy import make_policy
+from repro.serving.engine import VectorizedServingEngine
 from repro.serving.latency import LatencyModel
-from repro.serving.load_balancer import (
-    LeastLoadedBalancer,
-    RoundRobinBalancer,
-)
-from repro.serving.replica import Replica, ReplicaState
-from repro.serving.sim import ServingSimulator
 from repro.workloads import make_workload
 from repro.workloads.arrivals import Request
 
 CAT = default_catalog()
 CFG = get_config("llama3.2-1b")
-
-
-def mk_replica(zone="us-west-2a", t=0.0, ready=True, concurrency=2,
-               timeout_s=0.0):
-    z = CAT.zone(zone)
-    inst = Instance(
-        zone=zone, region=z.region, cloud=z.cloud,
-        kind=InstanceKind.SPOT, itype="g5.48xlarge", hourly_price=4.9,
-        launched_at=t, cold_start_s=183.0,
-    )
-    lm = LatencyModel.for_model(CFG, CAT.instance_type("g5.48xlarge"))
-    r = Replica(inst, lm, concurrency=concurrency, timeout_s=timeout_s)
-    if ready:
-        inst.step_to(t + 200.0)
-        r.readiness_probe(t + 200.0)
-    return r
+LM = LatencyModel.for_model(CFG, CAT.instance_type("g5.48xlarge"))
 
 
 # ---------------------------------------------------------------------------
@@ -68,92 +51,135 @@ def test_processing_dominates_rtt():
 
 
 # ---------------------------------------------------------------------------
-# Replica
+# Replica behaviour, on the engine (one- to three-replica fleets)
 # ---------------------------------------------------------------------------
+
+
+def _req(t, p=50, o=50, **kw):
+    return Request(arrival_s=t, prompt_tokens=p, output_tokens=o, **kw)
 
 
 def test_replica_readiness_follows_instance():
-    r = mk_replica(ready=False)
-    assert r.state is ReplicaState.PROVISIONING
-    r.instance.step_to(200.0)
-    assert r.readiness_probe(200.0)
+    """No request starts service before the replica's cold start ends."""
+    reqs = [_req(t) for t in (10.0, 60.0, 120.0)]
+    res, spans = run_fleet(reqs)
+    (ready,) = ready_times(res)
+    assert ready >= 183.0               # SimConfig's default cold start
+    assert res.n_completed == 3
+    for sp in spans:
+        assert segments(sp, "queue")[0]["t1_s"] >= ready
+        assert segments(sp, "service")[0]["t0_s"] >= ready
 
 
 def test_replica_completes_requests():
-    r = mk_replica()
-    req = Request(arrival_s=0.0, prompt_tokens=50, output_tokens=20)
-    r.submit(req, 0.0)
-    done, _ = r.step(0.0)
-    assert done == []          # just started
-    done, _ = r.step(1e6)
-    assert len(done) == 1
-    assert done[0][0].id == req.id
+    """A request on an idle ready replica completes after its service
+    time plus the client RTT."""
+    res, (sp,) = run_fleet([_req(300.0)])
+    assert res.n_completed == 1 and res.n_failed == 0
+    svc = LM.service_s(50, 50)
+    (service,) = segments(sp, "service")
+    assert service["t0_s"] == 300.0
+    assert service["t1_s"] == pytest.approx(300.0 + svc, abs=1e-9)
+    assert sp["outcome"] == "ok"
+    assert res.latencies_s[0] == pytest.approx(svc + sp["rtt_s"], abs=1e-9)
 
 
 def test_replica_concurrency_queueing():
-    r = mk_replica(concurrency=1)
-    reqs = [Request(arrival_s=0.0, prompt_tokens=50, output_tokens=50)
-            for _ in range(3)]
-    for q in reqs:
-        r.submit(q, 0.0)
-    r.step(0.0)
-    assert len(r.running) == 1 and len(r.queue) == 2
+    """Concurrency 1 serialises starts: each request waits in the
+    replica queue until the one before it has finished."""
+    res, spans = run_fleet([_req(300.0, o=500) for _ in range(3)],
+                           concurrency=1)
+    assert res.n_completed == 3
+    service = [segments(sp, "service")[0] for sp in spans]
+    for prev, nxt in zip(service, service[1:]):
+        assert nxt["t0_s"] >= prev["t1_s"]
+    # one slot, so no interference: every service span is the bare time
+    for seg in service:
+        assert seg["t1_s"] - seg["t0_s"] == pytest.approx(
+            LM.service_s(50, 500), rel=1e-9
+        )
+    assert [len(segments(sp, "rqueue")) for sp in spans] == [1, 1, 1]
+    assert segments(spans[2], "rqueue")[0]["t1_s"] > 300.0
 
 
 def test_replica_kill_returns_inflight():
-    r = mk_replica()
-    for _ in range(3):
-        r.submit(Request(arrival_s=0.0, prompt_tokens=10,
-                         output_tokens=10), 0.0)
-    r.step(0.0)
-    failed = r.kill()
-    assert len(failed) == 3
-    assert r.state is ReplicaState.DEAD
+    """A preemption re-pends the in-flight requests: each is retried on
+    the next replica, and the retries are counted."""
+    cap = np.ones(fleet.STEPS, np.int32)
+    cap[10:15] = 0                      # preempted at t=600 s
+    reqs = [_req(595.0 + i, o=20000) for i in range(3)]
+    res, spans = run_fleet(reqs, spot_cap=cap, concurrency=4)
+    assert res.n_preemptions == 1
+    assert res.n_retried_requests == 3
+    assert res.n_completed == 3
+    second_ready = ready_times(res)[1]
+    for sp in spans:
+        assert sp["attempts"] == 2
+        cut = [s for s in sp["segments"] if s.get("cut") == "preempt"]
+        assert [s["t1_s"] for s in cut] == [600.0]
+        assert segments(sp, "service")[-1]["t0_s"] >= second_ready
+        assert sp["outcome"] == "ok"
 
 
 def test_replica_queue_expiry():
-    r = mk_replica(concurrency=1, timeout_s=10.0)
-    r.submit(Request(arrival_s=0.0, prompt_tokens=10, output_tokens=10),
-             0.0)
-    r.submit(Request(arrival_s=0.0, prompt_tokens=10, output_tokens=10),
-             0.0)
-    r.step(0.0)
-    _, expired = r.step(50.0)
-    assert len(expired) == 1
+    """A request queued behind a long one past ``timeout_s`` fails."""
+    reqs = [_req(300.0, o=20000), _req(300.0)]
+    res, spans = run_fleet(reqs, concurrency=1, timeout_s=10.0)
+    assert LM.service_s(50, 20000) > 10.0
+    assert res.n_failed >= 1
+    sp = spans[1]
+    assert sp["outcome"] == "timeout"
+    (rq,) = segments(sp, "rqueue")
+    assert rq["cut"] == "timeout"
+    assert not segments(sp, "service")
+    # expiry is RTT-inclusive: t - arrival + rtt > timeout
+    assert rq["t1_s"] - 300.0 + 0.002 > 10.0
 
 
 # ---------------------------------------------------------------------------
-# Load balancers
+# Load balancers, on the engine
 # ---------------------------------------------------------------------------
 
 
 def test_round_robin_cycles():
-    lb = RoundRobinBalancer()
-    reps = [mk_replica(z) for z in
-            ("us-west-2a", "us-west-2b", "us-west-2c")]
-    lb.update_ready(reps)
-    req = Request(arrival_s=0.0, prompt_tokens=1, output_tokens=1)
-    picks = [lb.pick(req, 0.0).zone for _ in range(6)]
-    assert picks[:3] == ["us-west-2a", "us-west-2b", "us-west-2c"]
+    """Round-robin spreads requests evenly over the ready replicas."""
+    reqs = [_req(300.0 + 0.5 * i) for i in range(30)]
+    res, spans = run_fleet(reqs, n_replicas=3, lb="round_robin")
+    assert res.n_completed == 30
+    picks = [segments(sp, "rqueue")[0]["replica"] for sp in spans]
+    assert sorted(Counter(picks).values()) == [10, 10, 10]
+    assert picks[:3] == sorted(set(picks))
+    assert picks[3:6] == picks[:3]
 
 
 def test_least_loaded_prefers_idle():
-    lb = LeastLoadedBalancer()
-    busy, idle = mk_replica("us-west-2a"), mk_replica("us-west-2b")
-    for _ in range(4):
-        busy.submit(Request(arrival_s=0.0, prompt_tokens=9,
-                            output_tokens=9), 0.0)
-    lb.update_ready([busy, idle])
-    pick = lb.pick(Request(arrival_s=0.0, prompt_tokens=1,
-                           output_tokens=1), 0.0)
-    assert pick is idle
+    """Least-loaded sends new work past a busy replica to an idle one."""
+    # 2 s apart: each short request's completion is booked (at the next
+    # sub-tick) before the following one is routed
+    reqs = [_req(300.0, o=20000)] + [_req(301.0 + 2 * i) for i in range(6)]
+    res, spans = run_fleet(reqs, n_replicas=2, concurrency=1)
+    assert res.n_completed == 7
+    assert segments(spans[0], "service")[0]["t1_s"] > reqs[-1].arrival_s
+    busy = segments(spans[0], "rqueue")[0]["replica"]
+    others = {segments(sp, "rqueue")[0]["replica"] for sp in spans[1:]}
+    assert others == {1 - busy}
+    with pytest.raises(ValueError, match="lb must be one of"):
+        run_fleet(reqs, lb="random")
 
 
 def test_lb_returns_none_when_nothing_ready():
-    lb = LeastLoadedBalancer()
-    lb.update_ready([])
-    assert lb.pick(Request(arrival_s=0.0, prompt_tokens=1,
-                           output_tokens=1), 0.0) is None
+    """With no ready replica, requests wait in the pending pool; those
+    whose wait passes ``timeout_s`` fail there, the rest are served."""
+    reqs = [_req(10.0), _req(150.0)]
+    res, (early, late) = run_fleet(reqs, timeout_s=100.0)
+    (ready,) = ready_times(res)
+    assert early["outcome"] == "timeout"
+    assert [s["name"] for s in early["segments"]] == ["queue"]
+    assert early["segments"][0]["cut"] == "timeout"
+    assert early["finish_s"] - 10.0 > 100.0
+    assert late["outcome"] == "ok"
+    assert segments(late, "queue")[0]["t1_s"] == ready
+    assert res.n_completed == 1 and res.n_failed == 1
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +199,7 @@ def test_serving_sim_completes_requests():
     reqs = make_workload("poisson", rate_per_s=0.5, seed=1).generate(
         3 * 3600.0
     )
-    sim = ServingSimulator(
+    sim = VectorizedServingEngine(
         tr, make_policy("spothedge"), reqs, CFG, itype="g5.48xlarge",
         autoscaler=ConstantTarget(2), timeout_s=60.0,
         workload_name="poisson",
@@ -192,7 +218,7 @@ def test_spothedge_beats_singleregion_spot_on_failures():
     )
 
     def run(policy, zones=None):
-        sim = ServingSimulator(
+        sim = VectorizedServingEngine(
             tr, make_policy(policy), reqs, CFG, itype="g5.48xlarge",
             autoscaler=ConstantTarget(3), timeout_s=60.0, concurrency=2,
         )

@@ -11,8 +11,9 @@ mirroring the repo's other property suites.
 
 The byte-identity and parity tests pin the PR's tracing contract:
 
-* legacy ``ServingSimulator`` and ``VectorizedServingEngine`` produce
-  byte-identical span JSONL on the fixed token+migration scenario;
+* ``VectorizedServingEngine`` produces the span JSONL bytes recorded
+  from the per-request object engine it replaced (SHA-256 in
+  tests/data/engine_record.json) on the fixed token+migration scenario;
 * ``JaxServingEngine``'s host-side reconstruction matches the vector
   spans byte-for-byte after filtering to completion-resolved
   single-attempt requests (the kernel records the final attempt only);
@@ -26,6 +27,7 @@ from types import SimpleNamespace
 import numpy as np
 import pytest
 
+from engine_record import scenario, sha256
 from repro.cluster.traces import synth_correlated_trace
 from repro.configs import get_config
 from repro.core.autoscaler import ConstantTarget
@@ -36,7 +38,6 @@ from repro.obs.slo import SLOBurnConfig
 from repro.obs.spans import SpanCollector, span_sampled
 from repro.serving.engine import VectorizedServingEngine
 from repro.serving.jaxengine import JaxServingEngine
-from repro.serving.sim import ServingSimulator
 from repro.serving.token import TokenSchedulerConfig
 from repro.workloads import make_workload
 
@@ -191,17 +192,14 @@ def test_span_sampled_deterministic():
 
 
 @pytest.fixture(scope="module")
-def token_migration_runs():
+def token_migration_run():
     spec = MigrationSpec(enabled=True, drain_threshold_s=2.0)
-    legacy = _run(ServingSimulator, replica_model="token",
-                  migration=spec)
-    vector = _run(VectorizedServingEngine, replica_model="token",
-                  migration=spec)
-    return legacy, vector
+    return _run(VectorizedServingEngine, replica_model="token",
+                migration=spec)
 
 
-def test_span_tiling_token_migration(token_migration_runs):
-    _, vector = token_migration_runs
+def test_span_tiling_token_migration(token_migration_run):
+    vector = token_migration_run
     recs = vector.obs.span_records()
     assert recs
     check_span_tiling(recs)
@@ -211,15 +209,15 @@ def test_span_tiling_token_migration(token_migration_runs):
         assert "transfer" in kinds
 
 
-def test_span_bytes_identical_token_migration(token_migration_runs):
-    legacy, vector = token_migration_runs
-    a = dumps_jsonl(legacy.obs.span_records())
-    b = dumps_jsonl(vector.obs.span_records())
-    assert a and a == b
+def test_span_bytes_identical_token_migration(token_migration_run):
+    want = scenario("spans_token_migration")
+    b = dumps_jsonl(token_migration_run.obs.span_records())
+    assert b and len(b.splitlines()) == want["n_lines"]
+    assert sha256(b) == want["sha256"]
 
 
-def test_sampling_subset_matches_hash(token_migration_runs):
-    del token_migration_runs   # ordering only; this run is cheap
+def test_sampling_subset_matches_hash(token_migration_run):
+    del token_migration_run   # ordering only; this run is cheap
     res = _run(VectorizedServingEngine, trace_sample=0.25)
     recs = res.obs.span_records()
     assert recs

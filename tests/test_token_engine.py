@@ -3,7 +3,8 @@
 Covers the ISSUE-5 acceptance surface: the batch-1 reduction to the
 request-level latency model, KV-budget admission invariants (hypothesis),
 preemption-loses-KV re-prefill accounting, TTFT/TPOT/goodput metric
-units, engine integration (legacy == vector in token mode), and the
+units, engine integration (token mode pinned to the recorded answers
+in tests/data/engine_record.json), and the
 spec/suite plumbing (serving: section, sweep.replica_models axis,
 concurrency_cap satellite).
 """
@@ -13,6 +14,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from engine_record import assert_matches
 from repro.cluster.catalog import default_catalog
 from repro.cluster.traces import synth_correlated_trace
 from repro.configs import get_config
@@ -20,8 +22,6 @@ from repro.core.autoscaler import ConstantTarget
 from repro.core.policy import make_policy
 from repro.serving.engine import VectorizedServingEngine
 from repro.serving.latency import LatencyModel
-from repro.serving.replica import Replica
-from repro.serving.sim import ServingSimulator
 from repro.serving.token import (
     ContinuousBatch,
     TokenEngineConfig,
@@ -289,7 +289,7 @@ def test_simulator_aggregates_preemption_accounting():
     reqs = make_workload("poisson", rate_per_s=0.8, seed=3).generate(
         2 * 3600.0
     )
-    sim = ServingSimulator(
+    sim = VectorizedServingEngine(
         tr, make_policy("spothedge"), reqs, CFG, itype="g5.48xlarge",
         autoscaler=ConstantTarget(3), timeout_s=60.0,
         replica_model="token",
@@ -310,7 +310,7 @@ def test_simulator_aggregates_preemption_accounting():
 def _token_run(slo_ttft=10.0, slo_tpot=0.2):
     tr = _mini_trace(steps=120, seed=21)
     reqs = make_workload("poisson", rate_per_s=0.5, seed=1).generate(3600.0)
-    sim = ServingSimulator(
+    sim = VectorizedServingEngine(
         tr, make_policy("spothedge"), reqs, CFG, itype="g5.48xlarge",
         autoscaler=ConstantTarget(2), timeout_s=60.0,
         replica_model="token",
@@ -369,7 +369,7 @@ def test_empty_run_stats():
 
 
 # ---------------------------------------------------------------------------
-# engine integration: legacy == vector in token mode
+# engine integration: token mode against the recorded answers
 # ---------------------------------------------------------------------------
 
 
@@ -378,39 +378,21 @@ def test_empty_run_stats():
     ("even_spread", "rr"),
 ])
 def test_token_mode_differential(policy, lb):
-    from repro.serving.load_balancer import RoundRobinBalancer
-
     tr = _mini_trace(steps=150, seed=7)
     reqs = make_workload("poisson", rate_per_s=0.8, seed=7).generate(
         2 * 3600.0
     )
-    results = []
-    for cls in (ServingSimulator, VectorizedServingEngine):
-        kwargs = dict(
-            itype="g5.48xlarge", autoscaler=ConstantTarget(3),
-            timeout_s=60.0, replica_model="token",
-        )
-        if lb == "rr":
-            kwargs["lb"] = RoundRobinBalancer()
-        sim = cls(tr, make_policy(policy), reqs, CFG, **kwargs)
-        results.append(sim.run(2 * 3600.0 + 600.0))
-    legacy, vector = results
-    assert vector.n_requests == legacy.n_requests
-    assert vector.n_completed == legacy.n_completed
-    assert vector.n_failed == legacy.n_failed
-    np.testing.assert_allclose(
-        np.sort(vector.latencies_s), np.sort(legacy.latencies_s),
-        atol=1e-9, rtol=0,
+    kwargs = dict(
+        itype="g5.48xlarge", autoscaler=ConstantTarget(3),
+        timeout_s=60.0, replica_model="token",
     )
-    np.testing.assert_allclose(
-        np.sort(vector.token.ttft_s), np.sort(legacy.token.ttft_s),
-        atol=1e-9, rtol=0,
-    )
-    assert vector.token.n_slo_ok == legacy.token.n_slo_ok
-    assert (vector.token.n_kv_preempted_seqs
-            == legacy.token.n_kv_preempted_seqs)
-    assert (vector.token.lost_prefill_tokens
-            == legacy.token.lost_prefill_tokens)
+    if lb == "rr":
+        kwargs["lb"] = "round_robin"
+    sim = VectorizedServingEngine(tr, make_policy(policy), reqs, CFG,
+                                  **kwargs)
+    res = sim.run(2 * 3600.0 + 600.0)
+    assert_matches(res, f"token_mode_differential[{policy}-{lb}]",
+                   atol=1e-9)
 
 
 # ---------------------------------------------------------------------------
@@ -525,25 +507,23 @@ def test_concurrency_cap_lifted_to_spec():
 
 
 def test_eta_includes_residual_running_time():
-    from repro.cluster.instance import Instance, InstanceKind
+    """A request that must wait for a running sequence finishes that
+    sequence's residual running time later than it would alone."""
+    from fleet import run_fleet, segments
 
-    z = CAT.zone("us-west-2a")
-    inst = Instance(
-        zone="us-west-2a", region=z.region, cloud=z.cloud,
-        kind=InstanceKind.SPOT, itype="g5.48xlarge", hourly_price=4.9,
-        launched_at=0.0, cold_start_s=183.0,
+    long = Request(arrival_s=300.0, prompt_tokens=1000, output_tokens=20000)
+    probe = Request(arrival_s=305.0, prompt_tokens=50, output_tokens=50)
+    kw = dict(replica_model="token",
+              token_scheduler=TokenSchedulerConfig(max_batch=1))
+    alone, (p_alone,) = run_fleet([probe], **kw)
+    busy, (p_long, p_busy) = run_fleet([long, probe], **kw)
+    assert alone.n_completed == 1 and busy.n_completed == 2
+    # the probe joins the one-sequence batch when the long one's last
+    # decode iteration ends; that residual is added to its latency
+    end_long = segments(p_long, "decode")[-1]["t1_s"]
+    residual = end_long - probe.arrival_s
+    assert residual > 0
+    assert segments(p_busy, "prefill")[0]["t0_s"] == end_long
+    assert p_busy["e2e_s"] == pytest.approx(
+        p_alone["e2e_s"] + residual, rel=1e-9
     )
-    inst.step_to(200.0)
-    rep = Replica(inst, LM, concurrency=1)
-    rep.readiness_probe(200.0)
-    probe = Request(arrival_s=200.0, prompt_tokens=50, output_tokens=50)
-    idle_eta = rep.eta_if_submitted(probe, 200.0)
-    # fill the only slot with a long request: ETA must now include its
-    # residual service time even though the queue is empty
-    rep.submit(Request(arrival_s=200.0, prompt_tokens=1000,
-                       output_tokens=1000), 200.0)
-    rep.step(200.0)
-    assert len(rep.running) == 1 and not rep.queue
-    busy_eta = rep.eta_if_submitted(probe, 200.0)
-    residual = rep.running[0].finish_s - 200.0
-    assert busy_eta == pytest.approx(idle_eta + residual, rel=1e-9)

@@ -113,29 +113,26 @@ def test_client_regions_validation():
 
 def test_client_regions_exercise_rtt_in_lb():
     """Cross-region clients see the RTT term in their e2e latency."""
+    from fleet import ZONE, run_fleet, segments
     from repro.cluster.catalog import default_catalog, region_rtt_ms
-    from repro.cluster.instance import Instance, InstanceKind
-    from repro.configs import get_config
-    from repro.serving.latency import LatencyModel
-    from repro.serving.load_balancer import LoadBalancer
-    from repro.serving.replica import Replica
 
-    cat = default_catalog()
-    z = cat.zone("us-west-2a")
-    inst = Instance(
-        zone=z.name, region=z.region, cloud=z.cloud,
-        kind=InstanceKind.SPOT, itype="g5.48xlarge", hourly_price=4.9,
-        launched_at=0.0, cold_start_s=183.0,
-    )
-    lm = LatencyModel.for_model(
-        get_config("llama3.2-1b"), cat.instance_type("g5.48xlarge")
-    )
-    rep = Replica(inst, lm, concurrency=2)
-    far = Request(arrival_s=0.0, prompt_tokens=10, output_tokens=10,
+    region = default_catalog().zone(ZONE).region
+    far = Request(arrival_s=300.0, prompt_tokens=10, output_tokens=10,
                   client_region="eu-central-1")
-    near = Request(arrival_s=0.0, prompt_tokens=10, output_tokens=10,
-                   client_region="us-west-2")
-    assert LoadBalancer.rtt_s(far, rep) == pytest.approx(
-        region_rtt_ms("eu-central-1", "us-west-2") / 1e3
+    near = Request(arrival_s=310.0, prompt_tokens=10, output_tokens=10,
+                   client_region=region)
+    res, (sp_far, sp_near) = run_fleet([far, near])
+    assert res.n_completed == 2
+    rtt_far = region_rtt_ms("eu-central-1", region) / 1e3
+    rtt_near = region_rtt_ms(region, region) / 1e3
+    assert rtt_far > rtt_near
+    for sp, rtt in ((sp_far, rtt_far), (sp_near, rtt_near)):
+        assert sp["rtt_s"] == pytest.approx(rtt)
+        (service,) = segments(sp, "service")
+        # same tokens, idle replica: equal service, so e2e differs by RTT
+        assert sp["e2e_s"] == pytest.approx(
+            service["t1_s"] - sp["arrival_s"] + rtt, abs=1e-9
+        )
+    assert sp_far["e2e_s"] - sp_near["e2e_s"] == pytest.approx(
+        rtt_far - rtt_near, abs=1e-9
     )
-    assert LoadBalancer.rtt_s(far, rep) > LoadBalancer.rtt_s(near, rep)
