@@ -21,7 +21,8 @@ Kernels:
   blocked over KV with running max/denominator.
 * ``selective_scan``   — Mamba-1 within-chunk recurrence h' = a·h + b.
 * ``moe_gmm``          — grouped matmul over ragged per-expert row groups
-  (a scalar-prefetched tile→expert table picks each tile's weights).
+  (a scalar-prefetched tile→expert table and layer index pick each
+  tile's weights from a layer stack of experts).
 
 TPU tiling notes: MXU wants the two minor dims in multiples of (8, 128)
 for fp32 / (16, 128) for bf16; every BlockSpec here keeps each of its

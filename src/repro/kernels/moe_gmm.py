@@ -3,12 +3,18 @@
 Computes ``y[r] = x[r] @ w[g(r)]`` for rows ``x (M, D)`` sorted by group
 (expert) and padded per group to the row tile ``block_m``: group ``g``
 owns ``group_sizes[g]`` consecutive rows, a multiple of ``block_m``, so
-each row tile belongs to one group.  ``w`` is ``(G, D, F)``.
+each row tile belongs to one group.  ``w`` is a stack ``(L, G, D, F)``
+of every layer's experts, read at ``layer``: a model's layer scan hands
+over the whole stack and its layer index, so no layer's experts are
+sliced out of it (a Pallas call cannot fuse a slice of its operand, so
+XLA would copy the slice before every call).  A caller that holds one
+layer's ``(G, D, F)`` passes no ``layer``; it is read as a stack of one.
 
-Grid ``(F / bf, M / block_m)``.  A tile→group table and the number of
-live tiles are scalar-prefetched: each tile's weight block is the block
-of its group, so consecutive tiles of one group reuse the resident
-weights and each group's weights stream from HBM once per F block.
+Grid ``(F / bf, M / block_m)``.  A tile→group table, the number of
+live tiles and the layer are scalar-prefetched: each tile's weight block
+is the block of its layer and group, so consecutive tiles of one group
+reuse the resident weights and each group's weights stream from HBM
+once per F block.
 Tiles past the live ones name the last live tile's blocks, so Pallas
 copies nothing for them, and ``pl.when`` skips their MXU work; their
 output rows are left unwritten.  No ``(E, C, D)`` buffer and no padded
@@ -22,6 +28,7 @@ accumulation over the whole of D in one product.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -76,25 +83,28 @@ def tile_groups(group_sizes: jax.Array, n_tiles: int, block_m: int):
     return group, live[None]
 
 
-def _kernel(group_ref, live_ref, x_ref, w_ref, o_ref):
+def _kernel(group_ref, live_ref, layer_ref, x_ref, w_ref, o_ref):
     @pl.when(pl.program_id(1) < live_ref[0])
     def _compute():
         o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[0], (((1,), (0,)), ((), ())),
+            x_ref[...], w_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ).astype(o_ref.dtype)
 
 
 def gmm(
     x: jax.Array,                 # (M, D) rows sorted and padded by group
-    w: jax.Array,                 # (G, D, F)
+    w: jax.Array,                 # (L, G, D, F), or one layer's (G, D, F)
     group_sizes: jax.Array,       # (G,) int32, multiples of block_m
+    layer: Optional[jax.Array] = None,   # () int32: the stack's layer read
     *,
     block_m: int,
     interpret: bool = False,
 ) -> jax.Array:
+    if layer is None:
+        w, layer = w[None], 0
     M, D = x.shape
-    F = w.shape[2]
+    F = w.shape[3]
     if M % block_m:
         raise ValueError(f"{M} rows are not whole tiles of {block_m}")
     n_tiles = M // block_m
@@ -105,23 +115,23 @@ def gmm(
     def tile(t, live_ref):
         return jnp.minimum(t, jnp.maximum(live_ref[0] - 1, 0))
 
-    def x_map(f, t, group_ref, live_ref):
+    def x_map(f, t, group_ref, live_ref, layer_ref):
         return tile(t, live_ref), 0
 
-    def w_map(f, t, group_ref, live_ref):
-        return group_ref[tile(t, live_ref)], 0, f
+    def w_map(f, t, group_ref, live_ref, layer_ref):
+        return layer_ref[0], group_ref[tile(t, live_ref)], 0, f
 
-    def o_map(f, t, group_ref, live_ref):
+    def o_map(f, t, group_ref, live_ref, layer_ref):
         return tile(t, live_ref), f
 
     return pl.pallas_call(
         _kernel,
         grid_spec=compat.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(F // bf, n_tiles),
             in_specs=[
                 pl.BlockSpec((block_m, D), x_map),
-                pl.BlockSpec((1, D, bf), w_map),
+                pl.BlockSpec((1, 1, D, bf), w_map),
             ],
             out_specs=pl.BlockSpec((block_m, bf), o_map),
         ),
@@ -131,4 +141,4 @@ def gmm(
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
         interpret=interpret,
-    )(group, live, x, w)
+    )(group, live, jnp.reshape(layer, (1,)).astype(jnp.int32), x, w)

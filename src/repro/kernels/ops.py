@@ -113,15 +113,18 @@ def selective_scan(
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def moe_gmm(
     x: jax.Array,                 # (M, D) rows sorted, padded per group
-    w: jax.Array,                 # (G, D, F)
+    w: jax.Array,                 # (L, G, D, F) a stack, or (G, D, F)
     group_sizes: jax.Array,       # (G,) int32, multiples of block_m
+    layer: Optional[jax.Array] = None,   # () int32: the stack's layer read
     *,
     block_m: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """Ragged grouped matmul (``kernels/moe_gmm.py``); rows past the
+    """Ragged grouped matmul (``kernels/moe_gmm.py``) with ``layer``'s
+    experts of the stack ``w``, read where they lie; rows past the
     groups' are left unwritten."""
-    return _gmm.gmm(x, w, group_sizes, block_m=block_m, interpret=interpret)
+    return _gmm.gmm(x, w, group_sizes, layer, block_m=block_m,
+                    interpret=interpret)
 
 
 def moe_ffn(

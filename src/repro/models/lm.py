@@ -26,8 +26,11 @@ assignment (everything except Whisper's encoder-decoder, see ``whisper.py``):
   ``sliding_window`` slots and the full layers' whole-context cache,
 * MoE models serve (``prefill``, ``decode_step``) through the dropless
   expert share ``moe.moe_serve`` and count each held expert's
-  assignments in the cache's ``moe_load``; the full-sequence forward
-  (training) keeps the capacity layer and its aux loss,
+  assignments in the cache's ``moe_load``; a serving scan closes over
+  the expert stacks whole and ``moe_gmm`` reads each layer's experts
+  where they lie, at the layer's index (the cache's), so no layer's
+  experts are sliced out; the full-sequence forward (training) keeps
+  the capacity layer and its aux loss,
 * the serving step names its parts with ``jax.named_scope`` (``embed``,
   ``layers``, ``logits``; in an attention block ``norm``, ``attention``
   with its ``kv_write``, ``ffn`` holding the expert layer's ``moe``;
@@ -248,14 +251,15 @@ class TransformerLM:
     # ==================================================================
     def _attn_block(
         self, lp, x, *, positions, mode, kv, layer, cache_len, prefix_len,
-        full=False,
+        full=False, experts=None,
     ):
         """One attention block, layer ``layer`` of the stack whose cache is
-        ``kv``.  Returns (x, the updated cache stacks, aux loss, load): a
-        serving step (``kv`` given) of an MoE model runs the dropless
-        expert share, whose per-expert ``load`` it returns (else None);
-        the full-sequence forward runs the capacity layer and its aux
-        loss."""
+        ``kv`` and whose expert stacks are ``experts`` (``_split_experts``;
+        empty: ``lp`` holds the layer's own).  Returns (x, the updated
+        cache stacks, aux loss, load): a serving step (``kv`` given) of an
+        MoE model runs the dropless expert share, whose per-expert
+        ``load`` it returns (else None); the full-sequence forward runs the
+        capacity layer and its aux loss."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
         load = None
@@ -275,8 +279,9 @@ class TransformerLM:
                 if not cfg.is_moe:
                     return mlp_apply(lp["mlp"], cfg, hn)
                 if kv is not None:
-                    f, load = moe_mod.moe_serve(lp["moe"], cfg, hn,
-                                                impl=self.impl)
+                    f, load = moe_mod.moe_serve(
+                        dict(lp["moe"], **(experts or {})), cfg, hn,
+                        impl=self.impl, layer=layer if experts else None)
                     return f
                 f, aux_l = moe_mod.moe_apply(lp["moe"], cfg, hn,
                                              return_aux=True)
@@ -312,6 +317,20 @@ class TransformerLM:
     # ==================================================================
     # Stacks
     # ==================================================================
+    def _split_experts(self, stack, serving: bool):
+        """(the expert matrices of a layer stack's parameters, the rest).
+        A serving scan closes over the expert stacks whole, a loop
+        invariant that ``moe_gmm`` reads at the layer's index, and takes
+        the rest as its xs.  A dense stack, and the full-sequence forward
+        (whose capacity layer takes one layer's experts), split off
+        nothing."""
+        if not (serving and self.cfg.is_moe):
+            return {}, stack
+        moe = dict(stack["moe"])
+        experts = {k: moe.pop(k) for k in moe_mod.EXPERT_MATRICES
+                   if k in moe}
+        return experts, dict(stack, moe=moe)
+
     def _run_uniform_stack(
         self, params, x, *, positions, mode, cache, prefix_len
     ):
@@ -352,13 +371,17 @@ class TransformerLM:
             return x, new_cache, jnp.zeros((), jnp.float32)
 
         # attention families: the cache stacks ride in the carry, the
-        # scan's xs are (layer index, layer params)
+        # scan's xs are (layer index, layer params); a serving step's
+        # expert stacks are closed over, read at the layer index
+        experts, layers = self._split_experts(params["decoder"],
+                                              cache is not None)
+
         def body(carry, per_layer):
             xc, aux_acc, kv = carry
             layer, lp = per_layer
             y, kv, aux, load = self._attn_block(
                 lp, xc, positions=positions, mode=mode, kv=kv, layer=layer,
-                cache_len=cache_len, prefix_len=prefix_len,
+                cache_len=cache_len, prefix_len=prefix_len, experts=experts,
             )
             return (y, aux_acc + aux, kv), load
 
@@ -367,7 +390,7 @@ class TransformerLM:
         kv = cache["kv"] if cache is not None else None
         (x, aux, kv), load = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32), kv),
-            (jnp.arange(cfg.num_layers), params["decoder"]),
+            (jnp.arange(cfg.num_layers), layers),
         )
         if cache is None:
             return x, None, aux
@@ -386,24 +409,33 @@ class TransformerLM:
         ``(P·n, B, Kv, ring, D)``, window layer j of period p at
         ``p·n + j``, and the full layers' ``(P, B, Kv, slots, D)``.  Both
         stacks ride in the scans' carries; each layer reads and writes its
-        own slots where they lie, at its index."""
+        own slots where they lie, at its index.  A serving step's expert
+        stacks are indexed alike, ``(P·n, E, d, f)`` and ``(P, E, d, f)``,
+        and closed over whole."""
         cfg = self.cfg
         dec = params["decoder"]
         cache_len = None if cache is None else cache["len"]
         n = cfg.full_attn_every - 1
         zero = jnp.zeros((), jnp.float32)
+        serving = cache is not None
+        experts_w, window_layers = self._split_experts(dec["window"],
+                                                       serving)
+        experts_f, full_layers = self._split_experts(dec["full"], serving)
+        experts_w = {k: w.reshape((-1,) + w.shape[2:])
+                     for k, w in experts_w.items()}
 
-        def block(full):
+        def block(full, experts):
             def body(xc, lp, kv, layer):
                 return self._attn_block(
                     lp, xc, positions=positions, mode=mode, kv=kv,
                     layer=layer, cache_len=cache_len, prefix_len=prefix_len,
-                    full=full,
+                    full=full, experts=experts,
                 )
 
             return jax.checkpoint(body) if self.remat else body
 
-        window_block, full_block = block(False), block(True)
+        window_block = block(False, experts_w)
+        full_block = block(True, experts_f)
         kv_w = kv_f = load = None
         if cache is not None:
             kv_w, kv_f = cache["kv_window"], cache["kv_full"]
@@ -418,6 +450,8 @@ class TransformerLM:
                 j, lp = per_layer
                 y, kv_w, aux, n_load = window_block(xi, lp, kv_w, p * n + j)
                 if n_load is not None:
+                    # the counter's rows are the model's layers; the
+                    # window caches and experts stack window layers only
                     load = load.at[p * cfg.full_attn_every + j].add(n_load)
                 return (y, aux_i + aux, kv_w, load), None
 
@@ -433,7 +467,7 @@ class TransformerLM:
 
         (x, aux, kv_w, kv_f, load), _ = jax.lax.scan(
             period, (x, zero, kv_w, kv_f, load),
-            (jnp.arange(cfg.periods), dec["window"], dec["full"]),
+            (jnp.arange(cfg.periods), window_layers, full_layers),
         )
         if cache is None:
             return x, None, aux

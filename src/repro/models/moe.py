@@ -14,15 +14,16 @@ Two layers share the router and the expert weights:
   It routes over all ``cfg.num_experts``, keeps the assignments to its
   own experts, sorts them by expert and runs a grouped matmul over the
   ragged groups — the ``repro.kernels.moe_gmm`` Pallas kernel with
-  ``impl="pallas"``, ``jax.lax.ragged_dot`` otherwise.  Its result is
-  this device's part of the layer's output; the parts of all devices sum
-  to the whole layer's.
+  ``impl="pallas"``, ``jax.lax.ragged_dot`` otherwise.  A model's layer
+  scan hands it the whole expert stacks and the layer's index, which the
+  kernel reads where they lie.  Its result is this device's part of the
+  layer's output; the parts of all devices sum to the whole layer's.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -189,6 +190,8 @@ def moe_apply(
 #: chunk under ``lax.scan`` (the last one padded with tokens routed to no
 #: expert), so the sorted rows stay O(chunk · top_k · d)
 SERVE_CHUNK_TOKENS = 16_384
+#: the expert matrices of a layer's parameters, each ``(held, ...)``
+EXPERT_MATRICES = ("wi", "wg", "wo")
 
 
 def moe_serve(
@@ -197,16 +200,23 @@ def moe_serve(
     x: jax.Array,                    # (B, S, d)
     *,
     impl: str = "einsum",            # "pallas": the moe_gmm kernel
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """This device's part of the MoE layer, dropping nothing.  Returns
-    (y (B, S, d), load (held,) int32: the assignments each held expert
-    received)."""
+    """This device's part of the MoE layer, dropping nothing.  ``p``'s
+    expert matrices (``wi``, ``wg``, ``wo``) are one layer's ``(held, d,
+    f)``, or with ``layer`` (a () int32) stacks ``(L, held, d, f)`` read at
+    that index.  Returns (y (B, S, d), load (held,) int32: the
+    assignments each held expert received)."""
     B, S, d = x.shape
     N = B * S
+    if layer is None:
+        p = dict(p, **{k: p[k][None] for k in EXPERT_MATRICES if k in p})
+        layer = 0
     with jax.named_scope("moe"):
         xf = x.reshape(N, d)
         if N <= SERVE_CHUNK_TOKENS:
-            y, load = _serve_tokens(p, cfg, xf, jnp.ones((N,), bool), impl)
+            y, load = _serve_tokens(p, cfg, xf, jnp.ones((N,), bool),
+                                    layer, impl)
             return y.reshape(B, S, d), load
         n = -(-N // SERVE_CHUNK_TOKENS)
         pad = n * SERVE_CHUNK_TOKENS - N
@@ -216,7 +226,7 @@ def moe_serve(
             n, SERVE_CHUNK_TOKENS)
 
         def step(load, chunk):
-            y, c = _serve_tokens(p, cfg, *chunk, impl)
+            y, c = _serve_tokens(p, cfg, *chunk, layer, impl)
             return load + c, y
 
         load, ys = jax.lax.scan(
@@ -226,8 +236,9 @@ def moe_serve(
 
 
 def _serve_tokens(p, cfg: ModelConfig, x: jax.Array, real: jax.Array,
-                  impl: str):
-    """The layer on ``x (N, d)``; tokens not ``real`` go to no expert."""
+                  layer, impl: str):
+    """The layer on ``x (N, d)`` with ``layer``'s experts of the stacks in
+    ``p``; tokens not ``real`` go to no expert."""
     from repro.kernels import moe_gmm as gmm_mod
 
     N, d = x.shape
@@ -262,11 +273,14 @@ def _serve_tokens(p, cfg: ModelConfig, x: jax.Array, real: jax.Array,
         if impl == "pallas":
             from repro.kernels import ops as kops
 
+            # the whole stack, read at the layer by the kernel; the cast
+            # is a no-op on weights served in the step's dtype
             def mm(a, w):
-                return kops.moe_gmm(a, w.astype(dt), padded, block_m=bm)
+                return kops.moe_gmm(a, w.astype(dt), padded, layer,
+                                    block_m=bm)
         else:
             def mm(a, w):
-                return jax.lax.ragged_dot(a, w.astype(dt), padded)
+                return jax.lax.ragged_dot(a, w[layer].astype(dt), padded)
 
         h = mm(xs, p["wi"])
         act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu

@@ -354,6 +354,34 @@ def test_moe_gmm_matches_ref(case, dtype):
     )
 
 
+@pytest.mark.parametrize("layer", [0, 2, 4])          # first, middle, last
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_reads_the_indexed_layer(layer, dtype, monkeypatch):
+    """A stack (L, G, D, F) of five layers, each with its own experts,
+    read at ``layer`` gives bit for bit what that layer's (G, D, F) gives
+    alone, and no other layer's rows: ragged groups, empty ones, dead
+    tiles past the live ones; in float32 F is split into 128-wide
+    blocks."""
+    monkeypatch.setattr(gmm_mod, "_W_BLOCK_BYTES", 64 * 128 * 4)
+    sizes, M, L, D, F = [0, 16, 48, 0, 16], 160, 5, 64, 256
+    x = rnd(25, (M, D), dtype)
+    w = rnd(26, (L, len(sizes), D, F), dtype)
+    g = jnp.asarray(sizes, jnp.int32)
+    live = sum(sizes)
+
+    def rows(*args):
+        out = gmm_mod.gmm(x, *args, block_m=16, interpret=True)
+        return np.asarray(out[:live].astype(jnp.float32))
+
+    got = rows(w, g, jnp.int32(layer))
+    np.testing.assert_array_equal(got, rows(w[layer], g))
+    for other in set(range(L)) - {layer}:
+        assert not np.array_equal(got, rows(w[other], g)), other
+    via_ops = ops.moe_gmm(x, w, g, jnp.int32(layer), block_m=16,
+                          interpret=True)[:live]
+    np.testing.assert_array_equal(via_ops.astype(jnp.float32), got)
+
+
 def test_moe_ffn_matches_dense_path():
     """ops.moe_ffn == the model's einsum expert FFN."""
     E, C, D, F = 4, 32, 64, 96

@@ -284,3 +284,86 @@ def test_serving_layer_drops_nothing(impl, request):
     dropped, _ = moe.moe_apply(p, CFG, x)
     lost = jnp.all(dropped == 0, axis=-1) & jnp.any(want != 0, axis=-1)
     assert int(lost.sum()) > 0
+
+
+# ---------------------------------------------------------------------------
+# the expert stacks read where they lie
+# ---------------------------------------------------------------------------
+
+# three periods: the model's layer p·full_attn_every + j (the row of
+# ``moe_load``) runs past the window stack's P·n layers, so an expert
+# stack read at that index would give a window layer another's experts,
+# or none
+CFG3 = dataclasses.replace(CFG, num_layers=3 * CFG.full_attn_every)
+STACK_PROMPT, STACK_STEPS = 20, 3
+
+
+def _serve_pallas(cfg, params, toks):
+    """Prefill, then decode steps: (logits of each, the last cache)."""
+    model = build_model(cfg, impl="pallas")
+    cache = model.init_cache(B, toks.shape[1], dtype=F32)
+    lg, cache = model.prefill(params, toks[:, :STACK_PROMPT], cache,
+                              dtype=F32)
+    out = [lg[:, -1]]
+    step = jax.jit(functools.partial(model.decode_step, dtype=F32))
+    for t in range(STACK_PROMPT, toks.shape[1]):
+        lg, cache = step(params, toks[:, t:t + 1], cache)
+        out.append(lg[:, -1])
+    return jnp.stack(out, 1), cache
+
+
+def _sliced_experts(monkeypatch):
+    """The step before the stacks were read in place: the layer scan
+    slices each layer's experts out as its xs and hands the kernel that
+    layer's (E, d, f) alone."""
+    from repro.models import lm
+
+    monkeypatch.setattr(lm.TransformerLM, "_split_experts",
+                        lambda self, stack, serving: ({}, stack))
+
+
+def _roll_window_experts(params):
+    """The window layers' experts moved one layer on, over all P·n."""
+    window = params["decoder"]["window"]
+
+    def roll(w):
+        flat = w.reshape((-1,) + w.shape[2:])
+        return jnp.roll(flat, 1, axis=0).reshape(w.shape)
+
+    moe_p = dict(window["moe"], **{k: roll(window["moe"][k])
+                                   for k in moe.EXPERT_MATRICES})
+    decoder = dict(params["decoder"], window=dict(window, moe=moe_p))
+    return dict(params, decoder=decoder)
+
+
+@pytest.mark.parametrize("arch,rolled", [
+    ("mellum2-12b", False),
+    ("mellum2-12b", True),
+    ("qwen3-moe-30b", False),
+])
+def test_experts_read_in_place_match_sliced_experts(arch, rolled,
+                                                    interpret, monkeypatch):
+    """Prefill and decode steps (``impl="pallas"``, float32) in which
+    ``moe_gmm`` reads each layer's experts from the whole stacks at the
+    layer's index give the logits, caches and expert counts of the step
+    that feeds each layer its own sliced experts: three periods of
+    mellum2, whose window stack has 9 layers and 12 model layers, and a
+    uniform MoE stack.  Rolling the window experts by one layer changes
+    the logits, so the index is read."""
+    cfg = CFG3 if arch == "mellum2-12b" else get_smoke_config(arch)
+    params = build_model(cfg).init(jax.random.PRNGKey(7))
+    if rolled:
+        params = _roll_window_experts(params)
+    toks = jax.random.randint(jax.random.PRNGKey(8),
+                              (B, STACK_PROMPT + STACK_STEPS), 0,
+                              cfg.vocab_size)
+    got, got_cache = _serve_pallas(cfg, params, toks)
+    with monkeypatch.context() as m:
+        _sliced_experts(m)
+        want, want_cache = _serve_pallas(cfg, params, toks)
+    np.testing.assert_array_equal(got, want)
+    jax.tree.map(np.testing.assert_array_equal, got_cache, want_cache)
+    if rolled:
+        unrolled, _ = _serve_pallas(cfg, build_model(cfg).init(
+            jax.random.PRNGKey(7)), toks)
+        assert float(jnp.abs(got - unrolled).max()) > 30 * TOL

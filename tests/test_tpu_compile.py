@@ -112,18 +112,26 @@ def test_flash_decode_compiles(one_chip):
     assert "tpu_custom_call" in txt
 
 
+def _dims(shape) -> tuple:
+    return tuple(sorted(d for d in shape if d != 1))
+
+
 def _cache_shaped(txt: str, stacks) -> list:
     """(name, opcode, operands) of each instruction of the compiled
     program, outside fused computations, whose result holds a whole
-    cache stack of ``stacks`` or one layer of it: the same dimensions in
-    any order, ones dropped.  Instructions that move nothing (parameters,
-    tuples and their elements, bitcasts) are left out."""
+    cache stack of ``stacks`` or one layer of it."""
+    return _shaped(txt, list(stacks) + [s[1:] for s in stacks])
+
+
+def _shaped(txt: str, shapes) -> list:
+    """(name, opcode, operands) of each instruction of the compiled
+    program, outside fused computations, whose result has the dimensions
+    of one of ``shapes``, in any order, ones dropped.  Instructions that
+    move nothing (parameters, tuples and their elements, bitcasts) are
+    left out."""
     import re
 
-    def dims(shape):
-        return tuple(sorted(d for d in shape if d != 1))
-
-    wanted = {dims(s) for s in stacks} | {dims(s[1:]) for s in stacks}
+    wanted = {_dims(s) for s in shapes}
     found, fused = [], False
     for line in txt.splitlines():
         head = re.match(r"(ENTRY )?%(\S+) .*\{$", line)
@@ -135,7 +143,7 @@ def _cache_shaped(txt: str, stacks) -> list:
         if fused or not m or m.group(3) in (
                 "parameter", "get-tuple-element", "tuple", "bitcast"):
             continue
-        if dims(int(d) for d in m.group(2).split(",") if d) in wanted:
+        if _dims(int(d) for d in m.group(2).split(",") if d) in wanted:
             found.append((m.group(1), m.group(3), m.group(4)))
     return found
 
@@ -176,6 +184,48 @@ def test_decode_step_leaves_the_cache_where_it_lies(one_chip, arch):
     assert len(reads) == len(stacks)
     for operands in reads:
         assert sum(f"%{w}," in operands + "," for w in writes) == 2, operands
+
+
+def test_decode_step_reads_the_experts_where_they_lie(one_chip):
+    """The served mellum2 decode step (smoke depth, ``impl="pallas"``) at
+    the published expert widths (d 2304, expert 896) moves no expert
+    matrix: no instruction outside fused computations holds a layer's
+    ``(E, d, f)`` or a stack of them, and each ``moe_gmm`` call's weight
+    operand is a whole stack, ``(P·n, E, d, f)`` of the window layers or
+    ``(P, E, d, f)`` of the full ones: a parameter, a loop's element of
+    one or a bitcast, which the kernel reads at the layer's index."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_smoke_config
+    from repro.models import build_model
+
+    cfg = dataclasses.replace(get_smoke_config("mellum2-12b"),
+                              d_model=2304, moe_d_ff=896)
+    model = build_model(cfg, impl="pallas")
+    params, cache = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        (model.abstract(jnp.bfloat16), model.abstract_cache(8, 2048)),
+    )
+    dec = params["decoder"]
+    window = [w.shape for w in dec["window"]["moe"].values()
+              if w.ndim == 5]                          # (P, n, E, d, f)
+    full = [w.shape for w in dec["full"]["moe"].values() if w.ndim == 4]
+    assert len(window) == len(full) == 3
+    stacks = [(s[0] * s[1],) + s[2:] for s in window] + full
+    held = window + stacks + [s[1:] for s in window] + [s[1:] for s in full]
+    txt = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+        params, _sds(one_chip, (8, 1), jnp.int32), cache).compile().as_text()
+    assert _shaped(txt, held) == []
+    shape_of = dict(re.findall(r"%(\S+) = \w+\[([\d,]*)\]", txt))
+    op_of = dict(re.findall(r"%(\S+) = \S+ ([\w-]+)\(", txt))
+    calls = re.findall(r"%moe_gmm\.\d+ = .*? custom-call\(([^)]*)\)", txt)
+    assert len(calls) == 6                    # three per kind of layer
+    for operands in calls:
+        w = operands.split(", ")[-1].lstrip("%")
+        assert op_of[w] in ("parameter", "get-tuple-element", "bitcast"), w
+        dims = tuple(int(d) for d in shape_of[w].split(","))
+        assert dims in stacks, (w, dims)
 
 
 @pytest.mark.parametrize("mode", ["prefill", "decode"])
